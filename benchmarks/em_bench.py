@@ -20,9 +20,9 @@ Three rows per (d, cov) point at the paper-scale cohort
   fused         ops.gmm_estep_fused over the full (M·C, N, K) stack
 
 ``derived`` carries the fused row's speedup over per_client (the real
-pre-PR baseline). Run with ``use_pallas(True)`` on TPU for kernel numbers;
-this container times the XLA fallback (interpret-mode Pallas timings are
-not meaningful on CPU).
+pre-PR baseline). The fused row times the XLA reference
+(``use_pallas(False)``): interpret-mode Pallas timings mean nothing, and
+no chip number exists for this row yet.
 """
 from __future__ import annotations
 
@@ -94,17 +94,20 @@ def main(quick: bool = False):
     key = jax.random.PRNGKey(23)
     dims = [256] if quick else [256, 768]
     reps = 2 if quick else 5
-    ops.use_pallas(False)          # CPU container: time the XLA fallback
-    for d in dims:
-        x, mu, var, pi = _cohort(jax.random.fold_in(key, d), d)
-        us_pc = _time(_per_client, x, mu, var, pi, reps=reps)
-        us_vm = _time(_vmap_ref_cohort, x, mu, var, pi, reps=reps)
-        us_fu = _time(_fused, x, mu, var, pi, reps=reps)
-        tag = f"em_bench/M{M}_C{CN}_K{K}_d{d}"
-        C.emit(f"{tag}_per_client", us_pc, f"dispatches={M}")
-        C.emit(f"{tag}_vmap_ref", us_vm, "dispatches=1")
-        C.emit(f"{tag}_fused", us_fu,
-               f"speedup={us_pc / max(us_fu, 1e-9):.1f}x")
+    ops.use_pallas(False)          # the fused row times the XLA reference
+    try:
+        for d in dims:
+            x, mu, var, pi = _cohort(jax.random.fold_in(key, d), d)
+            us_pc = _time(_per_client, x, mu, var, pi, reps=reps)
+            us_vm = _time(_vmap_ref_cohort, x, mu, var, pi, reps=reps)
+            us_fu = _time(_fused, x, mu, var, pi, reps=reps)
+            tag = f"em_bench/M{M}_C{CN}_K{K}_d{d}"
+            C.emit(f"{tag}_per_client", us_pc, f"dispatches={M}")
+            C.emit(f"{tag}_vmap_ref", us_vm, "dispatches=1")
+            C.emit(f"{tag}_fused", us_fu,
+                   f"speedup={us_pc / max(us_fu, 1e-9):.1f}x")
+    finally:
+        ops.use_pallas(True)       # back to the platform's dispatch
 
 
 if __name__ == "__main__":

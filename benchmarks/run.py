@@ -52,6 +52,7 @@ import time
 import traceback
 
 from benchmarks import common as C
+from repro.launch import compile_cache
 
 MODULES = ["comm_cost", "gmm_quality", "topology", "dp_tradeoff",
            "reconstruction", "shifts", "ablations", "synthesize_bench",
@@ -76,6 +77,7 @@ def main(argv=None) -> None:
                          "tracer); emits analysis/sanitize/<module> rows")
     args = ap.parse_args(argv)
     mods = args.only.split(",") if args.only else MODULES
+    compile_cache.enable()
 
     print("name,us_per_call,derived")
     failures = []

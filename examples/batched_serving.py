@@ -7,6 +7,7 @@ import time
 import jax
 
 from repro.configs import get_config
+from repro.launch import compile_cache
 from repro.models import model as M
 from repro.serve.server import BatchedServer, Request, ServerConfig
 
@@ -35,4 +36,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -11,6 +11,7 @@ from repro.core import decentralized as DC
 from repro.core import fedpft as FP
 from repro.core import gmm as G
 from repro.core import head as H
+from repro.launch import compile_cache
 
 
 def main():
@@ -56,4 +57,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -26,6 +26,7 @@ from repro.configs import get_config
 from repro.core import gmm as G
 from repro.fl.api import FedSession, GMMSummarizer
 from repro.fl.ingest import IngestConfig
+from repro.launch import compile_cache
 from repro.launch.aot_cache import ProgramCache
 from repro.models import model as M
 from repro.serve.service import FedPFTService, ServiceConfig
@@ -82,4 +83,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -13,6 +13,7 @@ from repro.core import fedpft as FP
 from repro.core import gmm as G
 from repro.core import head as H
 from repro.core import reconstruction as RA
+from repro.launch import compile_cache
 
 
 def main():
@@ -67,4 +68,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

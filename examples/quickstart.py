@@ -35,6 +35,7 @@ from repro.core import fedpft as FP
 from repro.core import gmm as G
 from repro.core import head as H
 from repro.fl import api as FA
+from repro.launch import compile_cache
 
 
 def main():
@@ -83,4 +84,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

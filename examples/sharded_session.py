@@ -29,6 +29,7 @@ from repro import data as D
 from repro.core import gmm as G
 from repro.core import head as H
 from repro.fl import api as FA
+from repro.launch import compile_cache
 
 
 def main():
@@ -68,4 +69,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -23,6 +23,7 @@ from repro.core import gmm as G
 from repro.core import head as H
 from repro.fl import api as FA
 from repro.fl import ingest as IG
+from repro.launch import compile_cache
 
 
 def make_clients(n_clients, C, d, seed=0):
@@ -92,4 +93,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

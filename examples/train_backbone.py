@@ -8,6 +8,7 @@ then use it as the FedPFT feature extractor.
 import argparse
 import sys
 
+from repro.launch import compile_cache
 from repro.launch import train as train_driver
 
 
@@ -29,4 +30,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

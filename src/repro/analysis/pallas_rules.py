@@ -14,7 +14,8 @@ Checks per captured ``pallas_call``:
 * **PAL-ALIGN** (ERROR) — the last two block dims should be multiples of
   the MXU/VPU tile (128 lanes, 8 sublanes for f32).  A block dim that
   equals the *full* (padded) operand dim is exempt: the compiler keeps
-  whole-axis blocks resident and no lane remainder exists.
+  whole-axis blocks resident and no lane remainder exists.  A dim-1 block
+  on a longer axis is not exempt: the TPU compiler refuses it.
 * **PAL-VMEM** (WARN) — estimated VMEM footprint (all operand + output
   blocks ×2 for double buffering, plus declared scratch) must fit the
   ~16 MiB per-core budget (see /opt/skills/guides pallas notes).
@@ -171,13 +172,13 @@ def check_call(call: CapturedCall) -> List[Tuple[str, Severity, str]]:
                     f"block dim {bd} does not divide padded operand dim "
                     f"{od} (operand {op_shape}, block {block}, grid "
                     f"{call.grid}) in '{call.line_hint}'"))
-        # MXU alignment on the trailing two dims; exempt full-axis blocks
-        # (whole axis stays resident, no lane remainder) and degenerate
-        # dim-1 blocks (batch-style one-row stepping, masked by Mosaic)
+        # MXU alignment on the trailing two dims; exempt only full-axis
+        # blocks (whole axis stays resident, no lane remainder) — Mosaic
+        # refuses a dim-1 block on a longer axis like any other misfit
         trailing = list(zip(op_shape[-len(block):], block))[-2:]
         tiles = (_SUBLANE, _LANE)[-len(trailing):]
         for (od, bd), tile in zip(trailing, tiles):
-            if bd and bd != od and bd != 1 and bd % tile != 0:
+            if bd and bd != od and bd % tile != 0:
                 out.append((
                     "PAL-ALIGN", Severity.ERROR,
                     f"block dim {bd} is neither a multiple of the "

@@ -22,11 +22,6 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import gmm as G
 
-try:  # jax >= 0.6
-    from jax.experimental.shard_map import shard_map
-except ImportError:  # pragma: no cover
-    from jax.shard_map import shard_map
-
 
 def validate_cohort(I: int, n_shards: int, *, where: str = "fedpft_transfer"
                     ) -> None:
@@ -117,10 +112,10 @@ def fedpft_transfer(mesh, feats: jax.Array, labels: jax.Array,
         lls_g = jax.lax.all_gather(lls, "data", axis=0, tiled=True)
         return gathered, counts_g, lls_g
 
-    return shard_map(local, mesh=mesh,
-                     in_specs=(P("data"), P("data")),
-                     out_specs=(P(), P(), P()), check_rep=False)(feats,
-                                                                 labels)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(P("data"), P("data")),
+                         out_specs=(P(), P(), P()),
+                         check_vma=False)(feats, labels)
 
 
 def raw_feature_transfer(mesh, feats: jax.Array, labels: jax.Array):
@@ -129,9 +124,9 @@ def raw_feature_transfer(mesh, feats: jax.Array, labels: jax.Array):
         f16 = f.astype(jnp.bfloat16)     # paper's 16-bit wire encoding
         return (jax.lax.all_gather(f16, "data", axis=0, tiled=True),
                 jax.lax.all_gather(y, "data", axis=0, tiled=True))
-    return shard_map(local, mesh=mesh,
-                     in_specs=(P("data"), P("data")),
-                     out_specs=(P(), P()), check_rep=False)(feats, labels)
+    return jax.shard_map(local, mesh=mesh,
+                         in_specs=(P("data"), P("data")),
+                         out_specs=(P(), P()), check_vma=False)(feats, labels)
 
 
 def expected_wire_bytes(cov_type: str, d: int, K: int, C: int,

@@ -4,7 +4,8 @@ Replaces sklearn's ``GaussianMixture`` with a jit/vmap-compatible
 fixed-iteration EM so that *per-client × per-class* fits batch into one
 compiled SPMD program (the paper's Algorithm 1, line 8, reshaped for TPU).
 The diag/spher E-step inside that program is the Pallas kernel path
-(``kernels.ops.gmm_estep_fused`` — Pallas on TPU, XLA reference on CPU):
+(``kernels.ops.gmm_estep_fused``: compiled on a TPU, interpreted on the
+CPU, the XLA reference only after ``ops.use_pallas(False)``):
 one fused call per EM iteration covers the whole stack of fits and emits
 log-numerators + row logsumexp together (DESIGN.md §8).
 
@@ -158,7 +159,7 @@ def _m_step(x, resp, cfg: GMMConfig):
         cov = cov + cfg.reg * jnp.eye(d)[None]
     elif cfg.cov_type == "diag":
         x2 = (resp.T @ jnp.square(x)) / nk_safe
-        cov = x2 - jnp.square(mu) + cfg.reg
+        cov = jnp.maximum(x2 - jnp.square(mu) + cfg.reg, cfg.reg)
     else:
         x2 = jnp.sum(resp * jnp.sum(jnp.square(x), -1, keepdims=True),
                      axis=0) / nk_safe[:, 0]
@@ -170,8 +171,8 @@ def _m_step(x, resp, cfg: GMMConfig):
 def _estep_lr(x, xb, gmm, cov_type: str):
     """Fused E-step: log-numerators lr (B,N,K) + row logsumexp (B,N).
 
-    diag/spher dispatch through ``ops.gmm_estep_fused`` (Pallas on TPU,
-    XLA reference on CPU — DESIGN.md §8) on the compact shared-x block
+    diag/spher dispatch through ``ops.gmm_estep_fused`` (the platform's
+    Pallas path, DESIGN.md §8) on the compact shared-x block
     ``x`` (Bx, N, d); full covariance stays on the Cholesky XLA path,
     vmapped over the pre-expanded ``xb`` (B, N, d).
     """
@@ -232,6 +233,15 @@ def fit_gmm_batch(keys, x: jax.Array, weights: jax.Array,
 
 @partial(jax.jit, static_argnames=("cfg", "backend"))
 def _fit_gmm_batch(keys, x, weights, cfg: GMMConfig, backend):
+    # EM's moments cancel (diag Σ = E[x²] − μ², full Σ = E[xxᵀ] − μμᵀ), so
+    # every matmul of the fit runs at full f32 precision: a TPU's default
+    # single bf16 pass leaves errors larger than ``reg`` and turns small
+    # variances negative (NaN log-densities on the next E-step)
+    with jax.default_matmul_precision("highest"):
+        return _em(keys, x, weights, cfg)
+
+
+def _em(keys, x, weights, cfg: GMMConfig):
     B = weights.shape[0]
     x = x.astype(jnp.float32)
     weights = weights.astype(jnp.float32)

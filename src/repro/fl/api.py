@@ -672,8 +672,8 @@ class GMMSummarizer:
     The per-class EM stack runs as ONE batched fit
     (``gmm.fit_classwise_gmms`` → ``fit_gmm_batch``): the diag/spher
     E-step of all C fits is a single ``kernels.ops.gmm_estep_fused``
-    call per iteration — the Pallas kernel on TPU, its XLA reference on
-    CPU (DESIGN.md §8).
+    call per iteration — the compiled Pallas kernel on a TPU, the same
+    kernel interpreted on the CPU (DESIGN.md §8).
     """
     gmm: G.GMMConfig = G.GMMConfig()
 

@@ -8,8 +8,9 @@
   ssd              Mamba2 SSD chunked recurrence (scalar decay → pure MXU
                    matmuls), VMEM-resident N×P state
 
-``ops`` exposes jit'd wrappers with an XLA fallback; ``ref`` holds the
-pure-jnp oracles that define kernel semantics.
+``ops`` dispatches the E-step by platform (compiled on a TPU, interpreted
+on the CPU, the reference on request); ``ref`` holds the pure-jnp oracles
+that define kernel semantics.
 """
 from repro.kernels import ops, ref
 from repro.kernels.flash_attention import flash_attention

@@ -20,7 +20,15 @@ Two entry points share the kernel body:
                  (N, K) matrix in XLA. The logsumexp accumulates
                  flash-attention-style: running (m, l) statistics live in
                  VMEM scratch across the K-block sweep (the grid's minor
-                 axis) and are finalized on the last K block.
+                 axis) and are finalized on the last K block.  It leaves
+                 the kernel as a (B, N, 1) column, stored in (1, BN, 1)
+                 blocks: a (1, BN) row block of a (B, N) array has a
+                 second-to-last dim of 1 that is neither a multiple of 8
+                 nor the whole axis once B > 1, which Mosaic refuses.
+
+Both matmuls ask for ``Precision.HIGHEST`` (fp32 contraction on the MXU):
+the Mahalanobis expansion subtracts two d-term sums of similar size, and
+a bf16 pass would leave errors of several nats at d in the thousands.
 
 Batching: grid = (B, N/BN, K/BK). Component parameters vary per fit, but the
 feature rows are usually *shared* by groups of fits (one client's features,
@@ -57,10 +65,12 @@ def _logp_block(x_ref, xsq_ref, inv_ref, muinv_ref, const_ref):
     inv = inv_ref[0]                     # (BK, d) f32
     muinv = muinv_ref[0]                 # (BK, d) f32
     const = const_ref[0]                 # (1, BK) f32
+    dims = (((1,), (1,)), ((), ()))
+    hi = jax.lax.Precision.HIGHEST
     maha = (
-        jax.lax.dot_general(xsq, inv, (((1,), (1,)), ((), ())),
+        jax.lax.dot_general(xsq, inv, dims, precision=hi,
                             preferred_element_type=jnp.float32)
-        - 2.0 * jax.lax.dot_general(x, muinv, (((1,), (1,)), ((), ())),
+        - 2.0 * jax.lax.dot_general(x, muinv, dims, precision=hi,
                                     preferred_element_type=jnp.float32)
     )
     return -0.5 * maha + const
@@ -97,8 +107,7 @@ def _estep_fused_kernel(x_ref, xsq_ref, inv_ref, muinv_ref, const_ref,
 
     @pl.when(j == nj - 1)
     def _finalize():
-        lse_ref[0] = (m_scr[...]
-                      + jnp.log(jnp.maximum(l_scr[...], 1e-30)))[:, 0]
+        lse_ref[0] = m_scr[...] + jnp.log(jnp.maximum(l_scr[...], 1e-30))
 
 
 def _pad_to(a, axis, mult, value=0.0):
@@ -177,16 +186,16 @@ def _estep_call(x, mu, var, pi, *, block_n, block_k, fused, interpret):
     out, lse = pl.pallas_call(
         _estep_fused_kernel, grid=grid, in_specs=in_specs,
         out_specs=[out_spec,
-                   pl.BlockSpec((1, bn), lambda b, i, j: (b, i))],
+                   pl.BlockSpec((1, bn, 1), lambda b, i, j: (b, i, 0))],
         out_shape=[out_shape,
-                   jax.ShapeDtypeStruct((B, Np), jnp.float32)],
+                   jax.ShapeDtypeStruct((B, Np, 1), jnp.float32)],
         scratch_shapes=[
             # running (m, l) logsumexp stats — persist across the K sweep
             pltpu.VMEM((bn, 1), jnp.float32),
             pltpu.VMEM((bn, 1), jnp.float32),
         ],
         interpret=interpret)(xp, xsq, invp, muinvp, constp)
-    return out[:, :N, :K], lse[:, :N]
+    return out[:, :N, :K], lse[:, :N, 0]
 
 
 @functools.partial(jax.jit,
@@ -197,8 +206,9 @@ def estep(x: jax.Array, mu: jax.Array, var: jax.Array, pi: jax.Array,
     """log[π_k N(x_n | μ_k, diag Σ_k)] : (N, d) × (K, d) → (N, K).
 
     ``var`` may be diag ``(K, d)`` or spher ``(K,)``. Matches
-    ``ref.estep_ref``. ``interpret=True`` executes the kernel body in
-    Python on CPU (this container); on TPU pass ``interpret=False``.
+    ``ref.estep_ref``. ``interpret=True`` runs the kernel body through
+    the Pallas interpreter (CPU); on a TPU pass ``interpret=False``
+    (``kernels.ops`` picks by platform).
     """
     assert mu.ndim == 2, \
         f"estep is single-fit (got mu {mu.shape}); use estep_fused"

@@ -1,32 +1,49 @@
-"""jit'd public wrappers around the Pallas kernels, with XLA fallback.
+"""jit'd public wrappers around the E-step kernels, dispatched by platform.
 
-``use_pallas(True/False)`` flips the backend globally (tests exercise both);
-on this CPU container the Pallas path runs in interpret mode.
+The platform picks the path (DESIGN.md §8): compiled Pallas where JAX's
+default backend is a TPU, the same kernels in interpret mode where it is
+the CPU, and the pure-jnp reference (``ref``) only after a caller asks for
+it with ``use_pallas(False)``.  Any other platform is an error rather than
+a silent reference run.
 """
 from __future__ import annotations
 
+import jax
+
 from repro.kernels import ref
-from repro.kernels.flash_attention import flash_attention
 from repro.kernels.gmm_estep import estep, estep_fused
-from repro.kernels.ssd import ssd as ssd_kernel
-from repro.kernels.wkv6 import wkv6 as wkv6_kernel
 
-_STATE = {"use_pallas": False, "interpret": True}
+PALLAS = "pallas"          # compiled Mosaic kernels (TPU)
+INTERPRET = "interpret"    # the same kernels, interpreted (CPU)
+REFERENCE = "reference"    # the pure-jnp oracles, on request only
 
-
-def use_pallas(enable: bool = True, interpret: bool = True):
-    _STATE["use_pallas"] = enable
-    _STATE["interpret"] = interpret
+_STATE = {"use_pallas": True}
 
 
-def backend():
-    """Hashable snapshot of the dispatch state.
+def use_pallas(enable: bool = True):
+    """``True`` (the default): the platform's Pallas path — compiled on a
+    TPU, interpreted on the CPU.  ``False``: the XLA reference."""
+    _STATE["use_pallas"] = bool(enable)
+
+
+def backend() -> str:
+    """The dispatch in force: ``PALLAS``, ``INTERPRET`` or ``REFERENCE``.
 
     Callers that trace ops.* inside their own ``jit`` must pass this as a
     static argument so their cache keys on the backend — otherwise a
     ``use_pallas`` flip after the first trace is silently ignored
     (core/gmm.fit_gmm_batch does this)."""
-    return (_STATE["use_pallas"], _STATE["interpret"])
+    if not _STATE["use_pallas"]:
+        return REFERENCE
+    platform = jax.default_backend()
+    if platform == "tpu":
+        return PALLAS
+    if platform == "cpu":
+        return INTERPRET
+    raise RuntimeError(
+        f"kernels.ops: no Pallas path for platform {platform!r} — the "
+        "kernels are Mosaic (TPU) kernels; call use_pallas(False) to run "
+        "the XLA reference explicitly")
 
 
 def gmm_estep(x, mu, var, pi):
@@ -35,9 +52,10 @@ def gmm_estep(x, mu, var, pi):
     ``var`` is diag (K, d) or spher (K,) — both backends expand spher
     internally (the old fallback's ``broadcast_to((K,) → (K, d))`` raised).
     """
-    if _STATE["use_pallas"]:
-        return estep(x, mu, var, pi, interpret=_STATE["interpret"])
-    return ref.estep_ref(x, mu, var, pi)
+    mode = backend()
+    if mode == REFERENCE:
+        return ref.estep_ref(x, mu, var, pi)
+    return estep(x, mu, var, pi, interpret=mode == INTERPRET)
 
 
 def gmm_estep_fused(x, mu, var, pi):
@@ -48,31 +66,8 @@ def gmm_estep_fused(x, mu, var, pi):
     shared by B // Bx consecutive fits — and responsibilities + ``L_EM``
     come out of one tiled pass.
     """
-    if _STATE["use_pallas"]:
-        return estep_fused(x, mu, var, pi, interpret=_STATE["interpret"])
-    return ref.estep_fused_ref(x, mu, var, pi)
+    mode = backend()
+    if mode == REFERENCE:
+        return ref.estep_fused_ref(x, mu, var, pi)
+    return estep_fused(x, mu, var, pi, interpret=mode == INTERPRET)
 
-
-def attention(q, k, v, *, causal=True, window=0, prefix=0):
-    """(B,H,Sq,D) × (B,Hkv,Sk,D) flash attention → (B,H,Sq,D)."""
-    if _STATE["use_pallas"]:
-        return flash_attention(q, k, v, causal=causal, window=window,
-                               prefix=prefix, interpret=_STATE["interpret"])
-    return ref.attention_ref(q, k, v, causal=causal, window=window,
-                             prefix=prefix)
-
-
-def wkv6(r, k, v, lw, u, s0, chunk: int = 16):
-    """(B,H,T,Dh) WKV6 chunked recurrence → (out, final state)."""
-    if _STATE["use_pallas"]:
-        return wkv6_kernel(r, k, v, lw, u, s0, chunk=chunk,
-                           interpret=_STATE["interpret"])
-    return ref.wkv6_ref(r, k, v, lw, u, s0, chunk=chunk)
-
-
-def ssd(x, a_log, B, C, s0, chunk: int = 64):
-    """(Bt,H,T,P) Mamba2 SSD chunked recurrence → (y, final state)."""
-    if _STATE["use_pallas"]:
-        return ssd_kernel(x, a_log, B, C, s0, chunk=chunk,
-                          interpret=_STATE["interpret"])
-    return ref.ssd_ref(x, a_log, B, C, s0, chunk=chunk)

@@ -15,14 +15,17 @@ request path.  :class:`ProgramCache` bounds that churn:
 * entries live in an **LRU** of ``max_entries`` with hit/miss/evict/
   compile counters (``stats()``), surfaced in ``info["compile"]`` and the
   ``analysis_gate``/``compile_bench`` rows;
-* a backend that cannot AOT-compile (or serialize) **falls back to the
-  plain jit path** per entry (``jit_fallbacks`` counter) instead of
-  failing the round.
+* a backend that cannot AOT-compile **falls back to the plain jit path**
+  per entry instead of failing the round.  No failure vanishes: a failed
+  compile, costing or serialization bumps its counter (``jit_fallbacks``,
+  ``cost_failures``, ``serialize_failures``), keeps the exception on the
+  entry (``CachedProgram.errors``) and logs a warning.
 """
 from __future__ import annotations
 
 import collections
 import dataclasses
+import logging
 import time
 from functools import partial
 from typing import Any, Dict, List, Optional, Sequence, Tuple
@@ -34,6 +37,8 @@ from repro.launch import input_specs as IS
 
 __all__ = ["CachedProgram", "ProgramCache", "canonical_grid",
            "serving_grid", "mesh_fingerprint"]
+
+log = logging.getLogger(__name__)
 
 
 def mesh_fingerprint(mesh) -> Optional[Tuple]:
@@ -54,6 +59,8 @@ class CachedProgram:
     program's positional args.  ``serialized`` is the
     ``serialize_executable`` triple ``(payload, in_tree, out_tree)`` when
     the backend supports it — :meth:`deserialize` proves the round trip.
+    ``errors`` maps each failed phase (``"compile"``, ``"cost"``,
+    ``"serialize"``) to the exception it raised.
     """
     sig: FR.CohortSignature
     head_cfg: Any
@@ -65,6 +72,7 @@ class CachedProgram:
     cost: Optional[Any]                 # hlo_cost.Cost of the executable
     serialized: Optional[Tuple[bytes, Any, Any]]
     uses: int = 0
+    errors: Dict[str, Exception] = dataclasses.field(default_factory=dict)
 
     @property
     def aot(self) -> bool:
@@ -110,6 +118,7 @@ class ProgramCache:
         self.evictions = 0
         self.compiles = 0
         self.jit_fallbacks = 0
+        self.cost_failures = 0
         self.serialize_failures = 0
         self.total_compile_us = 0.0
 
@@ -151,12 +160,22 @@ class ProgramCache:
                        samples_per_class=samples_per_class)
         t0 = time.perf_counter()
         executable = cost = serialized = None
+        errors: Dict[str, Exception] = {}
+
+        def failed(phase: str, e: Exception) -> None:
+            errors[phase] = e
+            log.warning("round program %s: %s failed", canon, phase,
+                        exc_info=e)
+
+        # each phase is a boundary that must keep serving: the counters,
+        # the entry's ``errors`` and the log carry what went wrong
         try:
             specs = IS.round_specs_for(canon, mesh=mesh)
             lowered = FR.round_program.lower(*specs, **statics)
             executable = lowered.compile()
-        except Exception:
+        except Exception as e:
             self.jit_fallbacks += 1
+            failed("compile", e)
         compile_us = (time.perf_counter() - t0) * 1e6
         if executable is not None:
             self.compiles += 1
@@ -164,20 +183,23 @@ class ProgramCache:
             try:
                 from repro.launch.hlo_cost import HloCost
                 cost = HloCost(executable.as_text()).total()
-            except Exception:
-                cost = None
+            except Exception as e:
+                self.cost_failures += 1
+                failed("cost", e)
             if self.serialize:
                 try:
                     from jax.experimental import serialize_executable as SE
                     serialized = SE.serialize(executable)
-                except Exception:
+                except Exception as e:
                     self.serialize_failures += 1
+                    failed("serialize", e)
         return CachedProgram(
             sig=canon, head_cfg=head_cfg,
             samples_per_class=samples_per_class,
             fingerprint=mesh_fingerprint(mesh), executable=executable,
             fallback=partial(FR.round_program, **statics),
-            compile_us=compile_us, cost=cost, serialized=serialized)
+            compile_us=compile_us, cost=cost, serialized=serialized,
+            errors=errors)
 
     def warmup(self, sigs: Sequence[FR.CohortSignature], head_cfg,
                samples_per_class: Optional[int] = None,
@@ -197,11 +219,16 @@ class ProgramCache:
         """Cache keys in LRU order (oldest first) — eviction order."""
         return list(self._entries)
 
+    def failures(self) -> Dict[Tuple, Dict[str, Exception]]:
+        """The failed phases of each live entry that has any, by key."""
+        return {k: e.errors for k, e in self._entries.items() if e.errors}
+
     def stats(self) -> Dict[str, Any]:
         return {"entries": len(self._entries), "hits": self.hits,
                 "misses": self.misses, "evictions": self.evictions,
                 "compiles": self.compiles,
                 "jit_fallbacks": self.jit_fallbacks,
+                "cost_failures": self.cost_failures,
                 "serialize_failures": self.serialize_failures,
                 "total_compile_us": self.total_compile_us}
 

@@ -176,10 +176,21 @@ class TestPallasCheckers:
                             outputs=[((512, 512), (128, 512))],
                             scratch_bytes=0)
         assert self._rules(good) == set()
-        # degenerate dim-1 batch blocks and full-axis blocks are exempt
-        batchy = CapturedCall(grid=(2, 4), inputs=[((2, 512), (1, 128))],
+        # a dim-1 block is exempt only where it spans the whole axis
+        batchy = CapturedCall(grid=(2, 4), inputs=[((1, 512), (1, 128))],
                               outputs=[], scratch_bytes=0)
         assert self._rules(batchy) == set()
+
+    @pytest.mark.parametrize("B", [16, 100])
+    def test_row_block_on_batched_axis_fires(self, B):
+        """The fused E-step's old (1, bn) logsumexp block on its (B, Np)
+        output: the TPU compiler refuses a second-to-last block dim of 1
+        once B > 1, so PAL-ALIGN must too."""
+        from repro.analysis.pallas_rules import CapturedCall
+        lse_row = CapturedCall(grid=(B, 16, 1), inputs=[],
+                               outputs=[((B, 4096), (1, 256))],
+                               scratch_bytes=0)
+        assert self._rules(lse_row) == {"PAL-ALIGN"}
 
 
 class TestSanitizer:

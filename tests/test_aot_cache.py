@@ -187,6 +187,9 @@ class TestProgramCache:
         entry = cache.get(FR.signature_of(msgs), _HEAD)
         assert not entry.aot and cache.jit_fallbacks == 1 \
             and cache.compiles == 0
+        # the failure stays visible: the exception rides on the entry
+        assert isinstance(entry.errors["compile"], RuntimeError)
+        assert str(entry.errors["compile"]) == "no AOT"
         with pytest.raises(ValueError, match="serialized"):
             entry.deserialize()
         # the fallback round is still the SAME program — bit-identical to

@@ -76,7 +76,6 @@ class TestGmmEstepKernel:
             got = ops.gmm_estep(x, mu, var_s, pi)
             np.testing.assert_allclose(np.asarray(got), np.asarray(exp),
                                        rtol=3e-4, atol=3e-4)
-        ops.use_pallas(False)
 
 
 class TestGmmEstepFused:
@@ -160,7 +159,6 @@ class TestGmmEstepFused:
         a_lp, a_lse = ops.gmm_estep_fused(x, mu, var, pi)
         ops.use_pallas(True)
         b_lp, b_lse = ops.gmm_estep_fused(x, mu, var, pi)
-        ops.use_pallas(False)
         np.testing.assert_allclose(np.asarray(a_lp), np.asarray(b_lp),
                                    rtol=3e-4, atol=3e-4)
         np.testing.assert_allclose(np.asarray(a_lse), np.asarray(b_lse),
@@ -182,7 +180,6 @@ class TestFitGmmBackendParity:
         ga, lla = G.fit_gmm(key, x, w, cfg)
         ops.use_pallas(True)
         gb, llb = G.fit_gmm(key, x, w, cfg)
-        ops.use_pallas(False)
         if cov == "spher":
             assert ga["cov"].shape == gb["cov"].shape == (3,)
         for f in ("pi", "mu", "cov"):
@@ -201,7 +198,6 @@ class TestFitGmmBackendParity:
         ga, ca, _ = G.fit_classwise_gmms(key, x, labels, 3, cfg)
         ops.use_pallas(True)
         gb, cb, _ = G.fit_classwise_gmms(key, x, labels, 3, cfg)
-        ops.use_pallas(False)
         np.testing.assert_array_equal(np.asarray(ca), np.asarray(cb))
         np.testing.assert_allclose(np.asarray(ga["mu"]),
                                    np.asarray(gb["mu"]),
@@ -295,12 +291,14 @@ def test_flash_property(Sq, extra, H, G, window):
 
 
 def test_ops_dispatch(key):
-    """ops.use_pallas flips backends; results agree."""
+    """ops.use_pallas flips backends; results agree, and the platform
+    picks the Pallas path (interpret mode on the CPU) by default."""
     x, mu, var, pi = _estep_inputs(key, 40, 3, 8)
     ops.use_pallas(False)
     a = ops.gmm_estep(x, mu, var, pi)
     ops.use_pallas(True)
     b = ops.gmm_estep(x, mu, var, pi)
-    ops.use_pallas(False)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                rtol=3e-4, atol=3e-4)
+    assert ops.backend() == (ops.PALLAS if jax.default_backend() == "tpu"
+                             else ops.INTERPRET)
