@@ -121,6 +121,7 @@ def service_round(report: Report, size: Size = Size(), seed: int = 0):
 
     from repro.core import gmm as G
     from repro.fl import ingest as IG
+    from repro.fl import round as FR
     from repro.fl.api import FedSession, GMMSummarizer
     from repro.kernels import ops, ref
     from repro.launch.aot_cache import ProgramCache
@@ -141,7 +142,7 @@ def service_round(report: Report, size: Size = Size(), seed: int = 0):
     C, K = size.n_classes, size.n_components
     session = FedSession(
         n_classes=C, summarizer=GMMSummarizer(G.GMMConfig(K, "diag")),
-        ingest=IG.IngestConfig(capacity=next_pow2(size.n_clients * C),
+        ingest=IG.IngestConfig(capacity=FR.next_pow2(size.n_clients * C),
                                chunk_size=size.n_clients),
         program_cache=ProgramCache())
     svc = FedPFTService(cfg, params, session,
@@ -324,10 +325,6 @@ def service_round(report: Report, size: Size = Size(), seed: int = 0):
                               and 0 <= r.label < C for r in infer),
                  f"labels {got_labels}")
     return svc
-
-
-def next_pow2(n: int) -> int:
-    return 1 << (max(int(n), 1) - 1).bit_length()
 
 
 # ---------------------------------------------------------------------------

@@ -70,4 +70,3 @@ def gmm_estep_fused(x, mu, var, pi):
     if mode == REFERENCE:
         return ref.estep_fused_ref(x, mu, var, pi)
     return estep_fused(x, mu, var, pi, interpret=mode == INTERPRET)
-
