@@ -29,9 +29,6 @@ JSON so the perf trajectory is machine-readable across PRs.
   compile_bench     ISSUE 8           multi-tenant mixed-signature stream:
                                       cold vs warm AOT round-program cache
                                       (launch.aot_cache), no-cache contrast
-  serve_bench       ISSUE 9           FedPFT-as-a-service: rps + p50/p99
-                                      per traffic class under a ≥1000-
-                                      request mixed extract/infer stream
   chaos_bench       ISSUE 10          fault-injection sweeps: accuracy vs
                                       coverage under drop/corrupt/straggle,
                                       plus the 1000-client wire acceptance
@@ -57,8 +54,7 @@ from repro.launch import compile_cache
 MODULES = ["comm_cost", "gmm_quality", "topology", "dp_tradeoff",
            "reconstruction", "shifts", "ablations", "synthesize_bench",
            "em_bench", "head_bench", "ingest_bench", "compile_bench",
-           "serve_bench", "chaos_bench", "frontier", "roofline_report",
-           "analysis_gate"]
+           "chaos_bench", "frontier", "roofline_report", "analysis_gate"]
 
 
 def main(argv=None) -> None:

@@ -21,7 +21,7 @@ Serving the whole loop? `examples/fedpft_service.py` runs FedPFT as a
 service: backbone feature extraction and head classification share one
 continuous-batching slot pool, GMM messages stream through the ingest
 broker, and `close_round` trains the served head through the warm AOT
-cache (DESIGN.md §12, benchmarks/serve_bench.py).
+cache (DESIGN.md §12).
 
 Before sending a change, run the repo's own linter (DESIGN.md §10) —
 key discipline, compile churn, kernel + wire contracts:

@@ -43,7 +43,6 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -51,6 +50,7 @@ import jax.numpy as jnp
 import ml_dtypes
 import numpy as np
 
+from repro import obs
 from repro.core import distributed as DF
 from repro.core import dp as DP
 from repro.core import gmm as G
@@ -258,9 +258,21 @@ def encode_message(params: Dict, counts, logliks, *, kind: str,
     Returns the message carrying both the payload bytes and the decoded
     (round-tripped) parameters the receiver will actually compute on.
     """
-    counts = np.asarray(jax.device_get(counts)).astype(np.int64).ravel()
-    params = {k: np.asarray(jax.device_get(v), np.float32)
-              for k, v in params.items()}
+    with obs.span("client.readback"):
+        counts = np.asarray(jax.device_get(counts)).astype(np.int64).ravel()
+        params = {k: np.asarray(jax.device_get(v), np.float32)
+                  for k, v in params.items()}
+        lls = np.asarray(jax.device_get(logliks), np.float32).ravel()
+    with obs.span("wire.encode"):
+        return _encode(params, counts, lls, kind=kind, cov_type=cov_type,
+                       n_classes=n_classes, codec=codec)
+
+
+def _encode(params: Dict[str, np.ndarray], counts: np.ndarray,
+            lls: np.ndarray, *, kind: str, cov_type: str, n_classes: int,
+            codec: QuantizedCodec) -> ClientMessage:
+    """:func:`encode_message` on host arrays: subset, quantize, serialize,
+    decode, and upload the decoded parameters."""
     if kind == "gmm":
         K, d = params["mu"].shape[-2], params["mu"].shape[-1]
         present = np.flatnonzero(counts > 0)
@@ -298,7 +310,6 @@ def encode_message(params: Dict, counts, logliks, *, kind: str,
     else:
         decoded = decoded_sub
     decoded = {k: jnp.asarray(v) for k, v in decoded.items()}
-    lls = np.asarray(jax.device_get(logliks), np.float32).ravel()
     return ClientMessage(params=decoded,
                          logliks=tuple(float(v) for v in lls),
                          header=header, payload=payload)
@@ -865,7 +876,8 @@ class FedSession:
     #   IngestConfig routes the server phase through fl.ingest: arriving
     #   messages fold into a fixed-capacity reservoir chunk-at-a-time, so
     #   peak server memory and the fused scan's compile key are independent
-    #   of the cohort size M.  Requires synthesis="fused".
+    #   of the cohort size M.  Requires synthesis="fused".  The bytes of the
+    #   reservoir stack sent to the device land in info["staged_bytes"].
     ingest: Optional[IG.IngestConfig] = None
     # -- fault policy (DESIGN.md §13) ---------------------------------------
     #   ResilienceConfig arms the wire-level quarantine gate on the
@@ -898,17 +910,20 @@ class FedSession:
     def client_update(self, key, feats, labels, i: int = 0) -> ClientMessage:
         """Summarize → (optionally privatize) → encode."""
         summ = self.summarizer_for(i)
-        k_fit, k_dp = jax.random.split(key)
-        feats = self._normalize(feats)
-        params, counts, lls = summ.summarize(k_fit, feats, labels,
-                                             self.n_classes)
-        if self.min_class_count and summ.kind == "gmm":
-            counts = jnp.where(counts >= self.min_class_count, counts, 0)
-        if self.dp is not None:
-            assert summ.kind == "gmm" and summ.cov_type == "full" \
-                and params["mu"].shape[-2] == 1, \
-                "Theorem 4.1 requires K=1 full-covariance summaries"
-            params = DP.privatize_classwise(k_dp, params, counts, self.dp)
+        with obs.span("client.fit", client=i):
+            k_fit, k_dp = jax.random.split(key)
+            feats = self._normalize(feats)
+            params, counts, lls = summ.summarize(k_fit, feats, labels,
+                                                 self.n_classes)
+            if self.min_class_count and summ.kind == "gmm":
+                counts = jnp.where(counts >= self.min_class_count, counts,
+                                   0)
+            if self.dp is not None:
+                assert summ.kind == "gmm" and summ.cov_type == "full" \
+                    and params["mu"].shape[-2] == 1, \
+                    "Theorem 4.1 requires K=1 full-covariance summaries"
+                params = DP.privatize_classwise(k_dp, params, counts,
+                                                self.dp)
         return encode_message(params, counts, lls, kind=summ.kind,
                               cov_type=summ.cov_type,
                               n_classes=self.n_classes, codec=self.codec)
@@ -1021,10 +1036,10 @@ class FedSession:
                 mesh, jax.sharding.PartitionSpec())
             args = [a if a is None else jax.device_put(a, repl)
                     for a in args]
-        t0 = time.perf_counter()
-        head_params, losses = prog(*args)
-        jax.block_until_ready(head_params)
-        run_us = (time.perf_counter() - t0) * 1e6
+        with obs.span("round.program") as run:
+            head_params, losses = prog(*args)
+            jax.block_until_ready(head_params)
+        run_us = run.elapsed_ns / 1e3
         info["compile"] = {
             "hit": hit, "aot": prog.aot,
             "signature": dataclasses.astuple(sig),
@@ -1074,14 +1089,16 @@ class FedSession:
         (``layout="slots"`` at M = capacity; ``samples_per_class`` was
         applied at fold time, so the program gets None)."""
         cache = self.program_cache
-        sig = FR.signature_of_state(state)
-        canon = cache.canonical(sig)
-        pi, mu, cov, slot_labels, slot_counts = FR.pad_slots(
-            *state.padded_stack(), sig, canon)
+        with obs.span("round.stage"):
+            sig = FR.signature_of_state(state)
+            canon = cache.canonical(sig)
+            stack = FR.pad_slots(*state.padded_stack(), sig, canon)
+            info["staged_bytes"] = sum(a.nbytes for a in stack)
+            pi, mu, cov, slot_labels, slot_counts = jax.block_until_ready(
+                tuple(map(jnp.asarray, stack)))
         hits0 = cache.hits
         prog = cache.get(sig, self.head, samples_per_class=None, mesh=mesh)
-        args = [k_head, jnp.asarray(pi), jnp.asarray(mu), jnp.asarray(cov),
-                jnp.asarray(slot_counts), jnp.asarray(slot_labels)]
+        args = [k_head, pi, mu, cov, slot_counts, slot_labels]
         head_params, losses = self._exec_cached(
             prog, cache.hits > hits0, sig, canon, info, args, mesh=mesh)
         info.update(head_losses=losses)
@@ -1119,16 +1136,16 @@ class FedSession:
         if self.program_cache is not None:
             return self._cached_round_from_state(k_head, state, info,
                                                  messages, mesh=mesh)
-        pi, mu, cov, slot_labels, slot_counts = state.padded_stack()
-        pi, mu, cov = jnp.asarray(pi), jnp.asarray(mu), jnp.asarray(cov)
-        slot_labels = jnp.asarray(slot_labels)
-        slot_counts = jnp.asarray(slot_counts)
-        if mesh is not None:
-            repl = jax.sharding.NamedSharding(
-                mesh, jax.sharding.PartitionSpec())
-            pi, mu, cov = (jax.device_put(a, repl) for a in (pi, mu, cov))
-            slot_labels = jax.device_put(slot_labels, repl)
-            slot_counts = jax.device_put(slot_counts, repl)
+        with obs.span("round.stage"):
+            stack = state.padded_stack()
+            info["staged_bytes"] = sum(a.nbytes for a in stack)
+            staged = tuple(map(jnp.asarray, stack))
+            if mesh is not None:
+                repl = jax.sharding.NamedSharding(
+                    mesh, jax.sharding.PartitionSpec())
+                staged = tuple(jax.device_put(a, repl) for a in staged)
+            pi, mu, cov, slot_labels, slot_counts = jax.block_until_ready(
+                staged)
         head_params, losses = H.train_head_from_gmms(
             k_head, pi, mu, cov, slot_labels, slot_counts,
             self.n_classes, self.head, state.cov_type)

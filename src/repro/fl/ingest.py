@@ -45,6 +45,7 @@ from typing import Callable, Dict, Iterable, List, Optional, Tuple
 
 import numpy as np
 
+from repro import obs
 from repro.core import gmm as G
 from repro.fl import planner as P
 
@@ -465,18 +466,19 @@ class IngestBroker:
     def _fold(self) -> None:
         if not self._pending:
             return
-        if self._state is None:
-            h = self._pending[0][1].header
-            self._state = IngestState.empty(
-                self.n_classes, h.cov_type, h.K, h.d,
-                self.cfg.capacity, self.cfg.seed)
-            self._track_peak()   # state arrays + full pending chunk coexist
-        self._state = fold_messages(self._state, self._pending,
-                                    self.samples_per_class)
-        self._pending = []
-        self._pending_bytes = 0
-        self.chunks_folded += 1
-        self._track_peak()
+        with obs.span("ingest.fold"):
+            if self._state is None:
+                h = self._pending[0][1].header
+                self._state = IngestState.empty(
+                    self.n_classes, h.cov_type, h.K, h.d,
+                    self.cfg.capacity, self.cfg.seed)
+                self._track_peak()   # state + full pending chunk coexist
+            self._state = fold_messages(self._state, self._pending,
+                                        self.samples_per_class)
+            self._pending = []
+            self._pending_bytes = 0
+            self.chunks_folded += 1
+            self._track_peak()
 
     # -- the callback surface -----------------------------------------------
 
@@ -488,6 +490,10 @@ class IngestBroker:
         > DUPLICATE > OVER_CAP, so a sealed round never inspects payloads
         and a corrupt duplicate can't burn its client's admission slot.
         """
+        with obs.span("ingest.submit", client=client_id):
+            return self._verdict(client_id, message)
+
+    def _verdict(self, client_id: int, message) -> str:
         if message.header.kind != "gmm":
             raise ValueError(
                 f"IngestBroker: client {client_id} sent a "

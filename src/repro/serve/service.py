@@ -36,6 +36,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import head as H
 from repro.fl import ingest as IG
 from repro.launch import aot_cache as AC
@@ -141,6 +142,8 @@ class FedPFTService:
             EXTRACT: collections.deque(), INFER: collections.deque()}
         self.rounds = 0
         self.steps = 0
+        self.positions_computed = 0    # Σ n_slots × bucket over steps
+        self.real_tokens = 0           # Σ prompt tokens admitted
         self._next_rid = 0
         self.completed: Dict[str, List[ServiceRequest]] = {
             EXTRACT: [], INFER: []}
@@ -250,40 +253,47 @@ class FedPFTService:
         — short rows are right-padded (masked mean ignores pads), unused
         rows carry length 0 (masked mean returns zeros).
         """
-        batch = self._admit()
-        if not batch:
-            return 0
-        t_admit = self.clock()
-        B, S = self.scfg.n_slots, self.scfg.max_seq
-        bucket = self._serve.pow2_bucket(
-            max(r.tokens.shape[0] for r in batch),
-            self.scfg.min_bucket, S)
-        tokens = np.zeros((B, bucket), dtype=np.int32)
-        length = np.zeros((B,), dtype=np.int32)
-        for i, r in enumerate(batch):
-            L = r.tokens.shape[0]
-            tokens[i, :L] = r.tokens
-            length[i] = L
-            r.t_admit = t_admit
-        feats = self._feats(self.params, jnp.asarray(tokens),
-                            jnp.asarray(length))
-        infer_rows = [i for i, r in enumerate(batch) if r.kind == INFER]
-        if infer_rows:
-            labels = jnp.argmax(
-                self._head_logits(self.head, feats), axis=-1)
-        feats_h = np.asarray(jax.device_get(feats))
-        labels_h = (np.asarray(jax.device_get(labels))
-                    if infer_rows else None)
-        t_done = self.clock()
-        for i, r in enumerate(batch):
-            if r.kind == EXTRACT:
-                r.feats = feats_h[i]
-            else:
-                r.label = int(labels_h[i])
-            r.t_done, r.done = t_done, True
-            self.completed[r.kind].append(r)
-        self.steps += 1
-        return len(batch)
+        with obs.span("service.step", step=self.steps):
+            with obs.span("service.admit"):
+                batch = self._admit()
+                if not batch:
+                    return 0
+                t_admit = self.clock()
+                B, S = self.scfg.n_slots, self.scfg.max_seq
+                bucket = self._serve.pow2_bucket(
+                    max(r.tokens.shape[0] for r in batch),
+                    self.scfg.min_bucket, S)
+                tokens = np.zeros((B, bucket), dtype=np.int32)
+                length = np.zeros((B,), dtype=np.int32)
+                for i, r in enumerate(batch):
+                    L = r.tokens.shape[0]
+                    tokens[i, :L] = r.tokens
+                    length[i] = L
+                    r.t_admit = t_admit
+            with obs.span("service.dispatch"):
+                feats = self._feats(self.params, jnp.asarray(tokens),
+                                    jnp.asarray(length))
+                infer_rows = [i for i, r in enumerate(batch)
+                              if r.kind == INFER]
+                if infer_rows:
+                    labels = jnp.argmax(
+                        self._head_logits(self.head, feats), axis=-1)
+            with obs.span("service.readback"):
+                feats_h = np.asarray(jax.device_get(feats))
+                labels_h = (np.asarray(jax.device_get(labels))
+                            if infer_rows else None)
+            t_done = self.clock()
+            for i, r in enumerate(batch):
+                if r.kind == EXTRACT:
+                    r.feats = feats_h[i]
+                else:
+                    r.label = int(labels_h[i])
+                r.t_done, r.done = t_done, True
+                self.completed[r.kind].append(r)
+            self.steps += 1
+            self.positions_computed += B * bucket
+            self.real_tokens += int(length.sum())
+            return len(batch)
 
     def drain(self) -> int:
         """Step until both queues are empty; returns requests completed."""
@@ -303,12 +313,14 @@ class FedPFTService:
         and extracts deferred past the old round's deadline re-enter the
         work queue against it.
         """
-        result = self.session.aggregate_from_broker(key, self.broker)
-        self.head = result.model
-        self.broker = self._fresh_broker()
-        self.rounds += 1
-        while self._deferred:
-            self.queues[EXTRACT].append(self._deferred.popleft())
+        with obs.span("service.close_round", round=self.rounds):
+            result = self.session.aggregate_from_broker(key, self.broker)
+            with obs.span("service.install"):
+                self.head = result.model
+                self.broker = self._fresh_broker()
+                self.rounds += 1
+                while self._deferred:
+                    self.queues[EXTRACT].append(self._deferred.popleft())
         return result
 
     def warmup(self, d: int) -> Dict:
@@ -332,8 +344,12 @@ class FedPFTService:
         return self._feats._cache_size()
 
     def stats(self) -> Dict:
-        """Throughput + latency per traffic class, broker accounting."""
+        """Throughput + latency per traffic class, broker accounting, and
+        the feature step's work: positions computed (``n_slots`` × bucket
+        per step) against the real prompt tokens among them."""
         out: Dict = {"steps": self.steps, "rounds": self.rounds,
+                     "positions_computed": self.positions_computed,
+                     "real_tokens": self.real_tokens,
                      "rejected_no_head": self.rejected_no_head,
                      "shed_extracts": self.shed_extracts,
                      "deferred_extracts": self.deferred_extracts,

@@ -345,6 +345,75 @@ def test_service_round_sanitized(model, sanitized):
     assert r.done and r.label is not None
 
 
+@pytest.mark.slow
+def test_service_spans_split_close_round_and_step(model):
+    """The recorder splits a warmed round's ``service.close_round`` into
+    fold, stage, program and install, which lie inside it and cover at
+    least 90% of it; a serving step holds admit, dispatch and readback in
+    that order; the feature step's work reaches ``stats()`` and the staged
+    bytes the round's ``info``."""
+    import time
+    from repro import obs
+    from repro.launch.aot_cache import ProgramCache
+    svc = _make_service(model, cache=ProgramCache())
+    rng = np.random.default_rng(17)
+    datasets = _extract_cohort(svc, rng)
+    svc.warmup(d=datasets[0][0].shape[-1])
+    key = jax.random.PRNGKey(18)
+    keys = jax.random.split(key, len(datasets) + 1)
+    msgs = [svc.session.client_update(keys[1 + i], f, y, i)
+            for i, (f, y) in enumerate(datasets)]
+    t0 = time.perf_counter_ns()
+    for i, m in enumerate(msgs):
+        assert svc.submit_update(i, m) == "admitted"
+    res = svc.close_round(keys[0])
+    svc.submit_extract(rng.integers(1, svc.cfg.vocab_size, size=7))
+    svc.submit_infer(rng.integers(1, svc.cfg.vocab_size, size=5))
+    assert svc.step() == 2
+
+    recs = obs.spans(since_ns=t0)
+    by_seq = {r.seq: r for r in recs}
+    submits = [r for r in recs if r.name == "ingest.submit"]
+    assert [r.ids["client"] for r in submits] == [0, 1, 2]
+    close = [r for r in recs if r.name == "service.close_round"]
+    assert len(close) == 1 and close[0].ids == {"round": 0}
+    close = close[0]
+    parts = {"ingest.fold", "round.stage", "round.program",
+             "service.install"}
+    inside = [r for r in recs if r.name in parts]
+    assert {r.name for r in inside} == parts
+    for r in inside:
+        assert close.t0_ns <= r.t0_ns <= r.t1_ns <= close.t1_ns, r.name
+        assert r.ids["round"] == 0
+        # each lies under close_round, directly or through the session
+        p = r.parent
+        while p is not None and p != close.seq:
+            p = by_seq[p].parent
+        assert p == close.seq, r.name
+    covered = sum(r.t1_ns - r.t0_ns for r in inside)
+    assert covered >= 0.9 * (close.t1_ns - close.t0_ns), \
+        (covered, close.t1_ns - close.t0_ns)
+
+    step = [r for r in recs if r.name == "service.step"][-1]
+    kids = sorted((r for r in recs if r.parent == step.seq),
+                  key=lambda r: r.t0_ns)
+    assert [r.name for r in kids] == ["service.admit", "service.dispatch",
+                                      "service.readback"]
+    assert all(r.ids == step.ids for r in kids)
+    for a, b in zip(kids, kids[1:]):
+        assert a.t1_ns <= b.t0_ns
+    st = svc.stats()
+    assert st["real_tokens"] == sum(r.tokens.shape[0] for reqs in
+                                    svc.completed.values() for r in reqs)
+    assert st["positions_computed"] % 4 == 0
+    assert st["positions_computed"] > st["real_tokens"]
+    # the staged stack: f32 π, μ, σ² at the canonical slot count, then
+    # int32 labels and the counts (int32 or int64)
+    M, _, K, d = res.info["compile"]["canonical"][:4]
+    floats = 4 * M * K * (1 + 2 * d)
+    assert floats + 8 * M <= res.info["staged_bytes"] <= floats + 12 * M
+
+
 def test_serve_dir_lint_clean():
     """`python -m repro.analysis src/repro/serve` gates clean — the serve
     layer holds the same hygiene bar as the rest of the tree."""
