@@ -126,7 +126,8 @@ def init_attention(key, cfg: ModelConfig, n_layers: int, dtype):
 def _sdpa_chunked(q, k, v, *, causal: bool, window: int,
                   q_positions: jax.Array, chunk: int = 512,
                   kv_positions: Optional[jax.Array] = None,
-                  kv_valid: Optional[jax.Array] = None):
+                  kv_valid: Optional[jax.Array] = None,
+                  segment_ids: Optional[jax.Array] = None):
     """Chunked (over queries) scaled-dot-product attention.
 
     q: (B, Sq, H, D); k, v: (B, Sk, Hkv, D). GQA via head grouping.
@@ -134,6 +135,8 @@ def _sdpa_chunked(q, k, v, *, causal: bool, window: int,
     window > 0 restricts attention to the last `window` key positions.
     kv_positions: absolute position of each key slot (for ring buffers);
     kv_valid: bool mask of populated slots.
+    segment_ids: (B, Sq) with Sq == Sk, for packed rows: a query attends
+    only to keys of its own segment (on top of the causal/window mask).
     """
     B, Sq, H, D = q.shape
     Sk, Hkv = k.shape[1], k.shape[2]
@@ -145,8 +148,9 @@ def _sdpa_chunked(q, k, v, *, causal: bool, window: int,
         kv_positions = jnp.arange(Sk)
     kv_positions = jnp.broadcast_to(kv_positions, (Sk,))
 
-    def attend_block(q_blk, q_pos):
-        # q_blk: (B, C, Hkv, G, D); q_pos: (C,) absolute query positions
+    def attend_block(q_blk, q_pos, q_seg=None):
+        # q_blk: (B, C, Hkv, G, D); q_pos: (C,) absolute query positions;
+        # q_seg: (B, C) segment ids of the queries (packed rows only)
         s = jnp.einsum("bchgd,bshd->bhgcs", q_blk.astype(jnp.float32),
                        k.astype(jnp.float32)) * scale
         mask = jnp.ones((q_blk.shape[1], Sk), dtype=bool)
@@ -157,7 +161,12 @@ def _sdpa_chunked(q, k, v, *, causal: bool, window: int,
             mask &= rel < window
         if kv_valid is not None:
             mask &= kv_valid[None, :]
-        s = jnp.where(mask[None, None, None], s, -1e30)
+        if q_seg is None:
+            mask = mask[None, None, None]
+        else:
+            mask = (mask[None] & (q_seg[:, :, None]
+                                  == segment_ids[:, None, :]))[:, None, None]
+        s = jnp.where(mask, s, -1e30)
         p = jax.nn.softmax(s, axis=-1)
         o = jnp.einsum("bhgcs,bshd->bchgd", p, v.astype(jnp.float32))
         return o.astype(v.dtype)
@@ -167,23 +176,35 @@ def _sdpa_chunked(q, k, v, *, causal: bool, window: int,
         chunk = Sq  # fall back to single block for ragged sizes
     n_chunks = Sq // chunk
     if n_chunks == 1:
-        out = attend_block(q, q_positions)
+        out = attend_block(q, q_positions, segment_ids)
     else:
         qs = q.reshape(B, n_chunks, chunk, Hkv, G, D).transpose(1, 0, 2, 3, 4, 5)
         pos = q_positions.reshape(n_chunks, chunk)
-        out = jax.lax.map(lambda args: attend_block(*args), (qs, pos))
+        blocks = (qs, pos)
+        if segment_ids is not None:
+            blocks += (segment_ids.reshape(B, n_chunks, chunk)
+                       .transpose(1, 0, 2),)
+        out = jax.lax.map(lambda args: attend_block(*args), blocks)
         out = out.transpose(1, 0, 2, 3, 4, 5).reshape(B, Sq, Hkv, G, D)
     return out.reshape(B, Sq, H, D)
 
 
 def attention(x, w, layer_cache, cfg: ModelConfig, *, positions,
-              window: int = 0, use_cache: bool = False):
+              window: int = 0, use_cache: bool = False,
+              segment_ids: Optional[jax.Array] = None):
     """Full attention layer: qkv proj + rope + sdpa + out proj.
 
     x: (B, S, d). positions: (S,) absolute positions of the input tokens.
     layer_cache: None or dict(k, v, pos) — updated functionally when
     use_cache. Returns (out, new_cache).
+
+    segment_ids: (B, S) for rows that pack several sequences (no cache).
+    Then ``positions`` is (B, S), each token's position within its own
+    segment, which rope uses; causal order along the row is causal order
+    within each segment, and the segment mask keeps the rest apart.
     """
+    if use_cache and segment_ids is not None:
+        raise ValueError("attention: packed segments take no KV cache")
     B, S, d = x.shape
     h, hk, dh = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
     q = (x @ w["wq"]).reshape(B, S, h, dh)
@@ -254,9 +275,13 @@ def attention(x, w, layer_cache, cfg: ModelConfig, *, positions,
             out = _sdpa_chunked(q, ak, av, causal=cfg.causal, window=window,
                                 q_positions=positions, kv_positions=kv_pos,
                                 kv_valid=kv_valid)
-    else:
+    elif segment_ids is None:
         out = _sdpa_chunked(q, k, v, causal=cfg.causal, window=window,
                             q_positions=positions, kv_positions=positions)
+    else:
+        out = _sdpa_chunked(q, k, v, causal=cfg.causal, window=window,
+                            q_positions=jnp.arange(S),
+                            segment_ids=segment_ids)
     return out.reshape(B, S, h * dh) @ w["wo"], new_cache
 
 

@@ -135,10 +135,11 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int,
 
 
 def _transformer_block(cfg: ModelConfig, x, w, cache_l, *, positions, window,
-                       use_cache):
+                       use_cache, segment_ids=None):
     xn = rms_norm(x, w["ln1"])
     attn_out, new_cache = attention(xn, w, cache_l, cfg, positions=positions,
-                                    window=window, use_cache=use_cache)
+                                    window=window, use_cache=use_cache,
+                                    segment_ids=segment_ids)
     x = x + attn_out
     xn2 = rms_norm(x, w["ln2"])
     if cfg.n_experts:
@@ -168,12 +169,12 @@ def _scan_stack(body, x, stacked, *, unroll: bool = False):
 
 
 def _run_transformer(cfg: ModelConfig, x, blocks, cache, *, positions,
-                     window, use_cache):
+                     window, use_cache, segment_ids=None):
     def body(carry, inp):
         w_l, cache_l = inp
         y, new_cache, aux = _transformer_block(
             cfg, carry, w_l, cache_l, positions=positions, window=window,
-            use_cache=use_cache)
+            use_cache=use_cache, segment_ids=segment_ids)
         return _constrain(y), (new_cache, aux)
     if cfg.remat:
         body = jax.checkpoint(body)
@@ -361,14 +362,28 @@ def loss_fn(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array],
 
 
 def final_hidden(cfg: ModelConfig, params: Params,
-                 batch: Dict[str, jax.Array]):
+                 batch: Dict[str, jax.Array],
+                 segment_ids: Optional[jax.Array] = None,
+                 positions: Optional[jax.Array] = None):
     """Post-norm final hidden states ``(B, S, d)`` — the pooling-free body
     of :func:`features`.  The serving layer pools these under a length
     mask (``serve.make_feature_step``) so right-padded batches extract
     exactly the unpadded features: every decode-capable family is causal
     (attention) or left-to-right (SSM/hybrid recurrence), so a position's
-    hidden state never depends on later pad tokens."""
-    x, positions = _embed_inputs(cfg, params, batch)
+    hidden state never depends on later pad tokens.
+
+    Packed rows: ``segment_ids`` ``(B, S)`` names each token's sequence
+    within its row and ``positions`` ``(B, S)`` its position within that
+    sequence.  Attention then stays inside each segment and rope counts
+    from each segment's start, so every segment's hidden states are those
+    of its sequence alone.  Only attention-stack families take them: a
+    recurrent state (ssm, hybrid) would carry one segment into the next.
+    Without them the traced program is the unpacked one."""
+    x, default_pos = _embed_inputs(cfg, params, batch)
+    positions = default_pos if positions is None else positions
+    if segment_ids is not None and cfg.family in ("ssm", "hybrid"):
+        raise ValueError(f"final_hidden: {cfg.family} carries recurrent "
+                         "state along a row — packed segments would mix")
     if cfg.family == "ssm":
         state = rwkv_mod.init_rwkv_state(cfg, x.shape[0])
         x, _ = _run_rwkv(cfg, x, params["blocks"], state, use_cache=False)
@@ -388,7 +403,7 @@ def final_hidden(cfg: ModelConfig, params: Params,
         cache_in["v"] = cache_in["k"]
         x, _, _ = _run_transformer(cfg, x, params["blocks"], cache_in,
                                    positions=positions, window=0,
-                                   use_cache=False)
+                                   use_cache=False, segment_ids=segment_ids)
     return rms_norm(x, params["final_norm"])
 
 

@@ -12,6 +12,7 @@ from typing import Any, Callable, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.models import model as M
 from repro.models.config import ModelConfig
@@ -116,28 +117,125 @@ def make_bucketed_prefill_step(cfg: ModelConfig, max_seq: int,
     return prefill
 
 
+def pad_batch(prompts, width: int, n_slots: int):
+    """One request per row: ``(n_slots, width)`` tokens, right-padded, and
+    ``(n_slots,)`` lengths; rows past the prompts carry length 0."""
+    tokens = np.zeros((n_slots, width), np.int32)
+    length = np.zeros((n_slots,), np.int32)
+    for i, p in enumerate(prompts):
+        tokens[i, :len(p)] = p
+        length[i] = len(p)
+    return tokens, length
+
+
+def pack_rows(lengths, width: int, n_slots: int):
+    """First-fit-decreasing placement of sequences into rows of ``width``.
+
+    Returns ``(row, R)``: ``row[i]`` is the row of sequence ``i`` and ``R``
+    the row count, the power of two at or above the rows used, at most
+    ``n_slots``.  Ties keep the input order, so a layout is a function of
+    the lengths alone."""
+    if len(lengths) > n_slots:
+        raise ValueError(f"pack_rows: {len(lengths)} sequences > n_slots="
+                         f"{n_slots}")
+    free: list = []
+    row = [0] * len(lengths)
+    for i in sorted(range(len(lengths)), key=lambda i: -lengths[i]):
+        if lengths[i] > width:
+            raise ValueError(f"pack_rows: length {lengths[i]} > width "
+                             f"{width}")
+        k = next((k for k, f in enumerate(free) if f >= lengths[i]),
+                 len(free))
+        if k == len(free):
+            free.append(width)
+        free[k] -= lengths[i]
+        row[i] = k
+    return row, min(1 << max(len(free) - 1, 0).bit_length(), n_slots)
+
+
+def pack_batch(prompts, width: int, n_slots: int):
+    """Several requests per row: ``(R, width)`` tokens and ``(R, n_slots)``
+    segment lengths, the layout a packing feature step takes.
+
+    Request ``i`` is column ``i``: its length sits in the one row that
+    holds it (:func:`pack_rows`) and is 0 in every other.  A row holds its
+    segments left to right in column order, then padding; rows past the
+    packed ones are all padding."""
+    row, R = pack_rows([len(p) for p in prompts], width, n_slots)
+    tokens = np.zeros((R, width), np.int32)
+    length = np.zeros((R, n_slots), np.int32)
+    fill = [0] * R
+    for i, (p, k) in enumerate(zip(prompts, row)):
+        tokens[k, fill[k]:fill[k] + len(p)] = p
+        length[k, i] = len(p)
+        fill[k] += len(p)
+    return tokens, length
+
+
+def segment_layout(length, width: int):
+    """Per-token segment id and position within the segment, ``(R, width)``
+    each, of a packed batch's ``(R, G)`` segment lengths.  Tokens past a
+    row's segments are padding: segment ``G``, which no column pools."""
+    ends = jnp.cumsum(length, axis=1)                         # (R, G)
+    p = jnp.arange(width)
+    seg = jnp.sum(p[None, :, None] >= ends[:, None, :], axis=-1)
+    starts = jnp.concatenate([ends - length, ends[:, -1:]], axis=1)
+    return seg, p[None, :] - jnp.take_along_axis(starts, seg, axis=1)
+
+
 def make_feature_step(cfg: ModelConfig) -> Callable:
     """Masked FedPFT feature extraction over right-padded token batches.
 
-    ``feats(params, tokens, length)`` — ``tokens`` is ``(B, S_b)``
-    right-padded, ``length`` a ``(B,)`` vector of real lengths.  Returns
-    the ``(B, d_model)`` mean-pooled final hidden state over the real
-    positions only: exactly ``model.features`` on the unpadded sequence,
-    because every decode-capable family is causal/left-to-right so pad
-    positions never influence real ones.  Rows with ``length == 0``
-    (admission padding in the service's fixed-batch step) return zeros.
+    ``feats(params, tokens, length)`` returns mean-pooled final hidden
+    states over the real positions only: exactly ``model.features`` of
+    each unpadded sequence.  ``length`` takes one of two layouts:
+
+    * ``(B,)``, one request per row of ``tokens`` ``(B, S_b)``; returns
+      ``(B, d_model)``.  Every decode-capable family is causal/left-to-right,
+      so pad positions never influence real ones.  Rows with
+      ``length == 0`` return zeros.
+    * ``(R, G)``, packed (:func:`pack_batch`): row ``r`` of ``tokens``
+      ``(R, S_b)`` holds, left to right, the segments of lengths
+      ``length[r]``, then padding; column ``g`` is one request, nonzero
+      in at most one row.  Returns ``(G, d_model)``, zeros for an empty
+      column.  The step derives each token's segment and its position
+      within it on the device (:func:`segment_layout`): rope counts from
+      the segment's start, attention stays inside the segment, and pooling
+      is a masked sum per segment in float32 (a matmul at HIGHEST
+      precision; the default would round the states to bfloat16 on TPU).
+
+    Packing is exact only where every sequence-mixing layer is attention,
+    so only the ``dense`` family packs, and ``feats.packs`` says so:
+    ``ssm`` and ``hybrid`` carry recurrent state along the row, which would
+    cross segments, and ``moe``'s capacity routing already makes features
+    depend on batch-mates, which sharing rows would make worse.
     """
     assert cfg.has_decode, (
         f"{cfg.name} is encoder-only: bidirectional attention mixes pad "
         "positions into real ones — serve unpadded batches instead")
+    packs = cfg.family == "dense"
 
     def feats(params, tokens, length):
-        h = M.final_hidden(cfg, params, {"tokens": tokens})
-        mask = jnp.arange(h.shape[1])[None, :] < length[:, None]
-        w = mask.astype(jnp.float32)[..., None]
-        return jnp.sum(h.astype(jnp.float32) * w, axis=1) / jnp.maximum(
-            length[:, None].astype(jnp.float32), 1.0)
+        if length.ndim == 1:
+            h = M.final_hidden(cfg, params, {"tokens": tokens})
+            mask = jnp.arange(h.shape[1])[None, :] < length[:, None]
+            w = mask.astype(jnp.float32)[..., None]
+            return jnp.sum(h.astype(jnp.float32) * w, axis=1) / jnp.maximum(
+                length[:, None].astype(jnp.float32), 1.0)
+        if not packs:
+            raise ValueError(f"make_feature_step: {cfg.family} does not "
+                             "pack — pass (B,) lengths")
+        seg, pos = segment_layout(length, tokens.shape[1])
+        h = M.final_hidden(cfg, params, {"tokens": tokens},
+                           segment_ids=seg, positions=pos)
+        onehot = (seg[..., None] == jnp.arange(length.shape[1])).astype(
+            jnp.float32)                                      # (R, S_b, G)
+        sums = jnp.einsum("rsg,rsd->gd", onehot, h.astype(jnp.float32),
+                          precision=jax.lax.Precision.HIGHEST)
+        return sums / jnp.maximum(
+            jnp.sum(length, axis=0)[:, None].astype(jnp.float32), 1.0)
 
+    feats.packs = packs
     return feats
 
 

@@ -15,9 +15,12 @@ the continuous-batching slot discipline of :class:`serve.server
 traffic-class aware: when both queues are non-empty, extraction is
 guaranteed ``ceil(extract_share · n_slots)`` rows and inference the rest;
 an under-full class backfills the other's rows, so neither class can
-starve the pool.  Every step lowers to the SAME jitted call — a
-``(n_slots, S_bucket)`` masked feature batch — so the compile count is
-bounded by the number of power-of-two prompt buckets, never by traffic.
+starve the pool.  A step's batch is ``S_bucket`` wide, the power-of-two
+bucket of its longest prompt.  Where the feature step packs (the
+``dense`` family, ``serve.make_feature_step``), the admitted prompts share
+rows: ``R`` rows, a power of two ≤ ``n_slots``; otherwise each takes a
+row of ``n_slots``.  So the compile count is bounded by the prompt
+buckets times the row counts, never by traffic.
 
 The round program sits behind the session's
 :class:`~repro.launch.aot_cache.ProgramCache`: :meth:`warmup` pre-compiles
@@ -134,7 +137,10 @@ class FedPFTService:
             cfg, params, session, scfg
         self.clock = clock
         self._serve = _serve
-        self._feats = jax.jit(_serve.make_feature_step(cfg))
+        step = _serve.make_feature_step(cfg)
+        self._layout = (_serve.pack_batch if getattr(step, "packs", False)
+                        else _serve.pad_batch)
+        self._feats = jax.jit(step)
         self._head_logits = jax.jit(H.head_logits)
         self.head: Optional[Dict] = None          # installed by close_round
         self.broker = self._fresh_broker()
@@ -142,7 +148,8 @@ class FedPFTService:
             EXTRACT: collections.deque(), INFER: collections.deque()}
         self.rounds = 0
         self.steps = 0
-        self.positions_computed = 0    # Σ n_slots × bucket over steps
+        self.rows_computed = 0         # Σ rows over steps
+        self.positions_computed = 0    # Σ rows × bucket over steps
         self.real_tokens = 0           # Σ prompt tokens admitted
         self._next_rid = 0
         self.completed: Dict[str, List[ServiceRequest]] = {
@@ -248,10 +255,19 @@ class FedPFTService:
     def step(self) -> int:
         """One serving step: admit, batch, extract, classify.
 
-        Returns the number of requests completed.  The device sees one
-        fixed-shape ``(n_slots, S_bucket)`` call whatever the traffic mix
-        — short rows are right-padded (masked mean ignores pads), unused
-        rows carry length 0 (masked mean returns zeros).
+        Returns the number of requests completed.  The batch is
+        ``S_bucket`` wide, the power-of-two bucket of the longest admitted
+        prompt.  For a packing feature step (the ``dense`` family) the
+        prompts are packed first-fit-decreasing into ``R`` rows, ``R`` the
+        power of two at or above the rows used (≤ ``n_slots``), and the
+        call takes ``(R, S_bucket)`` tokens and ``(R, n_slots)`` segment
+        lengths (``serve.pack_batch``): each prompt attends and pools only
+        within its own segment.  ``ssm`` and ``hybrid`` (recurrent state
+        would cross segments) and ``moe`` (capacity routing makes features
+        depend on batch-mates) keep one prompt per row: an ``(n_slots,
+        S_bucket)`` call, short rows right-padded, unused rows of length 0
+        (``serve.pad_batch``).  Either way request ``i`` gets feature row
+        ``i``.
         """
         with obs.span("service.step", step=self.steps):
             with obs.span("service.admit"):
@@ -259,16 +275,12 @@ class FedPFTService:
                 if not batch:
                     return 0
                 t_admit = self.clock()
-                B, S = self.scfg.n_slots, self.scfg.max_seq
                 bucket = self._serve.pow2_bucket(
                     max(r.tokens.shape[0] for r in batch),
-                    self.scfg.min_bucket, S)
-                tokens = np.zeros((B, bucket), dtype=np.int32)
-                length = np.zeros((B,), dtype=np.int32)
-                for i, r in enumerate(batch):
-                    L = r.tokens.shape[0]
-                    tokens[i, :L] = r.tokens
-                    length[i] = L
+                    self.scfg.min_bucket, self.scfg.max_seq)
+                tokens, length = self._layout([r.tokens for r in batch],
+                                              bucket, self.scfg.n_slots)
+                for r in batch:
                     r.t_admit = t_admit
             with obs.span("service.dispatch"):
                 feats = self._feats(self.params, jnp.asarray(tokens),
@@ -291,7 +303,8 @@ class FedPFTService:
                 r.t_done, r.done = t_done, True
                 self.completed[r.kind].append(r)
             self.steps += 1
-            self.positions_computed += B * bucket
+            self.rows_computed += tokens.shape[0]
+            self.positions_computed += tokens.size
             self.real_tokens += int(length.sum())
             return len(batch)
 
@@ -340,14 +353,18 @@ class FedPFTService:
     # -- introspection ------------------------------------------------------
 
     def feature_compiles(self) -> int:
-        """Compiled feature-step variants (≤ #prompt buckets)."""
+        """Compiled feature-step variants (≤ #prompt buckets × #row
+        counts)."""
         return self._feats._cache_size()
 
     def stats(self) -> Dict:
         """Throughput + latency per traffic class, broker accounting, and
-        the feature step's work: positions computed (``n_slots`` × bucket
-        per step) against the real prompt tokens among them."""
+        the feature step's work: rows and positions computed (rows ×
+        bucket per step) against the real prompt tokens among them.
+        Packing's engagement is ``1 − rows_computed ÷ (steps · n_slots)``.
+        """
         out: Dict = {"steps": self.steps, "rounds": self.rounds,
+                     "rows_computed": self.rows_computed,
                      "positions_computed": self.positions_computed,
                      "real_tokens": self.real_tokens,
                      "rejected_no_head": self.rejected_no_head,

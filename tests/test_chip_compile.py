@@ -136,6 +136,20 @@ def test_feature_step_compiles_full_width(one_chip):
     _fits(compiled)
 
 
+def test_packed_feature_step_compiles_full_width(one_chip):
+    """The same step over a packed batch: 4 rows × 512 tokens holding up
+    to 16 requests' segments, the shape most steps of the extraction
+    cells take."""
+    cfg = dataclasses.replace(get_config("granite-3-2b"), n_layers=2)
+    params = jax.tree.map(
+        lambda a: _sds(a.shape, a.dtype, one_chip),
+        jax.eval_shape(partial(M.init_params, cfg), jax.random.PRNGKey(0)))
+    compiled = jax.jit(serve.make_feature_step(cfg)).lower(
+        params, _sds((4, 512), jnp.int32, one_chip),
+        _sds((4, 16), jnp.int32, one_chip)).compile()
+    _fits(compiled)
+
+
 def test_mesh_transfer_compiles_on_four_chips(topo, tpu_dispatch):
     """The mesh round's client phase over four chips: sharded EM with the
     compiled kernel, then the all-gather that is the one-shot transfer."""
