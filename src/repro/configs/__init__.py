@@ -13,6 +13,7 @@ from repro.configs.rwkv6_3b import CONFIG as _rwkv
 from repro.configs.granite_3_2b import CONFIG as _granite2
 from repro.configs.granite_moe_3b_a800m import CONFIG as _granitemoe
 from repro.configs.zamba2_7b import CONFIG as _zamba
+from repro.configs.granite_4_h_micro import CONFIG as _granite4h
 from repro.configs.hubert_xlarge import CONFIG as _hubert
 from repro.configs.pixtral_12b import CONFIG as _pixtral
 
@@ -20,7 +21,7 @@ ARCHS: dict[str, ModelConfig] = {
     c.name: c
     for c in [
         _grok, _granite34, _nemotron, _yi, _rwkv,
-        _granite2, _granitemoe, _zamba, _hubert, _pixtral,
+        _granite2, _granitemoe, _zamba, _hubert, _pixtral, _granite4h,
     ]
 }
 

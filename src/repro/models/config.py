@@ -40,11 +40,17 @@ class ModelConfig:
     conv_width: int = 4
     chunk_size: int = 256            # chunked-scan chunk length
 
-    # --- hybrid (zamba2) ---
-    attn_every: int = 6              # shared attention block period
+    # --- hybrid ---
+    attn_every: int = 6              # zamba2: shared attention block period
+    # granite-4.0-h: one period of layer kinds, "m" a Mamba-2 mixer, "a"
+    # attention, each layer with weights of its own and an MLP after its
+    # mixer; "" is zamba2's layout (Mamba-2 stack + shared block)
+    layer_pattern: str = ""
 
     # --- attention ---
     rope_theta: float = 1e6
+    use_rope: bool = True            # False: no position embedding (NoPE)
+    attn_scale: float = 0.0          # softmax scale; 0 => 1/sqrt(head_dim)
     sliding_window: int = 0          # 0 => full attention; >0 => window size
     causal: bool = True              # False for encoder-only
 
@@ -62,11 +68,23 @@ class ModelConfig:
     scan_layers: bool = True
     logit_softcap: float = 0.0       # grok uses 30.0
 
+    # --- Granite multipliers and norms ---
+    embed_scale: float = 1.0         # embedding_multiplier
+    residual_scale: float = 1.0      # residual_multiplier: every branch
+    norm_eps: float = 1e-6           # every RMSNorm
+
     def __post_init__(self):
         assert self.family in ARCH_FAMILIES, self.family
         if self.head_dim is None:
             object.__setattr__(self, "head_dim", self.d_model // self.n_heads)
         assert self.n_heads % max(self.n_kv_heads, 1) == 0
+        if self.layer_pattern:
+            if (self.family != "hybrid" or set(self.layer_pattern) - {"m", "a"}
+                    or self.n_layers % len(self.layer_pattern)):
+                raise ValueError(f"{self.name}: layer_pattern "
+                                 f"{self.layer_pattern!r} needs the hybrid "
+                                 "family, kinds 'm'/'a' and n_layers a "
+                                 "multiple of its length")
 
     @property
     def is_attention_free(self) -> bool:
@@ -97,6 +115,9 @@ class ModelConfig:
         ne = self.n_experts
         if ne:
             ne = min(ne, 4 if n_experts is None else n_experts)
+        period = len(self.layer_pattern)
+        if period:      # whole periods, at least one
+            n_layers = period * max(1, n_layers // period)
         return dataclasses.replace(
             self,
             name=self.name + "-reduced",

@@ -9,6 +9,7 @@ Conventions:
 """
 from __future__ import annotations
 
+import functools
 import math
 from typing import Optional
 
@@ -127,7 +128,8 @@ def _sdpa_chunked(q, k, v, *, causal: bool, window: int,
                   q_positions: jax.Array, chunk: int = 512,
                   kv_positions: Optional[jax.Array] = None,
                   kv_valid: Optional[jax.Array] = None,
-                  segment_ids: Optional[jax.Array] = None):
+                  segment_ids: Optional[jax.Array] = None,
+                  scale: Optional[float] = None):
     """Chunked (over queries) scaled-dot-product attention.
 
     q: (B, Sq, H, D); k, v: (B, Sk, Hkv, D). GQA via head grouping.
@@ -137,11 +139,12 @@ def _sdpa_chunked(q, k, v, *, causal: bool, window: int,
     kv_valid: bool mask of populated slots.
     segment_ids: (B, Sq) with Sq == Sk, for packed rows: a query attends
     only to keys of its own segment (on top of the causal/window mask).
+    scale: the softmax scale, 1/√D unless given.
     """
     B, Sq, H, D = q.shape
     Sk, Hkv = k.shape[1], k.shape[2]
     G = H // Hkv
-    scale = 1.0 / math.sqrt(D)
+    scale = 1.0 / math.sqrt(D) if scale is None else scale
     q = q.reshape(B, Sq, Hkv, G, D)
 
     if kv_positions is None:
@@ -189,6 +192,7 @@ def _sdpa_chunked(q, k, v, *, causal: bool, window: int,
     return out.reshape(B, Sq, H, D)
 
 
+@jax.named_scope("attention")
 def attention(x, w, layer_cache, cfg: ModelConfig, *, positions,
               window: int = 0, use_cache: bool = False,
               segment_ids: Optional[jax.Array] = None):
@@ -202,6 +206,9 @@ def attention(x, w, layer_cache, cfg: ModelConfig, *, positions,
     Then ``positions`` is (B, S), each token's position within its own
     segment, which rope uses; causal order along the row is causal order
     within each segment, and the segment mask keeps the rest apart.
+
+    ``cfg.use_rope`` False is NoPE: positions enter only through the
+    causal mask.  ``cfg.attn_scale`` (when nonzero) is the softmax scale.
     """
     if use_cache and segment_ids is not None:
         raise ValueError("attention: packed segments take no KV cache")
@@ -210,8 +217,10 @@ def attention(x, w, layer_cache, cfg: ModelConfig, *, positions,
     q = (x @ w["wq"]).reshape(B, S, h, dh)
     k = (x @ w["wk"]).reshape(B, S, hk, dh)
     v = (x @ w["wv"]).reshape(B, S, hk, dh)
-    q = apply_rope(q, positions, cfg.rope_theta)
-    k = apply_rope(k, positions, cfg.rope_theta)
+    if cfg.use_rope:
+        q = apply_rope(q, positions, cfg.rope_theta)
+        k = apply_rope(k, positions, cfg.rope_theta)
+    sdpa = functools.partial(_sdpa_chunked, scale=cfg.attn_scale or None)
     if S > 1:
         # GQA K/V: when n_kv_heads doesn't divide the model axis, the TP
         # projection splits head_dim, which shards the QK contraction and
@@ -239,9 +248,9 @@ def attention(x, w, layer_cache, cfg: ModelConfig, *, positions,
                 kv_pos = latest_pos - ((latest_pos - slot_ids) % S_max)
                 kv_valid = kv_pos >= 0
                 new_cache = {"k": ck, "v": cv}
-                out = _sdpa_chunked(q, ck, cv, causal=cfg.causal,
-                                    window=window, q_positions=positions,
-                                    kv_positions=kv_pos, kv_valid=kv_valid)
+                out = sdpa(q, ck, cv, causal=cfg.causal,
+                           window=window, q_positions=positions,
+                           kv_positions=kv_pos, kv_valid=kv_valid)
             else:
                 # prefill chunk: EVERY query must see its own window, so
                 # attend over [old ring ∪ current chunk] — writing first
@@ -255,9 +264,9 @@ def attention(x, w, layer_cache, cfg: ModelConfig, *, positions,
                 kv_pos = jnp.concatenate([old_pos, positions])
                 kv_valid = jnp.concatenate(
                     [old_valid, jnp.ones((S,), bool)])
-                out = _sdpa_chunked(q, kv_k, kv_v, causal=cfg.causal,
-                                    window=window, q_positions=positions,
-                                    kv_positions=kv_pos, kv_valid=kv_valid)
+                out = sdpa(q, kv_k, kv_v, causal=cfg.causal,
+                           window=window, q_positions=positions,
+                           kv_positions=kv_pos, kv_valid=kv_valid)
                 slots = positions % S_max
                 ck = ck.at[:, slots].set(k)   # duplicate slots: last wins
                 cv = cv.at[:, slots].set(v)
@@ -272,16 +281,16 @@ def attention(x, w, layer_cache, cfg: ModelConfig, *, positions,
             # cache (§Perf iter 6); the returned cache keeps its decode
             # layout. S==1 decode attends the sharded cache directly.
             ak, av = (constrain(ck), constrain(cv)) if S > 1 else (ck, cv)
-            out = _sdpa_chunked(q, ak, av, causal=cfg.causal, window=window,
-                                q_positions=positions, kv_positions=kv_pos,
-                                kv_valid=kv_valid)
+            out = sdpa(q, ak, av, causal=cfg.causal, window=window,
+                       q_positions=positions, kv_positions=kv_pos,
+                       kv_valid=kv_valid)
     elif segment_ids is None:
-        out = _sdpa_chunked(q, k, v, causal=cfg.causal, window=window,
-                            q_positions=positions, kv_positions=positions)
+        out = sdpa(q, k, v, causal=cfg.causal, window=window,
+                   q_positions=positions, kv_positions=positions)
     else:
-        out = _sdpa_chunked(q, k, v, causal=cfg.causal, window=window,
-                            q_positions=jnp.arange(S),
-                            segment_ids=segment_ids)
+        out = sdpa(q, k, v, causal=cfg.causal, window=window,
+                   q_positions=jnp.arange(S),
+                   segment_ids=segment_ids)
     return out.reshape(B, S, h * dh) @ w["wo"], new_cache
 
 
@@ -303,6 +312,7 @@ def init_mlp(key, cfg: ModelConfig, n_layers: int, dtype, d_ff=None):
     return w
 
 
+@jax.named_scope("mlp")
 def mlp(x, w, cfg: ModelConfig):
     h = x @ w["w_in"]
     if cfg.mlp_variant == "swiglu":

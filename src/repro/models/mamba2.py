@@ -1,4 +1,4 @@
-"""Mamba2 (SSD) block — chunked scalar-decay state-space recurrence.
+"""Mamba2 (SSD) mixer — chunked scalar-decay state-space recurrence.
 
 Per head h with state S ∈ R^{N×P} (N = ssm_state, P = ssm_head_dim):
     S_t = a_t · S_{t-1} + (Δ_t B_t) x_tᵀ            a_t = exp(Δ_t · A_h), A_h < 0
@@ -8,17 +8,21 @@ Training/prefill uses the chunked parallel form (intra-chunk pairwise decay
 products in log space, inter-chunk state carried with ``lax.scan``) — the
 same factorization as the SSD paper, which keeps everything matmul-shaped
 for the MXU. Decode is the O(1) single-step recurrence.
+
+``mamba_mixer`` is the mixer alone (in_proj → causal depthwise conv → SSD →
+gated RMSNorm → out_proj), as both hybrids use it: zamba2's layer
+(``mamba_block``) wraps it in a pre-norm and a residual, a granite-4.0-h
+layer (``model._mixer_layer``) in its own norms, multipliers and MLP.
 """
 from __future__ import annotations
+
+import math
 
 import jax
 import jax.numpy as jnp
 
 from repro.models.config import ModelConfig
 from repro.models.layers import dense_init, rms_norm
-
-DT_MIN, DT_MAX = 1e-3, 1e-1  # softplus(dt_bias + dt_raw) clamp range
-
 
 def mamba_dims(cfg: ModelConfig):
     d_inner = cfg.ssm_expand * cfg.d_model
@@ -28,40 +32,57 @@ def mamba_dims(cfg: ModelConfig):
     return d_inner, H, P, N
 
 
-def init_mamba_block(key, cfg: ModelConfig, n_layers: int, dtype):
+def init_mamba_mixer(key, cfg: ModelConfig, n_layers: int, dtype):
+    """Stacked mixer weights.  A and Δ start as Mamba-2's: A_log = log h
+    for heads h = 1..H, and dt_bias = softplus⁻¹(Δ) for Δ log-uniform in
+    [1e-3, 1e-1]."""
     d = cfg.d_model
     d_inner, H, P, N = mamba_dims(cfg)
     conv_dim = d_inner + 2 * N * 1  # x, B, C streams share the conv (grouped)
     L = (n_layers,)
     ks = jax.random.split(key, 6)
+    dt = jnp.exp(jax.random.uniform(ks[3], L + (H,), jnp.float32,
+                                    math.log(1e-3), math.log(1e-1)))
     return {
-        "ln": jnp.ones(L + (d,), dtype),
         # in_proj → [z (gate), x, B, C, dt]
         "w_in": dense_init(ks[0], L + (d, 2 * d_inner + 2 * N + H), dtype),
         "conv_w": dense_init(ks[1], L + (cfg.conv_width, conv_dim), dtype,
                              scale=0.5),
         "conv_b": jnp.zeros(L + (conv_dim,), dtype),
         # per-head decay scale / dt bias / skip
-        "A_log": jnp.zeros(L + (H,), jnp.float32),        # A = -exp(A_log)
-        "dt_bias": jnp.full(L + (H,), -4.0, jnp.float32),  # softplus ≈ 0.018
+        "A_log": jnp.broadcast_to(                       # A = -exp(A_log)
+            jnp.log(jnp.arange(1, H + 1, dtype=jnp.float32)), L + (H,)),
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),        # softplus⁻¹(Δ)
         "D": jnp.ones(L + (H,), jnp.float32),
         "gn": jnp.ones(L + (d_inner,), dtype),
         "w_out": dense_init(ks[2], L + (d_inner, d), dtype),
     }
 
 
+def init_mamba_block(key, cfg: ModelConfig, n_layers: int, dtype):
+    """zamba2's layer: the mixer and its pre-norm."""
+    return {"ln": jnp.ones((n_layers, cfg.d_model), dtype),
+            **init_mamba_mixer(key, cfg, n_layers, dtype)}
+
+
 def ssd_chunked(x, a_log, B, C, S0, chunk: int = 256):
     """Chunked SSD. x: (Bt,H,T,P); a_log: (Bt,H,T) per-step log decay (≤0);
     B, C: (Bt,T,N) shared across heads; S0: (Bt,H,N,P).
 
-    Returns y (Bt,H,T,P) and the final state.
+    Returns y (Bt,H,T,P) and the final state.  A T that is not a multiple
+    of the chunk is padded at the end with steps that neither decay nor
+    write the state (x = 0, log a = 0), so y[:T] and the state are exact.
     """
     Bt, H, T, P = x.shape
     N = B.shape[-1]
     Cn = min(chunk, T)
-    if T % Cn:
-        Cn = T
-    n = T // Cn
+    pad = -T % Cn
+    if pad:
+        x = jnp.pad(x, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        a_log = jnp.pad(a_log, ((0, 0), (0, 0), (0, pad)))
+        B = jnp.pad(B, ((0, 0), (0, pad), (0, 0)))
+        C = jnp.pad(C, ((0, 0), (0, pad), (0, 0)))
+    n = (T + pad) // Cn
     xs = x.reshape(Bt, H, n, Cn, P).transpose(2, 0, 1, 3, 4)
     als = a_log.reshape(Bt, H, n, Cn).transpose(2, 0, 1, 3)
     Bs = B.reshape(Bt, n, Cn, N).transpose(1, 0, 2, 3)
@@ -91,7 +112,7 @@ def ssd_chunked(x, a_log, B, C, S0, chunk: int = 256):
         return S_new, y
 
     S_fin, ys = jax.lax.scan(step, S0.astype(jnp.float32), (xs, als, Bs, Cs))
-    y = ys.transpose(1, 2, 0, 3, 4).reshape(Bt, H, T, P)
+    y = ys.transpose(1, 2, 0, 3, 4).reshape(Bt, H, T + pad, P)[:, :, :T]
     return y.astype(x.dtype), S_fin
 
 
@@ -105,48 +126,74 @@ def ssd_decode(x, a_log, B, C, S0):
     return y.astype(x.dtype), S
 
 
-def mamba_block(cfg: ModelConfig, x, w, state, *, use_cache: bool):
-    """One Mamba2 layer. x: (Bt,T,d). state: dict(conv, S) ring-free:
-    conv: (Bt, conv_width-1, conv_dim) trailing inputs; S: (Bt,H,N,P)."""
-    Bt, T, d = x.shape
+def delta(dt_raw, dt_bias):
+    """Δ = softplus(dt + dt_bias) in float32, unclamped: Mamba-2's
+    time_step_limit is (0, ∞)."""
+    return jax.nn.softplus(dt_raw.astype(jnp.float32) + dt_bias)
+
+
+def gated_norm(y, z, gn, eps):
+    """Mamba-2's RMSNormGated with norm_before_gate=False: gate by silu(z)
+    first, then normalise over d_inner (one group), in float32."""
+    gated = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+    return rms_norm(gated, gn, eps).astype(y.dtype)
+
+
+def mamba_mixer(cfg: ModelConfig, xn, w, state, *, use_cache: bool):
+    """The Mamba-2 mixer over normed inputs xn: (Bt,T,d).  state: dict(conv,
+    S) ring-free: conv: (Bt, conv_width-1, conv_dim) trailing inputs;
+    S: (Bt,H,N,P).  Returns (y (Bt,T,d), new state)."""
+    Bt, T, d = xn.shape
     d_inner, H, P, N = mamba_dims(cfg)
-    xn = rms_norm(x, w["ln"])
-    proj = xn @ w["w_in"]
+    with jax.named_scope("mixer_in_proj"):
+        proj = xn @ w["w_in"]
     z, xi, Bv, Cv, dt_raw = jnp.split(
         proj, [d_inner, 2 * d_inner, 2 * d_inner + N, 2 * d_inner + 2 * N],
         axis=-1)
 
-    # depthwise causal conv over [x, B, C]
-    conv_in = jnp.concatenate([xi, Bv, Cv], axis=-1)      # (Bt,T,conv_dim)
-    Kw = cfg.conv_width
-    hist = state["conv"]                                  # (Bt,Kw-1,conv_dim)
-    padded = jnp.concatenate([hist.astype(conv_in.dtype), conv_in], axis=1)
-    kern = w["conv_w"]                                    # (Kw, conv_dim)
-    conv = sum(padded[:, i:i + T] * kern[i] for i in range(Kw))
-    conv = jax.nn.silu(conv + w["conv_b"])
-    new_conv_state = padded[:, -(Kw - 1):] if Kw > 1 else hist
-    xi, Bv, Cv = jnp.split(conv, [d_inner, d_inner + N], axis=-1)
+    with jax.named_scope("mixer_conv"):
+        # depthwise causal conv over [x, B, C]
+        conv_in = jnp.concatenate([xi, Bv, Cv], axis=-1)  # (Bt,T,conv_dim)
+        Kw = cfg.conv_width
+        hist = state["conv"]                              # (Bt,Kw-1,conv_dim)
+        padded = jnp.concatenate([hist.astype(conv_in.dtype), conv_in],
+                                 axis=1)
+        kern = w["conv_w"]                                # (Kw, conv_dim)
+        conv = sum(padded[:, i:i + T] * kern[i] for i in range(Kw))
+        conv = jax.nn.silu(conv + w["conv_b"])
+        new_conv_state = padded[:, -(Kw - 1):] if Kw > 1 else hist
+        xi, Bv, Cv = jnp.split(conv, [d_inner, d_inner + N], axis=-1)
 
-    dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + w["dt_bias"])
-    dt = jnp.clip(dt, DT_MIN, DT_MAX)                     # (Bt,T,H)
-    A = -jnp.exp(w["A_log"])                              # (H,)
-    a_log = (dt * A).transpose(0, 2, 1)                   # (Bt,H,T)
-    xh = xi.reshape(Bt, T, H, P).transpose(0, 2, 1, 3)    # (Bt,H,T,P)
-    # fold dt into the input (standard SSD parameterization)
-    xh_dt = xh * dt.transpose(0, 2, 1)[..., None].astype(xh.dtype)
+    with jax.named_scope("ssd"):
+        dt = delta(dt_raw, w["dt_bias"])                  # (Bt,T,H)
+        A = -jnp.exp(w["A_log"])                          # (H,)
+        a_log = (dt * A).transpose(0, 2, 1)               # (Bt,H,T)
+        xh = xi.reshape(Bt, T, H, P).transpose(0, 2, 1, 3)  # (Bt,H,T,P)
+        # fold dt into the input (standard SSD parameterization)
+        xh_dt = xh * dt.transpose(0, 2, 1)[..., None].astype(xh.dtype)
 
-    if T == 1 and use_cache:
-        y, S = ssd_decode(xh_dt[:, :, 0], a_log[:, :, 0], Bv[:, 0], Cv[:, 0],
-                          state["S"])
-        y = y[:, :, None, :]
-    else:
-        y, S = ssd_chunked(xh_dt, a_log, Bv, Cv, state["S"],
-                           chunk=cfg.chunk_size)
-    y = y + w["D"][None, :, None, None].astype(y.dtype) * xh
-    y = y.transpose(0, 2, 1, 3).reshape(Bt, T, d_inner)
-    y = rms_norm(y, w["gn"]) * jax.nn.silu(z)
-    out = x + y @ w["w_out"]
+        if T == 1 and use_cache:
+            y, S = ssd_decode(xh_dt[:, :, 0], a_log[:, :, 0], Bv[:, 0],
+                              Cv[:, 0], state["S"])
+            y = y[:, :, None, :]
+        else:
+            y, S = ssd_chunked(xh_dt, a_log, Bv, Cv, state["S"],
+                               chunk=cfg.chunk_size)
+        y = y + w["D"][None, :, None, None].astype(y.dtype) * xh
+        y = y.transpose(0, 2, 1, 3).reshape(Bt, T, d_inner)
+
+    with jax.named_scope("gated_norm"):
+        y = gated_norm(y, z, w["gn"], cfg.norm_eps)
+    with jax.named_scope("mixer_out_proj"):
+        out = y @ w["w_out"]
     return out, {"conv": new_conv_state, "S": S}
+
+
+def mamba_block(cfg: ModelConfig, x, w, state, *, use_cache: bool):
+    """zamba2's Mamba-2 layer: pre-norm mixer plus residual."""
+    y, new_state = mamba_mixer(cfg, rms_norm(x, w["ln"], cfg.norm_eps), w,
+                               state, use_cache=use_cache)
+    return x + y, new_state
 
 
 def init_mamba_state(cfg: ModelConfig, n_layers: int, batch: int):

@@ -16,11 +16,15 @@ Families:
   encoder           — bidirectional transformer, masked-prediction objective
   ssm               — RWKV6 stack (attention-free)
   hybrid            — Mamba2 stack + ONE shared attention block applied every
-                      ``attn_every`` layers (zamba2-style weight sharing)
+                      ``attn_every`` layers (zamba2-style weight sharing);
+                      or, with ``layer_pattern``, layers of their own kind
+                      period after period (granite-4.0-h): each a Mamba-2 or
+                      NoPE attention mixer, then an MLP, every branch scaled
+                      by ``residual_scale``
 """
 from __future__ import annotations
 
-import functools
+import itertools
 from typing import Any, Dict, Optional
 
 import jax
@@ -62,6 +66,15 @@ def _init_transformer_stack(key, cfg: ModelConfig, n_layers: int, dtype):
     return w
 
 
+def _init_mixer_stack(key, cfg: ModelConfig, n_layers: int, dtype):
+    """granite-4.0-h Mamba-2 layers: norm, mixer, norm, MLP."""
+    km, kf = jax.random.split(key)
+    return {"ln1": jnp.ones((n_layers, cfg.d_model), dtype),
+            "ln2": jnp.ones((n_layers, cfg.d_model), dtype),
+            "mixer": mamba_mod.init_mamba_mixer(km, cfg, n_layers, dtype),
+            **init_mlp(kf, cfg, n_layers, dtype)}
+
+
 def _init_shared_attn_block(key, cfg: ModelConfig, dtype):
     """Zamba2 shared block: one full transformer block, reused."""
     stacked = _init_transformer_stack(key, cfg, 1, dtype)
@@ -85,6 +98,11 @@ def init_params(cfg: ModelConfig, key) -> Params:
     if cfg.family == "ssm":
         p["blocks"] = rwkv_mod.init_rwkv_block(k_blocks, cfg, cfg.n_layers,
                                                dtype)
+    elif cfg.layer_pattern:
+        n_mix, n_attn = hybrid_counts(cfg)
+        p["blocks"] = {
+            "mamba": _init_mixer_stack(k_blocks, cfg, n_mix, dtype),
+            "attn": _init_transformer_stack(k_shared, cfg, n_attn, dtype)}
     elif cfg.family == "hybrid":
         p["blocks"] = mamba_mod.init_mamba_block(k_blocks, cfg, cfg.n_layers,
                                                  dtype)
@@ -108,8 +126,23 @@ def _kv_shape(cfg: ModelConfig, n, batch, max_seq, window):
     return (n, batch, S, cfg.n_kv_heads, cfg.head_dim)
 
 
-def n_shared_uses(cfg: ModelConfig) -> int:
-    return cfg.n_layers // cfg.attn_every
+def hybrid_counts(cfg: ModelConfig):
+    """(Mamba-2 layers, attention uses) of a hybrid: zamba2 runs its shared
+    block after every ``attn_every`` layers; a ``layer_pattern`` counts
+    its kinds over the periods."""
+    if cfg.layer_pattern:
+        periods = cfg.n_layers // len(cfg.layer_pattern)
+        return (periods * cfg.layer_pattern.count("m"),
+                periods * cfg.layer_pattern.count("a"))
+    return cfg.n_layers, cfg.n_layers // cfg.attn_every
+
+
+def _hybrid_cache(cfg: ModelConfig, batch: int, max_seq: int, window: int,
+                  dtype):
+    n_mix, n_attn = hybrid_counts(cfg)
+    kv = _kv_shape(cfg, n_attn, batch, max_seq, window)
+    return {"mamba": mamba_mod.init_mamba_state(cfg, n_mix, batch),
+            "kv": {"k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype)}}
 
 
 def init_cache(cfg: ModelConfig, batch: int, max_seq: int,
@@ -119,12 +152,7 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int,
     if cfg.family == "ssm":
         return rwkv_mod.init_rwkv_state(cfg, batch)
     if cfg.family == "hybrid":
-        cache = mamba_mod.init_mamba_state(cfg, cfg.n_layers, batch)
-        n_uses = n_shared_uses(cfg)
-        kv = _kv_shape(cfg, n_uses, batch, max_seq, window)
-        return {"mamba": cache,
-                "shared_kv": {"k": jnp.zeros(kv, dtype),
-                              "v": jnp.zeros(kv, dtype)}}
+        return _hybrid_cache(cfg, batch, max_seq, window, dtype)
     kv = _kv_shape(cfg, cfg.n_layers, batch, max_seq, window)
     return {"k": jnp.zeros(kv, dtype), "v": jnp.zeros(kv, dtype)}
 
@@ -134,19 +162,38 @@ def init_cache(cfg: ModelConfig, batch: int, max_seq: int,
 # ---------------------------------------------------------------------------
 
 
-def _transformer_block(cfg: ModelConfig, x, w, cache_l, *, positions, window,
-                       use_cache, segment_ids=None):
-    xn = rms_norm(x, w["ln1"])
-    attn_out, new_cache = attention(xn, w, cache_l, cfg, positions=positions,
-                                    window=window, use_cache=use_cache,
-                                    segment_ids=segment_ids)
-    x = x + attn_out
-    xn2 = rms_norm(x, w["ln2"])
+def _residual(cfg: ModelConfig, x, y):
+    return x + (y if cfg.residual_scale == 1.0 else y * cfg.residual_scale)
+
+
+def _ffn(cfg: ModelConfig, x, w):
+    """A layer's second half: pre-norm MLP (or MoE) and its residual."""
+    xn2 = rms_norm(x, w["ln2"], cfg.norm_eps)
     if cfg.n_experts:
         y, aux = moe(xn2, w, cfg)
     else:
         y, aux = mlp(xn2, w, cfg), jnp.zeros((), jnp.float32)
-    return x + y, new_cache, aux
+    return _residual(cfg, x, y), aux
+
+
+def _transformer_block(cfg: ModelConfig, x, w, cache_l, *, positions, window,
+                       use_cache, segment_ids=None):
+    xn = rms_norm(x, w["ln1"], cfg.norm_eps)
+    attn_out, new_cache = attention(xn, w, cache_l, cfg, positions=positions,
+                                    window=window, use_cache=use_cache,
+                                    segment_ids=segment_ids)
+    x, aux = _ffn(cfg, _residual(cfg, x, attn_out), w)
+    return x, new_cache, aux
+
+
+def _mixer_layer(cfg: ModelConfig, x, w, state_l, *, use_cache):
+    """A granite-4.0-h Mamba-2 layer: the attention layer's shape with the
+    Mamba-2 mixer in attention's place."""
+    y, new_state = mamba_mod.mamba_mixer(
+        cfg, rms_norm(x, w["ln1"], cfg.norm_eps), w["mixer"], state_l,
+        use_cache=use_cache)
+    x, _ = _ffn(cfg, _residual(cfg, x, y), w)
+    return x, new_state
 
 
 def _scan_stack(body, x, stacked, *, unroll: bool = False):
@@ -196,18 +243,81 @@ def _run_rwkv(cfg: ModelConfig, x, blocks, state, *, use_cache):
     return x, new_state
 
 
+def _run_pattern(cfg: ModelConfig, x, blocks, cache, *, positions, window,
+                 use_cache):
+    """granite-4.0-h: ``cfg.layer_pattern`` period after period.  Scans
+    over the periods; inside one, over each run of consecutive layers of
+    one kind.  Layer stacks and their states keep one flat layer axis per
+    kind (``blocks["mamba"]``, ``blocks["attn"]``) in layer order."""
+    pat = cfg.layer_pattern
+    periods = cfg.n_layers // len(pat)
+    runs = [(kind, len(list(g))) for kind, g in itertools.groupby(pat)]
+
+    def by_period(tree, kind):
+        per = pat.count(kind)
+        return jax.tree.map(
+            lambda a: a.reshape((periods, per) + a.shape[1:]), tree)
+
+    def flat(tree):
+        return jax.tree.map(
+            lambda a: a.reshape((a.shape[0] * a.shape[1],) + a.shape[2:]),
+            tree)
+
+    def mixer_body(carry, inp):
+        y, new_st = _mixer_layer(cfg, carry, *inp, use_cache=use_cache)
+        return _constrain(y), new_st
+
+    def attn_body(carry, inp):
+        y, new_kv, _ = _transformer_block(
+            cfg, carry, *inp, positions=positions, window=window,
+            use_cache=use_cache)
+        return _constrain(y), new_kv
+    if cfg.remat:
+        mixer_body = jax.checkpoint(mixer_body)
+        attn_body = jax.checkpoint(attn_body)
+    bodies = {"m": mixer_body, "a": attn_body}
+
+    def period(x, inp):
+        stacks = {"m": (inp[0], inp[1]), "a": (inp[2], inp[3])}
+        done = {"m": [], "a": []}
+        at = {"m": 0, "a": 0}
+        for kind, n in runs:
+            run = jax.tree.map(lambda a: a[at[kind]:at[kind] + n],
+                               stacks[kind])
+            x, new = _scan_stack(bodies[kind], x, run,
+                                 unroll=not cfg.scan_layers)
+            done[kind].append(new)
+            at[kind] += n
+        cat = {k: jax.tree.map(lambda *a: jnp.concatenate(a), *v)
+               for k, v in done.items()}
+        return x, (cat["m"], cat["a"])
+
+    x, (new_st, new_kv) = _scan_stack(
+        period, x, (by_period(blocks["mamba"], "m"),
+                    by_period(cache["mamba"], "m"),
+                    by_period(blocks["attn"], "a"),
+                    by_period(cache["kv"], "a")),
+        unroll=not cfg.scan_layers)
+    return x, {"mamba": flat(new_st), "kv": flat(new_kv)}
+
+
 def _run_hybrid(cfg: ModelConfig, x, params, cache, *, positions, window,
                 use_cache):
     """Mamba2 stack with the shared attention block every ``attn_every``
     layers. Layer l counts 0-based; the shared block runs after layers
-    attn_every-1, 2·attn_every-1, … (n_uses times)."""
+    attn_every-1, 2·attn_every-1, … (n_uses times).  With a
+    ``layer_pattern``: :func:`_run_pattern`."""
+    if cfg.layer_pattern:
+        return _run_pattern(cfg, x, params["blocks"], cache,
+                            positions=positions, window=window,
+                            use_cache=use_cache)
     A = cfg.attn_every
     n_uses = cfg.n_layers // A
     tail = cfg.n_layers - n_uses * A
     blocks = params["blocks"]
     shared = params["shared_attn"]
     mamba_state = cache["mamba"]
-    shared_kv = cache["shared_kv"]
+    shared_kv = cache["kv"]
 
     def mamba_body(carry, inp):
         w_l, st_l = inp
@@ -251,7 +361,7 @@ def _run_hybrid(cfg: ModelConfig, x, params, cache, *, positions, window,
             new_tail_st)
     else:
         new_state = new_main_st
-    return x, {"mamba": new_state, "shared_kv": new_kv}
+    return x, {"mamba": new_state, "kv": new_kv}
 
 
 # ---------------------------------------------------------------------------
@@ -268,6 +378,8 @@ def _embed_inputs(cfg: ModelConfig, params, batch):
             x = jnp.where(m, params["mask_emb"].astype(x.dtype), x)
         return x, jnp.arange(x.shape[1])
     tok = params["embed"][batch["tokens"]]
+    if cfg.embed_scale != 1.0:
+        tok = tok * cfg.embed_scale
     if cfg.family == "vlm" and "img" in batch:
         img = batch["img"].astype(_dtype(cfg)) @ params["img_proj"]
         x = jnp.concatenate([img, tok], axis=1)
@@ -296,14 +408,8 @@ def forward(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array], *,
         x, new_cache = _run_rwkv(cfg, x, params["blocks"], state,
                                  use_cache=use_cache)
     elif cfg.family == "hybrid":
-        st = cache if cache is not None else {
-            "mamba": mamba_mod.init_mamba_state(cfg, cfg.n_layers, B),
-            "shared_kv": {
-                "k": jnp.zeros(_kv_shape(cfg, n_shared_uses(cfg), B, S,
-                                         window), x.dtype),
-                "v": jnp.zeros(_kv_shape(cfg, n_shared_uses(cfg), B, S,
-                                         window), x.dtype)},
-        }
+        st = cache if cache is not None else _hybrid_cache(cfg, B, S, window,
+                                                           x.dtype)
         x, new_cache = _run_hybrid(cfg, x, params, st, positions=positions,
                                    window=window, use_cache=use_cache)
     else:
@@ -319,7 +425,7 @@ def forward(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array], *,
         if cache is None:
             new_cache = None
 
-    x = rms_norm(x, params["final_norm"])
+    x = rms_norm(x, params["final_norm"], cfg.norm_eps)
     logits = _constrain((x @ params["lm_head"]).astype(jnp.float32),
                         "logits")
     if cfg.logit_softcap:
@@ -369,15 +475,18 @@ def final_hidden(cfg: ModelConfig, params: Params,
     of :func:`features`.  The serving layer pools these under a length
     mask (``serve.make_feature_step``) so right-padded batches extract
     exactly the unpadded features: every decode-capable family is causal
-    (attention) or left-to-right (SSM/hybrid recurrence), so a position's
-    hidden state never depends on later pad tokens.
+    (attention) or left-to-right (the RWKV and Mamba-2 recurrences and the
+    Mamba-2 causal conv, in both hybrid layouts: zamba2's shared block and
+    granite-4.0-h's layer pattern), so a position's hidden state never
+    depends on later pad tokens.
 
     Packed rows: ``segment_ids`` ``(B, S)`` names each token's sequence
     within its row and ``positions`` ``(B, S)`` its position within that
     sequence.  Attention then stays inside each segment and rope counts
     from each segment's start, so every segment's hidden states are those
     of its sequence alone.  Only attention-stack families take them: a
-    recurrent state (ssm, hybrid) would carry one segment into the next.
+    recurrent state (ssm, and either hybrid: its Mamba-2 state and conv
+    history) would carry one segment into the next.
     Without them the traced program is the unpacked one."""
     x, default_pos = _embed_inputs(cfg, params, batch)
     positions = default_pos if positions is None else positions
@@ -388,13 +497,7 @@ def final_hidden(cfg: ModelConfig, params: Params,
         state = rwkv_mod.init_rwkv_state(cfg, x.shape[0])
         x, _ = _run_rwkv(cfg, x, params["blocks"], state, use_cache=False)
     elif cfg.family == "hybrid":
-        B, S = x.shape[:2]
-        st = {"mamba": mamba_mod.init_mamba_state(cfg, cfg.n_layers, B),
-              "shared_kv": {
-                  "k": jnp.zeros(_kv_shape(cfg, n_shared_uses(cfg), B, S, 0),
-                                 x.dtype),
-                  "v": jnp.zeros(_kv_shape(cfg, n_shared_uses(cfg), B, S, 0),
-                                 x.dtype)}}
+        st = _hybrid_cache(cfg, x.shape[0], x.shape[1], 0, x.dtype)
         x, _ = _run_hybrid(cfg, x, params, st, positions=positions, window=0,
                            use_cache=False)
     else:
@@ -404,7 +507,7 @@ def final_hidden(cfg: ModelConfig, params: Params,
         x, _, _ = _run_transformer(cfg, x, params["blocks"], cache_in,
                                    positions=positions, window=0,
                                    use_cache=False, segment_ids=segment_ids)
-    return rms_norm(x, params["final_norm"])
+    return rms_norm(x, params["final_norm"], cfg.norm_eps)
 
 
 def features(cfg: ModelConfig, params: Params, batch: Dict[str, jax.Array]):

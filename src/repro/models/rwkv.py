@@ -111,7 +111,7 @@ def rwkv_block(cfg: ModelConfig, x, w, state, *, use_cache: bool):
     B, T, d = x.shape
     H, Dh = cfg.n_heads, cfg.ssm_head_dim
     # ---- time mix ----
-    xn = rms_norm(x, w["ln1"])
+    xn = rms_norm(x, w["ln1"], cfg.norm_eps)
     prev = _token_shift(xn, state["sx_tm"].astype(xn.dtype))
     def lerp(mix):
         return xn + (prev - xn) * mix
@@ -134,11 +134,11 @@ def rwkv_block(cfg: ModelConfig, x, w, state, *, use_cache: bool):
         o, S = wkv6_chunked(r, k, v, lw, w["u"], state["S"],
                             chunk=cfg.chunk_size)
     o = o.transpose(0, 2, 1, 3).reshape(B, T, d)
-    o = rms_norm(o, w["gn"]) * g
+    o = rms_norm(o, w["gn"], cfg.norm_eps) * g
     x = x + o @ w["wo"]
 
     # ---- channel mix ----
-    xn2 = rms_norm(x, w["ln2"])
+    xn2 = rms_norm(x, w["ln2"], cfg.norm_eps)
     prev2 = _token_shift(xn2, state["sx_cm"].astype(xn2.dtype))
     xc = xn2 + (prev2 - xn2) * w["mix_c"]
     h = jnp.square(jax.nn.relu(xc @ w["wc_in"]))

@@ -206,8 +206,10 @@ def make_feature_step(cfg: ModelConfig) -> Callable:
 
     Packing is exact only where every sequence-mixing layer is attention,
     so only the ``dense`` family packs, and ``feats.packs`` says so:
-    ``ssm`` and ``hybrid`` carry recurrent state along the row, which would
-    cross segments, and ``moe``'s capacity routing already makes features
+    ``ssm`` and ``hybrid`` (zamba2's Mamba-2 stack with a shared block,
+    and granite-4.0-h's Mamba-2 and NoPE attention layers alike) carry
+    recurrent state and conv history along the row, which would cross
+    segments, and ``moe``'s capacity routing already makes features
     depend on batch-mates, which sharing rows would make worse.
     """
     assert cfg.has_decode, (

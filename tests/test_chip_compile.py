@@ -150,6 +150,23 @@ def test_packed_feature_step_compiles_full_width(one_chip):
     _fits(compiled)
 
 
+def test_hybrid_feature_step_compiles_full_width(one_chip):
+    """granite-4-h-micro's feature step at its published width, one whole
+    period (10 layers: 9 Mamba-2 mixers, attention at index 5) over the
+    16-slot × 512-token batch its extraction cell steps: the SSD's
+    (16, 64, 256, 256) decay blocks and the NoPE attention fit one chip."""
+    cfg = dataclasses.replace(get_config("granite-4-h-micro"), n_layers=10,
+                              remat=False)
+    params = jax.tree.map(
+        lambda a: _sds(a.shape, a.dtype, one_chip),
+        jax.eval_shape(partial(M.init_params, cfg), jax.random.PRNGKey(0)))
+    assert params["blocks"]["mamba"]["mixer"]["w_in"].shape == (9, D, 8512)
+    compiled = jax.jit(serve.make_feature_step(cfg)).lower(
+        params, _sds((16, 512), jnp.int32, one_chip),
+        _sds((16,), jnp.int32, one_chip)).compile()
+    _fits(compiled)
+
+
 def test_mesh_transfer_compiles_on_four_chips(topo, tpu_dispatch):
     """The mesh round's client phase over four chips: sharded EM with the
     compiled kernel, then the all-gather that is the one-shot transfer."""

@@ -202,6 +202,7 @@ def _observe(svc):
 
 
 @pytest.mark.parametrize("name", ["rwkv6-3b", "zamba2-7b",
+                                  "granite-4-h-micro",
                                   "granite-moe-3b-a800m"])
 def test_recurrent_and_moe_families_keep_one_request_per_row(name):
     cfg = get_config(name).reduced()
