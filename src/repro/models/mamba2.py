@@ -65,13 +65,20 @@ def init_mamba_block(key, cfg: ModelConfig, n_layers: int, dtype):
             **init_mamba_mixer(key, cfg, n_layers, dtype)}
 
 
-def ssd_chunked(x, a_log, B, C, S0, chunk: int = 256):
+def ssd_chunked(x, a_log, B, C, S0, chunk: int = 256, segment_ids=None):
     """Chunked SSD. x: (Bt,H,T,P); a_log: (Bt,H,T) per-step log decay (≤0);
     B, C: (Bt,T,N) shared across heads; S0: (Bt,H,N,P).
 
     Returns y (Bt,H,T,P) and the final state.  A T that is not a multiple
     of the chunk is padded at the end with steps that neither decay nor
     write the state (x = 0, log a = 0), so y[:T] and the state are exact.
+
+    ``segment_ids`` (Bt,T), contiguous runs along each row: the recurrence
+    restarts from a zero state at every segment start (S0 enters the
+    first segment), and the final state is the last segment's.  Masks
+    keep each term inside its segment — the decay pairs (t, s), the
+    entering state and the carried one — so no log-decay sentinel is
+    needed.  Without them the traced program is the unsegmented one.
     """
     Bt, H, T, P = x.shape
     N = B.shape[-1]
@@ -82,14 +89,20 @@ def ssd_chunked(x, a_log, B, C, S0, chunk: int = 256):
         a_log = jnp.pad(a_log, ((0, 0), (0, 0), (0, pad)))
         B = jnp.pad(B, ((0, 0), (0, pad), (0, 0)))
         C = jnp.pad(C, ((0, 0), (0, pad), (0, 0)))
+        if segment_ids is not None:
+            segment_ids = jnp.pad(segment_ids, ((0, 0), (0, pad)),
+                                  mode="edge")
     n = (T + pad) // Cn
     xs = x.reshape(Bt, H, n, Cn, P).transpose(2, 0, 1, 3, 4)
     als = a_log.reshape(Bt, H, n, Cn).transpose(2, 0, 1, 3)
     Bs = B.reshape(Bt, n, Cn, N).transpose(1, 0, 2, 3)
     Cs = C.reshape(Bt, n, Cn, N).transpose(1, 0, 2, 3)
+    segmented = segment_ids is not None
 
-    def step(S, inp):
-        xc, alc, Bc, Cc = inp           # (Bt,H,Cn,P),(Bt,H,Cn),(Bt,Cn,N)×2
+    def step(carry, inp):
+        # carry: S, and with segments the segment the chunk is entered in
+        S, prev = carry if segmented else (carry, None)
+        xc, alc, Bc, Cc = inp[:4]       # (Bt,H,Cn,P),(Bt,H,Cn),(Bt,Cn,N)×2
         xc = xc.astype(jnp.float32)
         Bc = Bc.astype(jnp.float32)
         Cc = Cc.astype(jnp.float32)
@@ -97,21 +110,43 @@ def ssd_chunked(x, a_log, B, C, S0, chunk: int = 256):
         # intra-chunk: y_t += Σ_{s≤t} e^{cw_t - cw_s} (C_t·B_s) x_s
         expo = cw[..., :, None] - cw[..., None, :]        # (Bt,H,Cn,Cn)
         tri = jnp.arange(Cn)[:, None] >= jnp.arange(Cn)[None, :]
-        G = jnp.where(tri[None, None], jnp.exp(expo), 0.0)
+        pairs = tri[None, None]
+        if segmented:
+            sc = inp[4]                                   # (Bt,Cn)
+            pairs = pairs & (sc[:, :, None] == sc[:, None, :])[:, None]
+        G = jnp.where(pairs, jnp.exp(expo), 0.0)
         CB = jnp.einsum("btn,bsn->bts", Cc, Bc)           # (Bt,Cn,Cn)
         M = G * CB[:, None]                               # (Bt,H,Cn,Cn)
         y = jnp.einsum("bhts,bhsp->bhtp", M, xc)
         # inter-chunk: y_t += C_t e^{cw_t} S0
         Cdec = Cc[:, None] * jnp.exp(cw)[..., None]       # (Bt,H,Cn,N)
+        if segmented:
+            # only the entering segment reads S; segments are contiguous,
+            # so cw_t then spans that segment's steps alone
+            Cdec = Cdec * (sc == prev[:, None])[:, None, :, None]
         y += jnp.einsum("bhtn,bhnp->bhtp", Cdec, S)
         # state: S' = e^{cw_last} S + Σ_s e^{cw_last - cw_s} B_s x_sᵀ
         last = cw[..., -1:]                               # (Bt,H,1)
         Bdec = Bc[:, None] * jnp.exp(last[..., None] - cw[..., None])
-        S_new = jnp.exp(last)[..., None] * S + \
+        carried = jnp.exp(last)[..., None]                # (Bt,H,1,1)
+        if segmented:
+            # the last token's segment alone writes S'; S carries on only
+            # if that segment entered the chunk
+            end = sc[:, -1]
+            Bdec = Bdec * (sc == end[:, None])[:, None, :, None]
+            carried = carried * (end == prev)[:, None, None, None]
+        S_new = carried * S + \
             jnp.einsum("bhsn,bhsp->bhnp", Bdec, xc)
-        return S_new, y
+        return ((S_new, end) if segmented else S_new), y
 
-    S_fin, ys = jax.lax.scan(step, S0.astype(jnp.float32), (xs, als, Bs, Cs))
+    inputs = (xs, als, Bs, Cs)
+    S_init = S0.astype(jnp.float32)
+    if segmented:
+        inputs += (segment_ids.reshape(Bt, n, Cn).transpose(1, 0, 2),)
+        S_init = (S_init, segment_ids[:, 0])
+    S_fin, ys = jax.lax.scan(step, S_init, inputs)
+    if segmented:
+        S_fin = S_fin[0]
     y = ys.transpose(1, 2, 0, 3, 4).reshape(Bt, H, T + pad, P)[:, :, :T]
     return y.astype(x.dtype), S_fin
 
@@ -139,10 +174,30 @@ def gated_norm(y, z, gn, eps):
     return rms_norm(gated, gn, eps).astype(y.dtype)
 
 
-def mamba_mixer(cfg: ModelConfig, xn, w, state, *, use_cache: bool):
+def causal_conv(padded, kern, T: int, positions=None):
+    """Depthwise causal conv: out_t = Σ_i kern_i · padded_{t+i}, padded the
+    (Kw-1)-step history then the T inputs.  Tap i reaches Kw-1-i steps
+    back; with ``positions`` (Bt,T), each token's position within its
+    segment, it contributes only where that step is in the same segment."""
+    Kw = kern.shape[0]
+    if positions is None:
+        return sum(padded[:, i:i + T] * kern[i] for i in range(Kw))
+    return sum(jnp.where((positions >= Kw - 1 - i)[..., None],
+                         padded[:, i:i + T], 0) * kern[i] for i in range(Kw))
+
+
+def mamba_mixer(cfg: ModelConfig, xn, w, state, *, use_cache: bool,
+                segment_ids=None, positions=None):
     """The Mamba-2 mixer over normed inputs xn: (Bt,T,d).  state: dict(conv,
     S) ring-free: conv: (Bt, conv_width-1, conv_dim) trailing inputs;
-    S: (Bt,H,N,P).  Returns (y (Bt,T,d), new state)."""
+    S: (Bt,H,N,P).  Returns (y (Bt,T,d), new state).
+
+    Packed rows (no cache): ``segment_ids`` (Bt,T) names each token's
+    sequence and ``positions`` (Bt,T) its position within it; the conv
+    reads no history across a segment start and the SSD restarts from a
+    zero state there, so each segment mixes as if alone."""
+    if use_cache and segment_ids is not None:
+        raise ValueError("mamba_mixer: packed segments take no cache")
     Bt, T, d = xn.shape
     d_inner, H, P, N = mamba_dims(cfg)
     with jax.named_scope("mixer_in_proj"):
@@ -159,7 +214,8 @@ def mamba_mixer(cfg: ModelConfig, xn, w, state, *, use_cache: bool):
         padded = jnp.concatenate([hist.astype(conv_in.dtype), conv_in],
                                  axis=1)
         kern = w["conv_w"]                                # (Kw, conv_dim)
-        conv = sum(padded[:, i:i + T] * kern[i] for i in range(Kw))
+        conv = causal_conv(padded, kern, T,
+                           None if segment_ids is None else positions)
         conv = jax.nn.silu(conv + w["conv_b"])
         new_conv_state = padded[:, -(Kw - 1):] if Kw > 1 else hist
         xi, Bv, Cv = jnp.split(conv, [d_inner, d_inner + N], axis=-1)
@@ -178,7 +234,7 @@ def mamba_mixer(cfg: ModelConfig, xn, w, state, *, use_cache: bool):
             y = y[:, :, None, :]
         else:
             y, S = ssd_chunked(xh_dt, a_log, Bv, Cv, state["S"],
-                               chunk=cfg.chunk_size)
+                               chunk=cfg.chunk_size, segment_ids=segment_ids)
         y = y + w["D"][None, :, None, None].astype(y.dtype) * xh
         y = y.transpose(0, 2, 1, 3).reshape(Bt, T, d_inner)
 
@@ -189,10 +245,12 @@ def mamba_mixer(cfg: ModelConfig, xn, w, state, *, use_cache: bool):
     return out, {"conv": new_conv_state, "S": S}
 
 
-def mamba_block(cfg: ModelConfig, x, w, state, *, use_cache: bool):
+def mamba_block(cfg: ModelConfig, x, w, state, *, use_cache: bool,
+                segment_ids=None, positions=None):
     """zamba2's Mamba-2 layer: pre-norm mixer plus residual."""
     y, new_state = mamba_mixer(cfg, rms_norm(x, w["ln"], cfg.norm_eps), w,
-                               state, use_cache=use_cache)
+                               state, use_cache=use_cache,
+                               segment_ids=segment_ids, positions=positions)
     return x + y, new_state
 
 

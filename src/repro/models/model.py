@@ -186,12 +186,13 @@ def _transformer_block(cfg: ModelConfig, x, w, cache_l, *, positions, window,
     return x, new_cache, aux
 
 
-def _mixer_layer(cfg: ModelConfig, x, w, state_l, *, use_cache):
+def _mixer_layer(cfg: ModelConfig, x, w, state_l, *, use_cache,
+                 segment_ids=None, positions=None):
     """A granite-4.0-h Mamba-2 layer: the attention layer's shape with the
     Mamba-2 mixer in attention's place."""
     y, new_state = mamba_mod.mamba_mixer(
         cfg, rms_norm(x, w["ln1"], cfg.norm_eps), w["mixer"], state_l,
-        use_cache=use_cache)
+        use_cache=use_cache, segment_ids=segment_ids, positions=positions)
     x, _ = _ffn(cfg, _residual(cfg, x, y), w)
     return x, new_state
 
@@ -244,7 +245,7 @@ def _run_rwkv(cfg: ModelConfig, x, blocks, state, *, use_cache):
 
 
 def _run_pattern(cfg: ModelConfig, x, blocks, cache, *, positions, window,
-                 use_cache):
+                 use_cache, segment_ids=None):
     """granite-4.0-h: ``cfg.layer_pattern`` period after period.  Scans
     over the periods; inside one, over each run of consecutive layers of
     one kind.  Layer stacks and their states keep one flat layer axis per
@@ -264,13 +265,14 @@ def _run_pattern(cfg: ModelConfig, x, blocks, cache, *, positions, window,
             tree)
 
     def mixer_body(carry, inp):
-        y, new_st = _mixer_layer(cfg, carry, *inp, use_cache=use_cache)
+        y, new_st = _mixer_layer(cfg, carry, *inp, use_cache=use_cache,
+                                 segment_ids=segment_ids, positions=positions)
         return _constrain(y), new_st
 
     def attn_body(carry, inp):
         y, new_kv, _ = _transformer_block(
             cfg, carry, *inp, positions=positions, window=window,
-            use_cache=use_cache)
+            use_cache=use_cache, segment_ids=segment_ids)
         return _constrain(y), new_kv
     if cfg.remat:
         mixer_body = jax.checkpoint(mixer_body)
@@ -302,7 +304,7 @@ def _run_pattern(cfg: ModelConfig, x, blocks, cache, *, positions, window,
 
 
 def _run_hybrid(cfg: ModelConfig, x, params, cache, *, positions, window,
-                use_cache):
+                use_cache, segment_ids=None):
     """Mamba2 stack with the shared attention block every ``attn_every``
     layers. Layer l counts 0-based; the shared block runs after layers
     attn_every-1, 2·attn_every-1, … (n_uses times).  With a
@@ -310,7 +312,7 @@ def _run_hybrid(cfg: ModelConfig, x, params, cache, *, positions, window,
     if cfg.layer_pattern:
         return _run_pattern(cfg, x, params["blocks"], cache,
                             positions=positions, window=window,
-                            use_cache=use_cache)
+                            use_cache=use_cache, segment_ids=segment_ids)
     A = cfg.attn_every
     n_uses = cfg.n_layers // A
     tail = cfg.n_layers - n_uses * A
@@ -322,7 +324,9 @@ def _run_hybrid(cfg: ModelConfig, x, params, cache, *, positions, window,
     def mamba_body(carry, inp):
         w_l, st_l = inp
         y, new_st = mamba_mod.mamba_block(cfg, carry, w_l, st_l,
-                                          use_cache=use_cache)
+                                          use_cache=use_cache,
+                                          segment_ids=segment_ids,
+                                          positions=positions)
         return _constrain(y), new_st
     if cfg.remat:
         mamba_body = jax.checkpoint(mamba_body)
@@ -338,7 +342,7 @@ def _run_hybrid(cfg: ModelConfig, x, params, cache, *, positions, window,
         # shared attention block (weights shared; per-use KV cache)
         y, new_kv, _ = _transformer_block(
             cfg, x, shared, kv_l, positions=positions, window=window,
-            use_cache=use_cache)
+            use_cache=use_cache, segment_ids=segment_ids)
         return y, (new_st, new_kv)
 
     main_w = jax.tree.map(
@@ -483,14 +487,15 @@ def final_hidden(cfg: ModelConfig, params: Params,
     Packed rows: ``segment_ids`` ``(B, S)`` names each token's sequence
     within its row and ``positions`` ``(B, S)`` its position within that
     sequence.  Attention then stays inside each segment and rope counts
-    from each segment's start, so every segment's hidden states are those
-    of its sequence alone.  Only attention-stack families take them: a
-    recurrent state (ssm, and either hybrid: its Mamba-2 state and conv
-    history) would carry one segment into the next.
+    from each segment's start; in either hybrid the Mamba-2 mixer's conv
+    reads no history and its SSD restarts from a zero state at each
+    segment start.  So every segment's hidden states are those of its
+    sequence alone.  ``ssm`` (RWKV) does not take them: its recurrent
+    state would carry one segment into the next.
     Without them the traced program is the unpacked one."""
     x, default_pos = _embed_inputs(cfg, params, batch)
     positions = default_pos if positions is None else positions
-    if segment_ids is not None and cfg.family in ("ssm", "hybrid"):
+    if segment_ids is not None and cfg.family == "ssm":
         raise ValueError(f"final_hidden: {cfg.family} carries recurrent "
                          "state along a row — packed segments would mix")
     if cfg.family == "ssm":
@@ -499,7 +504,7 @@ def final_hidden(cfg: ModelConfig, params: Params,
     elif cfg.family == "hybrid":
         st = _hybrid_cache(cfg, x.shape[0], x.shape[1], 0, x.dtype)
         x, _ = _run_hybrid(cfg, x, params, st, positions=positions, window=0,
-                           use_cache=False)
+                           use_cache=False, segment_ids=segment_ids)
     else:
         cache_in = {"k": jnp.zeros((cfg.n_layers, x.shape[0], 0,
                                     cfg.n_kv_heads, cfg.head_dim), x.dtype)}

@@ -200,22 +200,24 @@ def make_feature_step(cfg: ModelConfig) -> Callable:
       in at most one row.  Returns ``(G, d_model)``, zeros for an empty
       column.  The step derives each token's segment and its position
       within it on the device (:func:`segment_layout`): rope counts from
-      the segment's start, attention stays inside the segment, and pooling
+      the segment's start, attention stays inside the segment, the Mamba-2
+      conv and SSD restart at it, and pooling
       is a masked sum per segment in float32 (a matmul at HIGHEST
       precision; the default would round the states to bfloat16 on TPU).
 
-    Packing is exact only where every sequence-mixing layer is attention,
-    so only the ``dense`` family packs, and ``feats.packs`` says so:
-    ``ssm`` and ``hybrid`` (zamba2's Mamba-2 stack with a shared block,
-    and granite-4.0-h's Mamba-2 and NoPE attention layers alike) carry
-    recurrent state and conv history along the row, which would cross
-    segments, and ``moe``'s capacity routing already makes features
+    Packing is exact where every sequence mixer keeps to its segment, and
+    ``feats.packs`` says which families pack: ``dense``, and ``hybrid``
+    (zamba2's Mamba-2 stack with a shared block, and granite-4.0-h's
+    Mamba-2 and NoPE attention layers alike), whose Mamba-2 mixer reads no
+    conv history and restarts its SSD state at each segment start.
+    ``ssm`` (RWKV) carries its recurrent state along the row, which would
+    cross segments, and ``moe``'s capacity routing already makes features
     depend on batch-mates, which sharing rows would make worse.
     """
     assert cfg.has_decode, (
         f"{cfg.name} is encoder-only: bidirectional attention mixes pad "
         "positions into real ones — serve unpadded batches instead")
-    packs = cfg.family == "dense"
+    packs = cfg.family in ("dense", "hybrid")
 
     def feats(params, tokens, length):
         if length.ndim == 1:

@@ -17,9 +17,9 @@ guaranteed ``ceil(extract_share · n_slots)`` rows and inference the rest;
 an under-full class backfills the other's rows, so neither class can
 starve the pool.  A step's batch is ``S_bucket`` wide, the power-of-two
 bucket of its longest prompt.  Where the feature step packs (the
-``dense`` family, ``serve.make_feature_step``), the admitted prompts share
-rows: ``R`` rows, a power of two ≤ ``n_slots``; otherwise each takes a
-row of ``n_slots``.  So the compile count is bounded by the prompt
+``dense`` and ``hybrid`` families, ``serve.make_feature_step``), the
+admitted prompts share rows: ``R`` rows, a power of two ≤ ``n_slots``;
+otherwise each takes a row of ``n_slots``.  So the compile count is bounded by the prompt
 buckets times the row counts, never by traffic.
 
 The round program sits behind the session's
@@ -257,14 +257,15 @@ class FedPFTService:
 
         Returns the number of requests completed.  The batch is
         ``S_bucket`` wide, the power-of-two bucket of the longest admitted
-        prompt.  For a packing feature step (the ``dense`` family) the
-        prompts are packed first-fit-decreasing into ``R`` rows, ``R`` the
-        power of two at or above the rows used (≤ ``n_slots``), and the
-        call takes ``(R, S_bucket)`` tokens and ``(R, n_slots)`` segment
-        lengths (``serve.pack_batch``): each prompt attends and pools only
-        within its own segment.  ``ssm`` and ``hybrid`` (recurrent state
-        would cross segments) and ``moe`` (capacity routing makes features
-        depend on batch-mates) keep one prompt per row: an ``(n_slots,
+        prompt.  For a packing feature step (the ``dense`` and ``hybrid``
+        families) the prompts are packed first-fit-decreasing into ``R``
+        rows, ``R`` the power of two at or above the rows used
+        (≤ ``n_slots``), and the call takes ``(R, S_bucket)`` tokens and
+        ``(R, n_slots)`` segment lengths (``serve.pack_batch``): each
+        prompt attends, mixes and pools only within its own segment.
+        ``ssm`` (recurrent state would cross segments) and ``moe``
+        (capacity routing makes features depend on batch-mates) keep one
+        prompt per row: an ``(n_slots,
         S_bucket)`` call, short rows right-padded, unused rows of length 0
         (``serve.pad_batch``).  Either way request ``i`` gets feature row
         ``i``.

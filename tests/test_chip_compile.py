@@ -167,6 +167,21 @@ def test_hybrid_feature_step_compiles_full_width(one_chip):
     _fits(compiled)
 
 
+def test_packed_hybrid_feature_step_compiles_full_width(one_chip):
+    """The same period over a packed batch: 16 rows × 512 tokens holding up
+    to 16 requests' segments, the largest call the hybrid's extraction
+    cell makes, with the segment masks in the SSD and the conv."""
+    cfg = dataclasses.replace(get_config("granite-4-h-micro"), n_layers=10,
+                              remat=False)
+    params = jax.tree.map(
+        lambda a: _sds(a.shape, a.dtype, one_chip),
+        jax.eval_shape(partial(M.init_params, cfg), jax.random.PRNGKey(0)))
+    compiled = jax.jit(serve.make_feature_step(cfg)).lower(
+        params, _sds((16, 512), jnp.int32, one_chip),
+        _sds((16, 16), jnp.int32, one_chip)).compile()
+    _fits(compiled)
+
+
 def test_mesh_transfer_compiles_on_four_chips(topo, tpu_dispatch):
     """The mesh round's client phase over four chips: sharded EM with the
     compiled kernel, then the all-gather that is the one-shot transfer."""

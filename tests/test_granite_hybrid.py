@@ -6,6 +6,7 @@ multiple of the chunk.  Planted faults must fail the same comparison, and
 the dense configs' features must stay bit-identical to those recorded
 before the hybrid's config fields existed."""
 import dataclasses
+import hashlib
 import json
 import pathlib
 
@@ -26,6 +27,7 @@ from repro.serve.service import FedPFTService, ServiceConfig
 
 GOLDEN = pathlib.Path(__file__).parent / "fixtures" / \
     "dense_features_golden.json"
+JAXPRS = pathlib.Path(__file__).parent / "fixtures" / "unpacked_jaxprs.json"
 
 # Relative L2 error of a row of final hidden states (or of a feature row).
 # float32 program: the chunked SSD factorises exp(Σ log a) and sums in
@@ -115,8 +117,36 @@ def test_served_type_matches_the_plain_reference():
     assert hidden_error(cfg, params, cfg, params, 70) < TOL_BF16
 
 
-@pytest.mark.parametrize("T,chunk", [(40, 16), (64, 16), (7, 16), (70, 32)])
-def test_ssd_chunked_is_the_sequential_recurrence(T, chunk):
+# (T, chunk, segment lengths of row 0): row 1 takes them in reverse order.
+SSD_CASES = [
+    pytest.param(40, 16, None, id="40-16"),
+    pytest.param(64, 16, None, id="64-16"),
+    pytest.param(7, 16, None, id="7-16"),
+    pytest.param(70, 32, None, id="70-32"),
+    # starts mid-chunk; the 20 spans the boundary at 16 (and, reversed, 32)
+    pytest.param(40, 16, (5, 9, 20, 6), id="40-16-mid_chunk"),
+    # segments that end exactly on chunk boundaries
+    pytest.param(40, 16, (16, 16, 8), id="40-16-on_boundary"),
+    # segments of one token, next to each other
+    pytest.param(40, 16, (1, 7, 1, 1, 30), id="40-16-length_one"),
+    # a segment over a whole chunk, entered from the chunk before
+    pytest.param(64, 16, (3, 40, 21), id="64-16-whole_chunk"),
+    # trailing padding, which segment_layout makes one more segment, in a
+    # T the chunk does not divide
+    pytest.param(70, 32, (33, 2, 20, 15), id="70-32-trailing_pad"),
+]
+
+
+def _segment_rows(lengths, Bt):
+    rows = [lengths if b % 2 == 0 else lengths[::-1] for b in range(Bt)]
+    return rows, jnp.asarray(np.stack([np.repeat(np.arange(len(r)), r)
+                                       for r in rows]))
+
+
+@pytest.mark.parametrize("T,chunk,lengths", SSD_CASES)
+def test_ssd_chunked_is_the_sequential_recurrence(T, chunk, lengths):
+    """The chunked form against the recurrence one step at a time; with
+    segment ids, against the recurrence over each segment alone."""
     Bt, H, P, N = 2, 3, 4, 5
     ks = jax.random.split(jax.random.PRNGKey(T), 5)
     x = jax.random.normal(ks[0], (Bt, T, H, P))
@@ -124,43 +154,105 @@ def test_ssd_chunked_is_the_sequential_recurrence(T, chunk):
     A = -jnp.exp(jax.random.normal(ks[2], (H,)))
     B = jax.random.normal(ks[3], (Bt, T, N))
     C = jax.random.normal(ks[4], (Bt, T, N))
-    want = REF.ssd_sequential(x, dt, A, B, C, jnp.zeros(H))
+    rows, seg = (([(T,)] * Bt, None) if lengths is None
+                 else _segment_rows(lengths, Bt))
     xh = x.transpose(0, 2, 1, 3)
     a_log = (dt * A).transpose(0, 2, 1)
     got, S = MM.ssd_chunked(xh * dt.transpose(0, 2, 1)[..., None], a_log,
-                            B, C, jnp.zeros((Bt, H, N, P)), chunk=chunk)
-    np.testing.assert_allclose(np.asarray(got.transpose(0, 2, 1, 3)),
-                               np.asarray(want), rtol=2e-5, atol=2e-5)
-    # the final state continues the sequence: one more step agrees
+                            B, C, jnp.zeros((Bt, H, N, P)), chunk=chunk,
+                            segment_ids=seg)
+    got = np.asarray(got.transpose(0, 2, 1, 3))
+    for b, row in enumerate(rows):
+        assert sum(row) == T
+        at = 0
+        for L in row:
+            sl = (slice(b, b + 1), slice(at, at + L))
+            want = REF.ssd_sequential(x[sl], dt[sl], A, B[sl], C[sl],
+                                      jnp.zeros(H))
+            np.testing.assert_allclose(got[sl], np.asarray(want),
+                                       rtol=2e-5, atol=2e-5)
+            at += L
+    # the final state continues each row's last segment: one more step
+    # agrees
     x1, dt1 = x[:, -1:], dt[:, -1:]
     y1, _ = MM.ssd_decode((x1 * dt1[..., None])[:, 0], (dt1 * A)[:, 0],
                           B[:, -1], C[:, -1], S)
-    whole = REF.ssd_sequential(jnp.concatenate([x, x1], 1),
-                               jnp.concatenate([dt, dt1], 1), A,
-                               jnp.concatenate([B, B[:, -1:]], 1),
-                               jnp.concatenate([C, C[:, -1:]], 1),
-                               jnp.zeros(H))
-    np.testing.assert_allclose(np.asarray(y1), np.asarray(whole[:, -1]),
-                               rtol=2e-5, atol=2e-5)
+    for b, row in enumerate(rows):
+        sl = (slice(b, b + 1), slice(T - row[-1], T))
+        whole = REF.ssd_sequential(jnp.concatenate([x[sl], x1[b:b + 1]], 1),
+                                   jnp.concatenate([dt[sl], dt1[b:b + 1]], 1),
+                                   A, jnp.concatenate([B[sl], B[b:b + 1, -1:]],
+                                                      1),
+                                   jnp.concatenate([C[sl], C[b:b + 1, -1:]],
+                                                   1), jnp.zeros(H))
+        np.testing.assert_allclose(np.asarray(y1[b]),
+                                   np.asarray(whole[0, -1]),
+                                   rtol=2e-5, atol=2e-5)
 
 
-def test_service_extracts_padded_prompts_as_each_alone(f32):
-    """Right-padded mixed lengths through ``FedPFTService``: padding
-    leaks neither through the conv nor through the state."""
-    cfg, params = f32
-    svc = FedPFTService(cfg, params, FedSession(
+def _service(cfg, params, n_slots):
+    return FedPFTService(cfg, params, FedSession(
         n_classes=2, ingest=IG.IngestConfig()), ServiceConfig(
-        n_slots=4, max_seq=128, min_bucket=8))
-    assert svc._layout is serve.pad_batch
-    rng = np.random.default_rng(3)
-    prompts = [rng.integers(1, cfg.vocab_size, size=n) for n in
-               (70, 3, 33, 1, 64, 9)]
+        n_slots=n_slots, max_seq=128, min_bucket=8))
+
+
+def _extract(svc, prompts):
+    """Every prompt through the service; the feature-step calls' shapes
+    and each request's features."""
+    calls, inner = [], svc._feats
+
+    def observed(params, tokens, length):
+        calls.append((tuple(tokens.shape), tuple(length.shape)))
+        return inner(params, tokens, length)
+    svc._feats = observed
     reqs = [svc.submit_extract(p) for p in prompts]
     while svc.queues["extract"]:
         svc.step()
-    got = np.stack([np.asarray(r.feats) for r in reqs])
+    return calls, np.stack([np.asarray(r.feats, np.float32) for r in reqs])
+
+
+def test_service_extracts_padded_prompts_as_each_alone(f32):
+    """Right-padded mixed lengths through ``FedPFTService``, one prompt per
+    row (the step's ``(B,)`` layout): padding leaks neither through the
+    conv nor through the state."""
+    cfg, params = f32
+    svc = _service(cfg, params, 4)
+    svc._layout = serve.pad_batch
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(1, cfg.vocab_size, size=n) for n in
+               (70, 3, 33, 1, 64, 9)]
+    calls, got = _extract(svc, prompts)
+    assert calls == [((4, 128), (4,)), ((4, 64), (4,))]
     err = row_errors(got, REF.features(cfg, params, prompts))
     assert err.max() < TOL_F32, err
+
+
+# Packed into two rows of 128 (first-fit decreasing): row 0 holds, left to
+# right, 70, 3, 1, 9, 2 and 40 tokens, so segments shorter than the conv
+# (4) follow others; row 1 holds 33 and 64.
+PACKED = (70, 3, 33, 1, 64, 9, 2, 40)
+
+
+def _packed_error(cfg, params):
+    svc = _service(cfg, params, 8)
+    assert svc._layout is serve.pack_batch
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(1, cfg.vocab_size, size=n) for n in PACKED]
+    calls, got = _extract(svc, prompts)
+    assert calls == [((2, 128), (2, 8))]
+    return row_errors(got, REF.features(cfg, params, prompts))
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_service_packs_prompts_as_each_alone(f32, dtype):
+    """Packed rows through ``FedPFTService``: each prompt's features are
+    the plain reference's of that prompt alone — neither the conv history
+    nor the SSD state crosses a segment start, nor does attention."""
+    cfg, params = f32 if dtype == "float32" else (small(dtype),) * 2
+    if dtype != "float32":
+        params = make_params(cfg)
+    err = _packed_error(cfg, params)
+    assert err.max() < (TOL_F32 if dtype == "float32" else TOL_BF16), err
 
 
 def _norm_before_gate(y, z, gn, eps):
@@ -169,12 +261,14 @@ def _norm_before_gate(y, z, gn, eps):
 
 
 def _state_dropped_at_chunks(real):
-    def ssd(x, a_log, B, C, S0, chunk=256):
+    def ssd(x, a_log, B, C, S0, chunk=256, segment_ids=None):
         ys = []
         for t0 in range(0, x.shape[2], chunk):
             y, S = real(x[:, :, t0:t0 + chunk], a_log[..., t0:t0 + chunk],
                         B[:, t0:t0 + chunk], C[:, t0:t0 + chunk],
-                        S0 if t0 == 0 else jnp.zeros_like(S0), chunk)
+                        S0 if t0 == 0 else jnp.zeros_like(S0), chunk,
+                        None if segment_ids is None
+                        else segment_ids[:, t0:t0 + chunk])
             ys.append(y)
         return jnp.concatenate(ys, axis=2), S
     return ssd
@@ -207,6 +301,32 @@ def test_a_planted_fault_fails_the_comparison(f32, monkeypatch, fault):
     assert err > 10 * TOL_F32, (fault, err)
 
 
+def _state_not_reset(real):
+    def ssd(*args, segment_ids=None, **kw):
+        return real(*args, **kw)
+    return ssd
+
+
+def _conv_history_leaks(real):
+    def conv(padded, kern, T, positions=None):
+        return real(padded, kern, T)
+    return conv
+
+
+@pytest.mark.parametrize("fault", ["state_not_reset", "conv_history_leaks"])
+def test_a_planted_fault_fails_the_packed_comparison(f32, monkeypatch,
+                                                     fault):
+    cfg, params = f32
+    if fault == "state_not_reset":
+        monkeypatch.setattr(MM, "ssd_chunked", _state_not_reset(
+            MM.ssd_chunked))
+    else:
+        monkeypatch.setattr(MM, "causal_conv", _conv_history_leaks(
+            MM.causal_conv))
+    err = _packed_error(cfg, params)
+    assert err.max() > 10 * TOL_F32, (fault, err)
+
+
 def _dense_features(name):
     cfg = get_config(name).reduced()
     params = M.init_params(cfg, jax.random.PRNGKey(0))
@@ -228,3 +348,43 @@ def test_dense_features_are_bit_identical_to_before(name):
     np.testing.assert_array_equal(rows, np.asarray(want["rows"], np.float32))
     np.testing.assert_array_equal(packed,
                                   np.asarray(want["packed"], np.float32))
+
+
+def _jaxpr_digest(fn, *args):
+    return hashlib.sha256(str(jax.make_jaxpr(fn)(*args)).encode()).hexdigest()
+
+
+def _unpacked_digest(name):
+    sds = jax.ShapeDtypeStruct
+    if name == "ssd_chunked":
+        f32 = jnp.float32
+        return _jaxpr_digest(
+            lambda x, a, B, C, S: MM.ssd_chunked(x, a, B, C, S, chunk=16),
+            sds((2, 3, 40, 4), f32), sds((2, 3, 40), f32),
+            sds((2, 40, 5), f32), sds((2, 40, 5), f32),
+            sds((2, 3, 5, 4), f32))
+    arch, call = name.split(".")
+    cfg = (small("bfloat16") if arch == "granite-4-h-micro"
+           else get_config(arch).reduced())
+    p = jax.eval_shape(lambda k: M.init_params(cfg, k), jax.random.PRNGKey(0))
+    if call == "final_hidden":
+        return _jaxpr_digest(
+            lambda p, t: M.final_hidden(cfg, p, {"tokens": t}), p,
+            sds((2, 40), jnp.int32))
+    rows = 2 if call == "feature_step_packed" else 4
+    length = (rows, 4) if call == "feature_step_packed" else (4,)
+    return _jaxpr_digest(serve.make_feature_step(cfg), p,
+                         sds((rows, 16), jnp.int32), sds(length, jnp.int32))
+
+
+@pytest.mark.parametrize("name", [
+    "ssd_chunked", "granite-4-h-micro.final_hidden",
+    "granite-4-h-micro.feature_step", "zamba2-7b.final_hidden",
+    "zamba2-7b.feature_step", "granite-3-2b.final_hidden",
+    "granite-3-2b.feature_step", "granite-3-2b.feature_step_packed"])
+def test_unpacked_programs_trace_as_before(name):
+    """Segment isolation in the Mamba-2 mixer adds nothing to a call
+    without segment ids: the SSD, both hybrids' unpacked feature steps and
+    the dense steps trace the jaxprs recorded before it existed."""
+    want = json.loads(JAXPRS.read_text())[name]
+    assert _unpacked_digest(name) == want

@@ -22,6 +22,12 @@ from repro.models import layers as LY
 from repro.models import model as M
 
 TOL = 3e-6          # relative L2 per feature row, float32 model
+# zamba2 packed against its own unpacked call, per feature row: float32
+# differs in the SSD's summation order across chunk boundaries (≈1e-6);
+# bfloat16 also rounds the chunked sums differently where a segment's
+# chunks are cut elsewhere (≈1e-3).  A state not reset reads 0.06–0.09
+# in bfloat16.
+ZAMBA2_TOL = {"float32": 1e-5, "bfloat16": 0.01}
 
 
 @pytest.fixture(scope="module")
@@ -201,12 +207,10 @@ def _observe(svc):
     return calls, inner
 
 
-@pytest.mark.parametrize("name", ["rwkv6-3b", "zamba2-7b",
-                                  "granite-4-h-micro",
-                                  "granite-moe-3b-a800m"])
+@pytest.mark.parametrize("name", ["rwkv6-3b", "granite-moe-3b-a800m"])
 def test_recurrent_and_moe_families_keep_one_request_per_row(name):
     cfg = get_config(name).reduced()
-    assert cfg.family in ("ssm", "hybrid", "moe")
+    assert cfg.family in ("ssm", "moe")
     step = serve.make_feature_step(cfg)
     assert step.packs is False
     with pytest.raises(ValueError, match="does not pack"):
@@ -219,6 +223,49 @@ def test_recurrent_and_moe_families_keep_one_request_per_row(name):
     assert calls == [((4, 8), (4,))]
     svc._feats = jitted
     assert svc.stats()["rows_computed"] == 4
+
+
+@pytest.mark.parametrize("name", ["zamba2-7b", "granite-4-h-micro"])
+def test_hybrid_families_pack_rows(name):
+    """Both hybrid layouts pack: three prompts of a bucket of 8 take two
+    rows (5 + 3, then 2), not ``n_slots``; the engagement counter says
+    so."""
+    cfg = get_config(name).reduced()
+    assert cfg.family == "hybrid"
+    step = serve.make_feature_step(cfg)
+    assert step.packs is True
+    svc = _service(cfg, M.init_params(cfg, jax.random.PRNGKey(0)))
+    calls, jitted = _observe(svc)
+    for L in (3, 5, 2):
+        svc.submit_extract(np.arange(1, L + 1))
+    assert svc.step() == 3
+    assert calls == [((2, 8), (2, 4))]
+    svc._feats = jitted
+    st = svc.stats()
+    assert st["rows_computed"] == 2 < svc.steps * svc.scfg.n_slots
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_zamba2_packed_service_equals_its_unpacked_step(dtype):
+    """zamba2's Mamba-2 stack and shared attention block, packed through
+    the service, give each request the features of its own ``(B,)`` call:
+    segments shorter than the conv (4) follow others in a row, and
+    segments cross the SSD's chunks (32)."""
+    cfg = dataclasses.replace(get_config("zamba2-7b").reduced(),
+                              dtype=dtype, remat=False)
+    assert cfg.conv_width == 4 and cfg.chunk_size == 32
+    params = M.init_params(cfg, jax.random.PRNGKey(2))
+    svc = _service(cfg, params, n_slots=8, max_seq=128)
+    calls, step = _observe(svc)
+    lengths = [70, 3, 33, 1, 64, 9, 2, 40]
+    reqs = [svc.submit_extract(p) for p in _prompts(cfg, lengths, seed=4)]
+    svc.drain()
+    assert calls == [((2, 128), (2, 8))]
+    got = np.stack([np.asarray(r.feats, np.float32) for r in reqs])
+    want = np.stack([np.asarray(step(
+        params, jnp.asarray(p)[None], jnp.asarray([len(p)])), np.float32)[0]
+        for p in _prompts(cfg, lengths, seed=4)])
+    assert _row_errors(got, want).max() < ZAMBA2_TOL[dtype]
 
 
 def test_service_packs_and_counts_the_shapes_it_calls(dense):
