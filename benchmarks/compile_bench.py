@@ -1,7 +1,7 @@
 """ISSUE 8: multi-tenant round latency, cold vs warm AOT cache.
 
 A server hosting many federations sees a stream of cohorts with mixed
-signatures (M, cov_type, …).  Without the cache every *distinct* fused
+signatures (slot count M, cov_type, …).  Without the cache every *distinct* fused
 slot-stack shape pays trace+compile inside the request path; with
 ``FedSession(program_cache=ProgramCache())`` cohorts pad to the canonical
 power-of-two grid and every signature compiles exactly once.
@@ -66,7 +66,6 @@ def _round(sess, seed: int, msgs):
 
 def main(quick: bool = False):
     from repro.core import head as H
-    from repro.fl import round as FR
     from repro.fl.api import FedSession
     from repro.launch.aot_cache import ProgramCache
 
@@ -81,17 +80,15 @@ def main(quick: bool = False):
 
     cohorts = [(M, cov, _messages(M, cov, seed=17 * i + M))
                for i, (M, cov) in enumerate(tenants)]
-    canon = {(cache.canonical(FR.signature_of(m))) for _, _, m in cohorts}
 
-    # cold pass: one round per canonical signature, compile in-path
-    cold, seen = [], set()
-    for M, cov, msgs in cohorts:
-        sig = cache.canonical(FR.signature_of(msgs))
-        if sig in seen:
-            continue
-        seen.add(sig)
-        us, _ = _round(sess, len(seen), msgs)
-        cold.append(us)
+    # cold pass: every cohort once; a round that missed the cache compiled
+    # in-path, and the session reports the canonical signature it ran at
+    cold, canon = [], set()
+    for i, (M, cov, msgs) in enumerate(cohorts):
+        us, r = _round(sess, i + 1, msgs)
+        canon.add(r.info["compile"]["canonical"])
+        if not r.info["compile"]["hit"]:
+            cold.append(us)
     cold_mean = float(np.mean(cold))
     C.emit("compile_bench/cold_round", cold_mean,
            f"signatures={len(canon)};compiles={cache.compiles};"

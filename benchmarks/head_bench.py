@@ -1,15 +1,14 @@
 """Micro-benchmark: the server phase end-to-end — fused sampler-in-the-loop
-head training vs the materializing paths (ISSUE 5).
+head training vs the materializing path.
 
 The server never needs the synthetic pool, it needs minibatches drawn from
-the clients' mixtures.  Three ways to get them, A/B'd on the skewed 10×10
-cohort (counts log-spaced 1 → 4096, the ISSUE 3 planner scenario):
+the clients' mixtures.  Two ways to get them, A/B'd on the skewed 10×10
+cohort (counts log-spaced 1 → 4096):
 
 * ``pooled``    planner-bucketed synthesis, concatenate every chunk into the
                 (Σcounts, d) pool, one ``train_head`` scan over it — peak
-                memory carries the whole pool;
-* ``streamed``  the same chunks fed to ``train_head_streaming`` without
-                pooling — peak O(largest bucket), one jitted scan per chunk;
+                memory carries the whole pool (the heterogeneous-cohort
+                fallback);
 * ``fused``     ``train_head_from_gmms``: no synthesis at all — every Adam
                 step draws its minibatch from the (G, K, …) slot stack
                 inside ONE jitted scan.  Zero materialization, one dispatch.
@@ -76,11 +75,6 @@ def main(quick: bool = False):
         head, _ = H.train_head(key, feats, ys, CN, cfg)
         return head
 
-    def run_streamed():
-        chunks, _ = FA.synthesize_chunks(key, batch, counts, "diag")
-        head, _ = H.train_head_streaming(key, chunks, CN, cfg)
-        return head
-
     def run_fused():
         head, _ = H.train_head_from_gmms(key, stack["pi"], stack["mu"],
                                          stack["cov"], labels, slot_counts,
@@ -88,19 +82,13 @@ def main(quick: bool = False):
         return head
 
     us_pool = _time(run_pooled, reps)
-    us_stream = _time(run_streamed, reps)
     us_fused = _time(run_fused, reps)
 
     # dispatch counts: synthesis dispatches + head-training dispatches
-    n_chunks = plan.n_dispatches
     disp_pool = plan.n_dispatches + 1          # bucket samples + one scan
-    # bucket samples + ≤ _INTERLEAVE round-robin segments per chunk
-    disp_stream = plan.n_dispatches + H._INTERLEAVE * n_chunks
     disp_fused = 1                              # one fused device program
     # peak-memory proxy: largest resident synthetic tensor (f32 bytes)
     pool_bytes = plan.requested * D * 4
-    biggest_bucket = max(b.padded_draws for b in plan.buckets)
-    stream_bytes = biggest_bucket * D * 4
     stack_bytes = sum(int(np.prod(np.shape(v))) * 4 for v in stack.values())
     # slot stack + one (window, batch, d) noise block + the hoisted
     # (n_steps, batch) int32 slot/component draws
@@ -110,11 +98,8 @@ def main(quick: bool = False):
 
     C.emit(f"head_bench/skew_M{M}_C{CN}_pooled", us_pool,
            f"dispatches={disp_pool}:pool_bytes={pool_bytes}")
-    C.emit(f"head_bench/skew_M{M}_C{CN}_streamed", us_stream,
-           f"dispatches={disp_stream}:peak_bytes={stream_bytes}")
     C.emit(f"head_bench/skew_M{M}_C{CN}_fused", us_fused,
            f"dispatches={disp_fused}:peak_bytes={fused_bytes}:"
-           f"speedup_vs_streamed={us_stream / max(us_fused, 1e-9):.1f}x:"
            f"speedup_vs_pooled={us_pool / max(us_fused, 1e-9):.1f}x")
 
 

@@ -21,8 +21,7 @@ JSON so the perf trajectory is machine-readable across PRs.
                                       counts) planner-vs-monolithic A/B
   em_bench          ISSUE 2           fused batched vs reference E-step
   head_bench        ISSUE 5           fused sampler-in-the-loop head vs
-                                      planned+streamed vs pooled on the
-                                      skewed cohort
+                                      pooled on the skewed cohort
   ingest_bench      ISSUE 6           100k-client streaming ingestion:
                                       clients/sec folded + peak resident
                                       bytes vs the stacked-cohort cost

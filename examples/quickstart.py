@@ -5,16 +5,16 @@
 Ten clients with non-iid (Dirichlet β=0.1) data each fit per-class GMMs
 over foundation-model features. The session encodes each summary with a
 REAL 16-bit wire codec (the server decodes and computes on the quantized
-parameters — `comm_bytes` is the actual payload length), then synthesizes
-the cohort's features through the count-stratified planner (one jitted
-sample per power-of-two count bucket — ≤ 2·Σcounts draws even under the
-heavy Dirichlet skew here) and trains the global classifier head. One
+parameters — `comm_bytes` is the actual payload length), then trains the
+global classifier head straight from the decoded mixtures: every Adam
+step draws its minibatch from the (client, class) slot stack inside one
+jitted scan, so the synthetic feature pool is never materialized. One
 round, a fraction of the bytes, near-centralized accuracy.
 
 Serving many federations? Pass `FedSession(program_cache=
 launch.aot_cache.ProgramCache())` to AOT-compile each canonical cohort
 shape once and serve every later round from the executable cache —
-cohorts pad to power-of-two sizes bit-identically, and warm rounds skip
+slot stacks pad to power-of-two sizes bit-identically, and warm rounds skip
 trace+compile entirely (DESIGN.md §11, benchmarks/compile_bench.py).
 
 Serving the whole loop? `examples/fedpft_service.py` runs FedPFT as a
@@ -74,9 +74,9 @@ def main():
     plan = res.info["synthesis_plans"][0]
     print(f"FedPFT       acc={acc:.4f}  comm={comm/1e3:8.1f} KB "
           f"({len(res.messages)} encoded messages)")
-    print(f"planner      {plan.n_dispatches} buckets, "
-          f"{plan.padded_draws} draws for {plan.requested} requested "
-          f"(monolithic pad: {plan.monolithic_draws})")
+    print(f"server       head trained from {len(plan.slot_table)} mixture "
+          f"slots; the {plan.requested} synthetic features they stand for "
+          "were never materialized")
     print(f"Centralized  acc={acc_c:.4f}  comm={info_c['comm_bytes']/1e3:8.1f} KB")
     print(f"→ {info_c['comm_bytes']/comm:.1f}× less "
           f"communication, {abs(acc_c-acc)*100:.2f} pts from the oracle, "

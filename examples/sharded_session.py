@@ -7,12 +7,11 @@ initializes — same trick as the multidevice test lane, tests/_spawn.py),
 shards an 8-client cohort over the mesh's "data" axis, and runs the whole
 round with `FedSession(shards=…)`: each shard fits its clients' classwise
 GMMs as one batched EM, the bf16 wire pytree crosses the mesh in a single
-all_gather (THE communication round), and the server phase — planner-
-bucketed synthesis laid out data-parallel over the mixture slots, then a
-streamed head fit — runs on the replicated parameters.  The same session
-on a 1-shard mesh produces the same bytes, the same synthetic statistics,
-and the same head: shard count is an execution detail, not a semantic one
-(DESIGN.md §5).
+all_gather (THE communication round), and the server phase — the head
+trained straight from the decoded mixture-slot stack in one fused scan —
+runs replicated on every shard.  The same session on a 1-shard mesh
+produces the same bytes and the same head: shard count is an execution
+detail, not a semantic one (DESIGN.md §5).
 """
 import os
 
@@ -47,7 +46,7 @@ def main():
             summarizer=FA.GMMSummarizer(
                 G.GMMConfig(n_components=3, cov_type="diag", n_iter=15)),
             head=H.HeadConfig(n_steps=300, lr=3e-3),
-            shards=shards, synthesis="streamed")
+            shards=shards)
 
     key = jax.random.PRNGKey(0)
     print(f"host devices: {jax.device_count()}")

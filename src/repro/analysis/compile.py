@@ -139,15 +139,14 @@ def _sample_stacked_cases():
 
 
 def _round_sigs():
-    """The round program's canonical mini-grid: every layout × a cov-type
-    spread, all at power-of-two M (what launch.aot_cache compiles for)."""
+    """The round program's canonical mini-grid: a cov-type spread at
+    power-of-two slot counts (what launch.aot_cache compiles for)."""
     from repro.fl.round import CohortSignature
     return [
         CohortSignature(M=4, C=8, K=2, d=32, cov_type="diag"),
         CohortSignature(M=4, C=8, K=2, d=32, cov_type="full"),
         CohortSignature(M=16, C=8, K=2, d=32, cov_type="spher"),
-        CohortSignature(M=64, C=8, K=2, d=32, cov_type="diag",
-                        dtype="float32", layout="slots"),
+        CohortSignature(M=64, C=8, K=2, d=32, cov_type="diag"),
     ]
 
 
@@ -156,8 +155,8 @@ def _round_program_cases():
     from repro.launch.input_specs import round_specs_for
     cfg = HeadConfig(n_steps=8)
     return [
-        (f"{s.layout}/{s.cov_type}/M{s.M}", tuple(round_specs_for(s)),
-         {"sig": s, "head_cfg": cfg, "samples_per_class": None})
+        (f"{s.cov_type}/M{s.M}", tuple(round_specs_for(s)),
+         {"sig": s, "head_cfg": cfg})
         for s in _round_sigs()
     ]
 
@@ -173,8 +172,7 @@ def cache_entry_points() -> List[Entry]:
               _round_program_cases,
               lambda: {"sig": _round_sigs()[0],
                        "head_cfg": _imp("repro.core.head", "HeadConfig")(
-                           n_steps=8),
-                       "samples_per_class": None}),
+                           n_steps=8)}),
     ]
 
 
@@ -319,7 +317,7 @@ class CacheKeyRule(SemanticRule):
     """CACHE-KEY: invariants the AOT executable cache keys on.
 
     ``launch.aot_cache.ProgramCache`` keys entries on ``(CohortSignature,
-    HeadConfig, samples_per_class, mesh fingerprint)`` and assumes a key
+    HeadConfig, mesh fingerprint)`` and assumes a key
     that compares equal ALWAYS maps to one executable.  Two ways that
     breaks: a static whose hash isn't stable across reconstruction (a
     dataclass growing an unhashable or identity-hashed field — every

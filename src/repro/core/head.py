@@ -4,23 +4,21 @@ The paper trains h with Adam + cross-entropy on either real features
 (Centralized oracle) or GMM-sampled synthetic features (FedPFT). One jitted
 ``lax.scan`` runs the whole optimization — no python step loop.
 
-Three ways to feed it synthetic features (DESIGN.md §2):
+Two ways to feed it synthetic features (DESIGN.md §2):
 
-* :func:`train_head` — a materialized (N, d) pool;
-* :func:`train_head_streaming` — the planner's per-bucket chunks, never
-  concatenated: steps are grouped by their assigned chunk and each group
-  runs as ONE jitted scan, so the dispatch count is bounded by the number
-  of chunks, not ``n_steps``;
-* :func:`train_head_from_gmms` — the zero-materialization path: every Adam
-  step draws its minibatch from the decoded mixture-slot stack *inside*
-  one fused scan (``gmm.sample_slot_minibatch``); no pooled tensor and no
-  per-step host dispatch ever exist.
+* :func:`train_head_from_gmms` — the server phase: every Adam step draws
+  its minibatch from the decoded mixture-slot stack *inside* one fused
+  scan (``gmm.sample_slot_minibatch``); no pooled tensor and no per-step
+  host dispatch ever exist;
+* :func:`train_head` — a materialized (N, d) pool: real features (the
+  Centralized oracle, chain clients) and the heterogeneous-cohort
+  fallback, whose mixed K / covariance families share no slot stack.
 """
 from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Dict, Optional, Sequence, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -99,129 +97,6 @@ def train_head(key, feats: jax.Array, labels: jax.Array, n_classes: int,
     keys = jax.random.split(k_steps, cfg.n_steps)
     (params, _), losses = jax.lax.scan(step, (params, opt_state), keys)
     return params, losses
-
-
-# round-robin passes over the chunk list in train_head_streaming: bounds
-# the gap between two visits to the same chunk by ≈ n_steps/_INTERLEAVE
-# while keeping the dispatch count O(chunks)
-_INTERLEAVE = 4
-
-
-@partial(jax.jit, static_argnames=("cfg",))
-def _streaming_scan(keys, params, opt_state, feats, labels,
-                    cfg: HeadConfig):
-    """ALL the steps assigned to one chunk, as ONE jitted ``lax.scan``.
-
-    Minibatches are padded to ``cfg.batch_size`` with weight-0 rows (a
-    1-row chunk draws a full-width batch whose tail is masked), so the
-    compile key is the chunk shape alone — never a per-(shape, bs) pair.
-    """
-    bs = cfg.batch_size
-    n_rows = feats.shape[0]
-    w = (jnp.arange(bs) < min(bs, n_rows)).astype(jnp.float32)
-    opt = optim.adam(cfg.lr, weight_decay=cfg.weight_decay)
-
-    def step(carry, k):
-        params, opt_state = carry
-        idx = jax.random.randint(k, (bs,), 0, n_rows)
-        loss, grads = jax.value_and_grad(_xent)(
-            params, feats[idx], labels[idx], w)
-        updates, opt_state = opt.update(grads, opt_state, params)
-        return (optim.apply_updates(params, updates), opt_state), loss
-
-    (params, opt_state), losses = jax.lax.scan(step, (params, opt_state),
-                                               keys)
-    return params, opt_state, losses
-
-
-def train_head_streaming(key, chunks: Sequence[Tuple[jax.Array, jax.Array]],
-                         n_classes: int, cfg: HeadConfig,
-                         chunk_sharding=None) -> Tuple[Dict, jax.Array]:
-    """Train a linear head over (feats, labels) chunks WITHOUT pooling them.
-
-    Steps are allocated to chunks ∝ row count (largest-remainder rounding
-    of ``n_steps·size/Σsize``) and each minibatch is drawn uniformly
-    within its chunk — the same expected minibatch law as
-    :func:`train_head`'s uniform sampling over the concatenated pool, but
-    the chunks are never concatenated: the planner's bucketed synthesis
-    (fl/planner) can hand over its per-bucket outputs and peak memory
-    stays O(largest chunk) on top of the resident chunk list.  Each
-    chunk's allocation is split into ``_INTERLEAVE`` segments scheduled
-    round-robin over the chunks — no chunk's steps all run last, so a
-    class concentrated in one small chunk is revisited every
-    ``≈ n_steps/_INTERLEAVE`` steps instead of being overwritten by
-    whichever chunk happens to train last — and every segment runs as ONE
-    jitted scan (:func:`_streaming_scan`).  The device dispatch count is
-    therefore ≤ ``_INTERLEAVE ·`` the number of chunks — not
-    ``cfg.n_steps`` as in the pre-fusion host loop — and, because the
-    allocation is deterministic in the chunk sizes, the compile count is
-    bounded by the distinct (chunk shape, segment length) pairs
-    (minibatches are padded to ``batch_size`` with weight-0 rows, so a
-    1-row chunk never triggers its own compile).  Optimizer state carries
-    across segments; the loss trace is returned in execution order.
-
-    Returns (head params, per-step loss trace), matching ``train_head``'s
-    contract — including the N=0 guard: a chunk list with zero total rows
-    returns the freshly-initialized head and an empty loss trace.
-
-    ``chunk_sharding``: an optional ``jax.sharding.Sharding`` every chunk
-    is pinned to before stepping.  The mesh-mode server (fl/api,
-    DESIGN.md §5) passes the replicated layout so the per-chunk jits see
-    one placement regardless of what the data-parallel sampling left
-    behind — without it, each (shape, sharding) pair would compile its own
-    scan.
-    """
-    if not chunks:
-        raise ValueError("train_head_streaming needs at least one chunk "
-                         "(the feature dim is unknowable from [])")
-    d = int(chunks[0][0].shape[1])
-    chunks = [(jnp.asarray(f, jnp.float32), jnp.asarray(y))
-              for f, y in chunks if int(f.shape[0]) > 0]
-    # dim agreement checked on the surviving chunks only: an all-filtered
-    # group's (0, d') placeholder must not abort a well-defined round
-    dims = sorted({int(f.shape[1]) for f, _ in chunks})
-    if len(dims) > 1:
-        raise ValueError(
-            f"train_head_streaming: chunks disagree on the feature dim "
-            f"(saw d ∈ {dims}) — one head cannot train over mixed feature "
-            "spaces; synthesize each cohort group separately")
-    d = dims[0] if dims else d
-    if chunk_sharding is not None:
-        chunks = [(jax.device_put(f, chunk_sharding),
-                   jax.device_put(y, chunk_sharding)) for f, y in chunks]
-    k_init, _, k_steps = jax.random.split(key, 3)
-    if not chunks:
-        return (init_head(k_init, d, n_classes),
-                jnp.zeros((0,), jnp.float32))
-    sizes = np.asarray([int(f.shape[0]) for f, _ in chunks], np.float64)
-    params = init_head(k_init, d, n_classes)
-    opt = optim.adam(cfg.lr, weight_decay=cfg.weight_decay)
-    opt_state = opt.init(params)
-    # deterministic ∝-size step allocation (largest-remainder): stable
-    # across calls, so the per-(shape, length) scans compile once per
-    # cohort layout instead of once per RNG draw
-    raw = sizes / sizes.sum() * cfg.n_steps
-    n_per = np.floor(raw).astype(np.int64)
-    short = cfg.n_steps - int(n_per.sum())
-    if short:
-        n_per[np.argsort(-(raw - np.floor(raw)))[:short]] += 1
-    keys = jax.random.split(k_steps, cfg.n_steps)
-    offsets = np.concatenate([[0], np.cumsum(n_per)])
-    losses = []
-    for r in range(_INTERLEAVE):
-        for j, (f, y) in enumerate(chunks):
-            # segment r of chunk j: its keys are the r-th slice of the
-            # chunk's contiguous key block (splitting points deterministic)
-            lo = int(offsets[j]) + int(n_per[j] * r // _INTERLEAVE)
-            hi = int(offsets[j]) + int(n_per[j] * (r + 1) // _INTERLEAVE)
-            if hi == lo:
-                continue
-            params, opt_state, loss = _streaming_scan(
-                keys[lo:hi], params, opt_state, f, y, cfg)
-            losses.append(loss)
-    if not losses:
-        return params, jnp.zeros((0,), jnp.float32)
-    return params, jnp.concatenate(losses)
 
 
 def fused_gmm_steps(key, pi, mu, cov, slot_labels, counts, n_classes: int,
@@ -315,8 +190,7 @@ def train_head_from_gmms(key, pi: jax.Array, mu: jax.Array, cov: jax.Array,
     sampling factor (the ``gmm.sample_slot_minibatch`` law, windowed by
     ``cfg.noise_window`` for RNG throughput).  Peak memory is
     O(window·batch·d + slot stack) instead of O(Σcounts·d), and the
-    ``cfg.n_steps`` host dispatches of the streamed path collapse to one
-    device program.  In expectation each step's minibatch follows exactly
+    whole optimization is one device program.  In expectation each step's minibatch follows exactly
     the law of uniform sampling from the pooled ``synthesize_chunks``
     output — equivalence is tested distributionally (tests/test_fused_head).
 

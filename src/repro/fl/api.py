@@ -1,4 +1,4 @@
-"""Unified federation API — one message schema, one codec, one synthesis path.
+"""Unified federation API — one message schema, one codec, one server phase.
 
 Layering (DESIGN.md §2): every FedPFT variant in this repo is the same four
 orthogonal pieces composed by a :class:`FedSession`:
@@ -14,27 +14,25 @@ orthogonal pieces composed by a :class:`FedSession`:
     privacy      an optional DP hook applied to the summary *before* encoding
                  (Theorem 4.1's Gaussian mechanism)
 
-Server-side synthesis never needs the pool (DESIGN.md §2): by default
-(``FedSession(synthesis="fused")``) the head trains STRAIGHT from the
-decoded mixture-slot stack — every Adam step draws its minibatch inside
-one jitted scan (``core.head.train_head_from_gmms``), keyed on the
-planner's flat slot table, so the pooled ``(N, d)`` tensor never exists.
-The materializing paths are kept for the A/B, DP-audit, and
-reconstruction benches: ``synthesis="streamed"`` runs the count-stratified
-planner (:mod:`repro.fl.planner`) — one jitted sample per power-of-two
-count bucket, ≤ 2·Σcounts total draws under any skew, chunks streamed
-into ``core.head.train_head_streaming`` — and ``synthesis="pooled"``
-concatenates the chunks for callers that need the synthetic set
-materialized.  Bucketed sampling keys fold deterministically per *global*
-(client, class) slot: no two slots ever share a key, whatever the
-bucketing (realized values still depend on the bucket's padded S —
-policies are equal in distribution, not bitwise).
+Server-side synthesis never needs the pool (DESIGN.md §2): every GMM
+route — the message list (host and mesh), the ingest session and
+:meth:`FedSession.aggregate_from_broker` — builds ONE flat mixture-slot
+stack and hands it to ONE runner (:meth:`FedSession._fused_round`), which
+trains the head straight from it: every Adam step draws its minibatch
+inside one jitted scan (``core.head.train_head_from_gmms``, or the AOT
+round program of ``fl.round`` when a ``program_cache`` is set), so the
+pooled ``(N, d)`` tensor never exists.  Only a heterogeneous cohort (mixed
+K or covariance family, paper §6.3), which cannot share one slot stack,
+falls back to materializing the pool: the count-stratified planner
+(:mod:`repro.fl.planner`) draws it one jitted sample per power-of-two
+count bucket (≤ 2·Σcounts draws under any skew), keyed per *global*
+(client, class) slot, and ``core.head.train_head`` trains on it.
 
 Mesh execution (DESIGN.md §5): ``FedSession(mesh=…)`` or ``shards=n``
 routes the round through :meth:`FedSession.run_sharded` — client fits as
 one ``shard_map``'d batched EM per shard, the bf16 wire crossing the mesh
 in a single ``all_gather`` (``core.distributed.fedpft_transfer``), and
-the server phase data-parallel on the replicated parameters.  The wire
+the server phase replicated on every shard.  The wire
 layout is ONE contract shared with the host codec (``gmm.WIRE_FIELDS`` /
 ``gmm.tril_pack``): :func:`messages_from_wire` turns the gathered pytree
 into byte-accurate :class:`ClientMessage`s.
@@ -63,15 +61,10 @@ from repro.fl import round as FR
 __all__ = [
     "QuantizedCodec", "WireHeader", "ClientMessage", "GMMSummarizer",
     "HeadSummarizer", "Star", "Chain", "Ring", "FedSession", "SessionResult",
-    "SYNTHESIS_MODES", "encode_message", "stack_messages",
-    "messages_from_wire", "decode_payload", "fused_slot_stack",
-    "synthesize_batched", "synthesize_chunks", "synthesize_group_chunks",
-    "synthesize_looped",
+    "encode_message", "stack_messages", "messages_from_wire",
+    "decode_payload", "fused_slot_stack", "synthesize_batched",
+    "synthesize_chunks", "synthesize_groups", "synthesize_looped",
 ]
-
-# server synthesis policies (DESIGN.md §2): when the pool materializes and
-# when it never does
-SYNTHESIS_MODES = ("fused", "streamed", "pooled")
 
 # ---------------------------------------------------------------------------
 # wire codec
@@ -451,31 +444,6 @@ def _sample_stacked(key, slot_ids, pi, mu, cov, S: int,
     return jax.vmap(one)(keys, pi, mu, fac)
 
 
-def _shard_bucket(mesh, slots, arrays):
-    """Lay one bucket's flat ``(G_b, …)`` stacks out data-parallel over the
-    mesh's "data" axis.
-
-    The pow2 planner produces arbitrary bucket sizes, so the stack is
-    first padded to a multiple of the axis (repeating the last slot —
-    the caller slices the padding rows back off the samples) and then
-    placed ``P("data")``: every device really owns ``⌈G_b/n⌉`` slots
-    instead of silently replicating the whole bucket.
-
-    Values are sharding-independent: every slot's draw is keyed by its
-    *global* slot id inside :func:`_sample_stacked` and no op crosses
-    slots, so sharding (and the discarded padding) moves FLOPs across
-    devices without changing a bit of the result — the
-    shard-count-invariance tests lean on this.
-    """
-    n = mesh.shape["data"]
-    pad = (-int(slots.shape[0])) % n
-    grow = lambda a: jnp.concatenate(
-        [a, jnp.repeat(a[-1:], pad, axis=0)]) if pad else a
-    put = lambda a: jax.device_put(grow(a), jax.sharding.NamedSharding(
-        mesh, jax.sharding.PartitionSpec("data")))
-    return put(slots), tuple(put(a) for a in arrays)
-
-
 def fused_slot_stack(batch: Dict[str, jax.Array], counts,
                      samples_per_class: Optional[int] = None):
     """Gather the planner's :class:`~repro.fl.planner.SlotTable` rows from
@@ -504,49 +472,30 @@ def fused_slot_stack(batch: Dict[str, jax.Array], counts,
     return stack, labels, jnp.asarray(table.counts), plan
 
 
-def synthesize_group_chunks(key, items,
-                            samples_per_class: Optional[int] = None,
-                            policy: str = "pow2", mesh=None
-                            ) -> Tuple[List[Tuple[jax.Array, jax.Array]],
-                                       List[P.SynthesisPlan]]:
-    """Planned synthesis over a possibly-heterogeneous cohort → chunk list.
+def synthesize_groups(key, items, samples_per_class: Optional[int] = None
+                      ) -> Tuple[jax.Array, jax.Array]:
+    """Pooled synthesis over a possibly-heterogeneous cohort.
 
     ``items``: sequence of ``(params, counts, cov_type)`` per client.
     Clients with matching (cov_type, param shapes) stack into one group —
     one :func:`plan_synthesis` plan per group, one jitted sample per count
     bucket; a mixed-K/cov cohort (paper §6.3) gets one plan per family.
     The fold_in per group keeps draws deterministic in sorted-group order.
-
-    Returns ``(chunks, plans)`` where every chunk is a compacted
-    ``(feats, labels)`` pair — stream them into
-    ``core.head.train_head_streaming`` or concatenate for the pooled view.
+    Returns the pooled ``(N, d)`` features and ``(N,)`` labels.
     """
     groups: Dict[Tuple, List] = {}
     for params, counts, cov_type in items:
         sig = (cov_type,) + tuple(np.shape(params[f]) for f in _GMM_FIELDS)
         groups.setdefault(sig, []).append((params, counts))
-    chunks, plans = [], []
+    chunks = []
     for gi, (sig, members) in enumerate(sorted(groups.items())):
         batch = jax.tree.map(lambda *xs: jnp.stack(xs),
                              *[p for p, _ in members])
         counts = np.stack([np.asarray(jax.device_get(c)) for _, c in
                            members])
-        ch, plan = synthesize_chunks(jax.random.fold_in(key, gi), batch,
-                                     counts, sig[0], samples_per_class,
-                                     policy=policy, mesh=mesh)
+        ch, _ = synthesize_chunks(jax.random.fold_in(key, gi), batch,
+                                  counts, sig[0], samples_per_class)
         chunks.extend(ch)
-        plans.append(plan)
-    return chunks, plans
-
-
-def synthesize_groups(key, items, samples_per_class: Optional[int] = None
-                      ) -> Tuple[jax.Array, jax.Array]:
-    """Pooled synthesis over a possibly-heterogeneous cohort.
-
-    The concatenating view of :func:`synthesize_group_chunks` — same plans,
-    same draws, one (N, d) feature pool.
-    """
-    chunks, _ = synthesize_group_chunks(key, items, samples_per_class)
     return (jnp.concatenate([f for f, _ in chunks]),
             jnp.concatenate([y for _, y in chunks]))
 
@@ -555,8 +504,7 @@ def synthesize_chunks(key, batch: Dict[str, jax.Array], counts,
                       cov_type: str,
                       samples_per_class: Optional[int] = None,
                       policy: str = "pow2",
-                      plan: Optional[P.SynthesisPlan] = None,
-                      mesh=None
+                      plan: Optional[P.SynthesisPlan] = None
                       ) -> Tuple[List[Tuple[jax.Array, jax.Array]],
                                  P.SynthesisPlan]:
     """Algorithm 1, lines 13-16, executed bucket-by-bucket.
@@ -579,10 +527,6 @@ def synthesize_chunks(key, batch: Dict[str, jax.Array], counts,
     ``(chunks, plan)``; chunks is a list of compacted ``(feats (n, d),
     labels (n,))`` pairs in ascending-bucket order, and is never empty —
     an all-zero cohort yields one ``(0, d)`` chunk.
-
-    ``mesh``: lay each bucket's slot stack out data-parallel over the
-    mesh's "data" axis before sampling (:func:`_shard_bucket`) — same
-    values, FLOPs spread across shards.
     """
     counts = np.asarray(jax.device_get(counts), np.int64)
     if counts.ndim == 1:
@@ -607,16 +551,11 @@ def synthesize_chunks(key, batch: Dict[str, jax.Array], counts,
     for b in plan.buckets:
         slots = jnp.asarray(b.slots)
         stacks = (flat["pi"][slots], flat["mu"][slots], flat["cov"][slots])
-        if mesh is not None:
-            # data-parallel server phase: each device samples its share of
-            # the bucket's slots (mesh mode, DESIGN.md §5)
-            slots, stacks = _shard_bucket(mesh, slots, stacks)
         # the shared key is deliberate: _sample_stacked folds it per
         # GLOBAL slot id, so draws are bucket-partition-invariant and
         # never collide across buckets (slots are disjoint)
         samples = _sample_stacked(key, slots, *stacks,  # lint: disable=KEY-CHAIN
                                   b.S, cov_type)               # (G_b, S, d)
-        samples = samples[: len(b.slots)]   # drop _shard_bucket's padding
         # compact away the padding rows host-side: one gather per bucket
         keep = np.arange(b.S)[None, :] < b.n_eff[:, None]      # (G_b, S)
         idx = np.flatnonzero(keep)
@@ -851,33 +790,21 @@ class FedSession:
     aggregate: str = "synthesize"  # "synthesize" | "avg" | "ensemble" | "fedbe"
     client_summarizers: Optional[Tuple[Any, ...]] = None  # heterogeneous K/cov
     min_class_count: int = 0       # don't transmit classes below this count
-    # -- server synthesis policy (DESIGN.md §2) -----------------------------
-    #   "fused"    (default) zero-materialization: the head trains straight
-    #              from the mixture-slot stack, minibatches drawn inside ONE
-    #              jitted scan (head.train_head_from_gmms) — the pooled
-    #              (N, d) tensor never exists.  Heterogeneous cohorts
-    #              (mixed K / cov family, paper §6.3) can't stack into one
-    #              slot tensor and fall back to "pooled"
-    #              (info["synthesis_fallback"]).
-    #   "streamed" planner buckets are materialized as chunks and streamed
-    #              into train_head_streaming — peak O(largest bucket)
-    #   "pooled"   the pre-fusion path: synthesize everything, concat, train
-    synthesis: str = "fused"
     # -- AOT round-program cache (DESIGN.md §11) ----------------------------
     #   a launch.aot_cache.ProgramCache: the fused server phase runs as an
-    #   ahead-of-time compiled round program, cohorts padded to the cache's
-    #   canonical signature grid (bit-identical heads — count-0 identity
-    #   pads are no-ops).  One cache instance serves the host, mesh, and
-    #   ingest paths; hit/miss + amortized latency land in info["compile"].
-    #   Heterogeneous cohorts (mixed K/cov, §6.3) bypass it via the usual
-    #   pooled fallback.
+    #   ahead-of-time compiled round program, slot stacks padded to the
+    #   cache's canonical signature grid (bit-identical heads — count-0
+    #   identity pads are no-ops).  One cache instance serves the host,
+    #   mesh, and ingest paths; hit/miss + amortized latency land in
+    #   info["compile"].  Heterogeneous cohorts (mixed K/cov, §6.3) bypass
+    #   it via the pooled fallback.
     program_cache: Optional[Any] = None
     # -- streaming ingestion (DESIGN.md §9) ---------------------------------
     #   IngestConfig routes the server phase through fl.ingest: arriving
     #   messages fold into a fixed-capacity reservoir chunk-at-a-time, so
     #   peak server memory and the fused scan's compile key are independent
-    #   of the cohort size M.  Requires synthesis="fused".  The bytes of the
-    #   reservoir stack sent to the device land in info["staged_bytes"].
+    #   of the cohort size M.  The bytes of the reservoir stack sent to the
+    #   device land in info["staged_bytes"].
     ingest: Optional[IG.IngestConfig] = None
     # -- fault policy (DESIGN.md §13) ---------------------------------------
     #   ResilienceConfig arms the wire-level quarantine gate on the
@@ -995,115 +922,20 @@ class FedSession:
 
     # -- server side --------------------------------------------------------
 
-    def _synthesize_all(self, key, messages: Sequence[ClientMessage],
-                        mesh=None
-                        ) -> Tuple[List[Tuple[jax.Array, jax.Array]],
-                                   List[P.SynthesisPlan]]:
-        return synthesize_group_chunks(
-            key, [(m.params, m.counts, m.header.cov_type)
-                  for m in messages], self.samples_per_class, mesh=mesh)
-
-    def _synthesis_mode(self) -> str:
-        if self.synthesis not in SYNTHESIS_MODES:
-            raise ValueError(
-                f"FedSession: unknown synthesis={self.synthesis!r} — choose "
-                f"one of {SYNTHESIS_MODES}")
-        return self.synthesis
-
     def _fused_slot_stack(self, messages: Sequence[ClientMessage]):
-        """(slot stack, labels, counts, plan) for the fused path, or None
-        if the cohort is heterogeneous (mixed K / cov family, §6.3) and
-        can't stack into one (G, K, …) tensor."""
+        """``(slot stack, plan)`` for :meth:`_fused_round` — the stack as
+        ``(pi, mu, cov, slot_labels, slot_counts)`` — or None if the cohort
+        is heterogeneous (mixed K / cov family, §6.3) and can't stack into
+        one (G, K, …) tensor."""
         sigs = {(m.header.cov_type,)
                 + tuple(np.shape(m.params[f]) for f in _GMM_FIELDS)
                 for m in messages}
         if len(sigs) > 1:
             return None
-        return fused_slot_stack(stack_messages(messages),
-                                np.stack([m.counts for m in messages]),
-                                self.samples_per_class)
-
-    def _exec_cached(self, prog, hit: bool, sig, canon, info: Dict, args,
-                     mesh=None):
-        """Run one cache entry and fill ``info["compile"]`` (hit/miss,
-        compile vs run vs compile-amortized latency, live cache counters).
-        ``args`` is the round program's positional list ``(key, pi, mu,
-        cov, counts[, slot_labels])``; under a mesh every operand is
-        pinned replicated to match the executable's AOT input shardings."""
-        cache = self.program_cache
-        if mesh is not None:
-            repl = jax.sharding.NamedSharding(
-                mesh, jax.sharding.PartitionSpec())
-            args = [a if a is None else jax.device_put(a, repl)
-                    for a in args]
-        with obs.span("round.program") as run:
-            head_params, losses = prog(*args)
-            jax.block_until_ready(head_params)
-        run_us = run.elapsed_ns / 1e3
-        info["compile"] = {
-            "hit": hit, "aot": prog.aot,
-            "signature": dataclasses.astuple(sig),
-            "canonical": dataclasses.astuple(canon),
-            "compile_us": prog.compile_us, "run_us": run_us,
-            # compile cost spread over every round the entry has served —
-            # the multi-tenant metric compile_bench tracks
-            "amortized_us": prog.compile_us / max(prog.uses, 1) + run_us,
-            "cache": cache.stats(),
-        }
-        return head_params, losses
-
-    def _cached_round(self, k_head, messages: Sequence[ClientMessage],
-                      sig, info: Dict, mesh=None) -> SessionResult:
-        """Serve the fused server phase from the AOT round-program cache
-        (DESIGN.md §11): stack the wire tensors, pad the cohort up to the
-        cache's canonical signature (leading ``gmm.identity_gmm`` count-0
-        clients — exact no-ops, so the head is bit-identical to the
-        compacted path), and run the compiled executable."""
-        cache = self.program_cache
-        stack, counts = FR.wire_stack(messages)
-        info["synthesis"] = "fused"
-        n_eff = counts if self.samples_per_class is None else \
-            np.where(counts > 0, self.samples_per_class, 0)
-        if int((n_eff > 0).sum()) == 0:
-            # every class filtered — mirrors the empty-plan guard
-            return self._empty_cohort_result(k_head, info, messages)
-        canon = cache.canonical(sig)
-        stack, counts = FR.pad_cohort(stack, counts, sig, canon)
-        hits0 = cache.hits
-        prog = cache.get(sig, self.head,
-                         samples_per_class=self.samples_per_class,
-                         mesh=mesh)
-        args = [k_head, jnp.asarray(stack["pi"]), jnp.asarray(stack["mu"]),
-                jnp.asarray(stack["cov"]), jnp.asarray(counts), None]
-        head_params, losses = self._exec_cached(
-            prog, cache.hits > hits0, sig, canon, info, args, mesh=mesh)
-        info.update(head_losses=losses)
-        return SessionResult(model=head_params, info=info,
-                             messages=list(messages))
-
-    def _cached_round_from_state(self, k_head, state: "IG.IngestState",
-                                 info: Dict, messages,
-                                 mesh=None) -> SessionResult:
-        """Streaming counterpart of :meth:`_cached_round`: the reservoir's
-        padded stack is already a fixed-shape decoded slot stack
-        (``layout="slots"`` at M = capacity; ``samples_per_class`` was
-        applied at fold time, so the program gets None)."""
-        cache = self.program_cache
-        with obs.span("round.stage"):
-            sig = FR.signature_of_state(state)
-            canon = cache.canonical(sig)
-            stack = FR.pad_slots(*state.padded_stack(), sig, canon)
-            info["staged_bytes"] = sum(a.nbytes for a in stack)
-            pi, mu, cov, slot_labels, slot_counts = jax.block_until_ready(
-                tuple(map(jnp.asarray, stack)))
-        hits0 = cache.hits
-        prog = cache.get(sig, self.head, samples_per_class=None, mesh=mesh)
-        args = [k_head, pi, mu, cov, slot_counts, slot_labels]
-        head_params, losses = self._exec_cached(
-            prog, cache.hits > hits0, sig, canon, info, args, mesh=mesh)
-        info.update(head_losses=losses)
-        return SessionResult(model=head_params, info=info,
-                             messages=list(messages))
+        stack, labels, counts, plan = fused_slot_stack(
+            stack_messages(messages), np.stack([m.counts for m in messages]),
+            self.samples_per_class)
+        return (stack["pi"], stack["mu"], stack["cov"], labels, counts), plan
 
     def _empty_cohort_result(self, k_head, info: Dict, messages,
                              d: Optional[int] = None) -> SessionResult:
@@ -1120,38 +952,121 @@ class FedSession:
         return SessionResult(model=H.init_head(k_head, d, self.n_classes),
                              info=info, messages=list(messages))
 
-    def _check_ingest_mode(self) -> None:
-        if self._synthesis_mode() != "fused":
-            raise ValueError(
-                "FedSession(ingest=...): streaming ingestion trains the "
-                "head straight from the bounded slot reservoir — only "
-                "synthesis='fused' never materializes the cohort; drop "
-                "ingest= for the 'streamed'/'pooled' A/B paths")
+    def _fused_round(self, k_head, make_stack, cov_type: str, info: Dict,
+                     messages: Sequence[ClientMessage] = (),
+                     mesh=None) -> SessionResult:
+        """THE server phase (DESIGN.md §2, §11): train the head straight
+        from a flat slot stack ``(pi, mu, cov, slot_labels, slot_counts)``
+        — the pooled ``(N, d)`` tensor never exists.  ``make_stack()``
+        returns the stack; it runs inside the ``round.stage`` span, so the
+        span covers unpacking the reservoir.
 
-    def _train_from_state(self, k_head, state: "IG.IngestState",
-                          info: Dict, messages, mesh=None) -> SessionResult:
-        """Fused head training on the reservoir's fixed-shape padded stack
-        — the streaming counterpart of the ``mode == "fused"`` branch of
-        :meth:`server_aggregate`; compile key = capacity, not M."""
-        if self.program_cache is not None:
-            return self._cached_round_from_state(k_head, state, info,
-                                                 messages, mesh=mesh)
+        Every GMM route ends here: the message list (host and mesh) with
+        :meth:`_fused_slot_stack`'s compacted stack, the ingest session
+        and :meth:`aggregate_from_broker` with the reservoir's padded one.
+        With a ``program_cache`` the stack is padded to the cache's
+        canonical signature (leading ``gmm.identity_gmm`` rows of count 0
+        — exact no-ops, so the head is bit-identical to the exact-shape
+        run) and the AOT executable runs; without one,
+        ``head.train_head_from_gmms`` runs at the exact shape.  Under a
+        mesh every operand is pinned to the replicated layout: same inputs
+        and the same RNG on every shard train identical heads, and the
+        layout matches the executable's AOT input shardings (DESIGN.md
+        §5).  A stack with no draw mass (every class filtered) returns the
+        freshly-initialized head.  Fills ``info["staged_bytes"]``,
+        ``info["head_losses"]`` and, when cached, ``info["compile"]``.
+        """
+        cache = self.program_cache
         with obs.span("round.stage"):
-            stack = state.padded_stack()
+            stack = make_stack()
+            M, K, d = (int(n) for n in np.shape(stack[1]))
+            if not np.any(np.asarray(jax.device_get(stack[4])) > 0):
+                return self._empty_cohort_result(k_head, info, messages,
+                                                 d=d)
+            sig = FR.CohortSignature(M=M, C=self.n_classes, K=K, d=d,
+                                     cov_type=cov_type)
+            if cache is not None:
+                canon = cache.canonical(sig)
+                stack = FR.pad_slots(*stack, sig, canon)
             info["staged_bytes"] = sum(a.nbytes for a in stack)
             staged = tuple(map(jnp.asarray, stack))
             if mesh is not None:
                 repl = jax.sharding.NamedSharding(
                     mesh, jax.sharding.PartitionSpec())
+                k_head = jax.device_put(k_head, repl)
                 staged = tuple(jax.device_put(a, repl) for a in staged)
             pi, mu, cov, slot_labels, slot_counts = jax.block_until_ready(
                 staged)
-        head_params, losses = H.train_head_from_gmms(
-            k_head, pi, mu, cov, slot_labels, slot_counts,
-            self.n_classes, self.head, state.cov_type)
-        info.update(head_losses=losses)
+        if cache is None:
+            with obs.span("round.program"):
+                head_params, losses = H.train_head_from_gmms(
+                    k_head, pi, mu, cov, slot_labels, slot_counts,
+                    self.n_classes, self.head, cov_type)
+                jax.block_until_ready(head_params)
+        else:
+            hits0 = cache.hits
+            prog = cache.get(sig, self.head, mesh=mesh)
+            with obs.span("round.program") as run:
+                head_params, losses = prog(k_head, pi, mu, cov, slot_counts,
+                                           slot_labels)
+                jax.block_until_ready(head_params)
+            run_us = run.elapsed_ns / 1e3
+            info["compile"] = {
+                "hit": cache.hits > hits0, "aot": prog.aot,
+                "signature": dataclasses.astuple(sig),
+                "canonical": dataclasses.astuple(canon),
+                "compile_us": prog.compile_us, "run_us": run_us,
+                # compile cost spread over every round the entry has
+                # served — the multi-tenant metric compile_bench tracks
+                "amortized_us": prog.compile_us / max(prog.uses, 1) + run_us,
+                "cache": cache.stats(),
+            }
+        info["head_losses"] = losses
         return SessionResult(model=head_params, info=info,
                              messages=list(messages))
+
+    def _pooled_round(self, k_syn, k_head,
+                      messages: Sequence[ClientMessage],
+                      info: Dict) -> SessionResult:
+        """The heterogeneous-cohort fallback (mixed K / cov family, paper
+        §6.3): no one slot stack holds the cohort, so the pool is
+        synthesized per schema group (:func:`synthesize_groups`) and the
+        head trains on it.  Mesh cohorts share one summarizer, so they
+        never land here."""
+        info["synthesis"] = "pooled"
+        info["synthesis_fallback"] = "heterogeneous cohort"
+        feats, labels = synthesize_groups(
+            k_syn, [(m.params, m.counts, m.header.cov_type)
+                    for m in messages], self.samples_per_class)
+        if feats.shape[0] == 0:
+            return self._empty_cohort_result(k_head, info, messages)
+        head_params, losses = H.train_head(k_head, feats, labels,
+                                           self.n_classes, self.head)
+        info.update(synthetic_feats=feats, synthetic_labels=labels,
+                    head_losses=losses)
+        return SessionResult(model=head_params, info=info,
+                             messages=list(messages))
+
+    def _close_broker(self, key, broker, info: Dict,
+                      expected: Optional[int],
+                      messages: Sequence[ClientMessage] = (),
+                      mesh=None) -> SessionResult:
+        """Seal ``broker`` and train the head from its reservoir — the key
+        plumbing (``_, k_head = split(key)``, the fused branch's ``(k_syn,
+        k_head)`` split, so heads are bit-identical either way), byte
+        accounting and fault info every broker route shares."""
+        state = broker.close()
+        _, k_head = jax.random.split(key)
+        info = {"synthesis": "fused", **info}
+        acct = broker.accounting()
+        info["ingest"] = acct
+        info.setdefault("comm_bytes", acct["sent_bytes"])
+        _merge_fault_info(info, acct, expected=expected)
+        if state is None:
+            return self._empty_cohort_result(k_head, info, messages,
+                                             d=broker.header_d)
+        return self._fused_round(k_head, state.padded_stack,
+                                 state.cov_type, info, messages, mesh=mesh)
 
     def _ingest_aggregate(self, key, messages: Sequence[ClientMessage],
                           info: Dict, mesh=None) -> SessionResult:
@@ -1163,23 +1078,12 @@ class FedSession:
         streaming loop, so this path (host or mesh) and
         :meth:`_run_streaming` share one state machine.
         """
-        self._check_ingest_mode()
         broker = IG.IngestBroker(self.ingest, self.n_classes,
                                  samples_per_class=self.samples_per_class)
         for i, m in enumerate(messages):
             broker.submit(i, m)
-        state = broker.close()
-        _, k_head = jax.random.split(key)   # mirrors the fused branch's
-        #   (k_syn, k_head) split — bit-identical head keys either way
-        info["synthesis"] = "fused"
-        acct = broker.accounting()
-        info["ingest"] = acct
-        _merge_fault_info(info, acct, expected=len(messages))
-        if state is None or len(state.slot_table()) == 0:
-            return self._empty_cohort_result(k_head, info, messages,
-                                             d=broker.header_d)
-        return self._train_from_state(k_head, state, info, messages,
-                                      mesh=mesh)
+        return self._close_broker(key, broker, info, len(messages),
+                                  messages, mesh=mesh)
 
     def aggregate_from_broker(self, key, broker,
                               info: Optional[Dict] = None,
@@ -1199,20 +1103,7 @@ class FedSession:
         coverage fraction against ``expected_clients`` (default: distinct
         client ids the broker saw).
         """
-        self._check_ingest_mode()
-        state = broker.close()
-        _, k_head = jax.random.split(key)
-        base: Dict = {"synthesis": "fused"}
-        if info:
-            base.update(info)
-        acct = broker.accounting()
-        base["ingest"] = acct
-        base.setdefault("comm_bytes", acct["sent_bytes"])
-        _merge_fault_info(base, acct, expected=expected_clients)
-        if state is None or len(state.slot_table()) == 0:
-            return self._empty_cohort_result(k_head, base, [],
-                                             d=broker.header_d)
-        return self._train_from_state(k_head, state, base, messages=[])
+        return self._close_broker(key, broker, info or {}, expected_clients)
 
     def server_aggregate(self, key, messages: Sequence[ClientMessage],
                          mesh=None) -> SessionResult:
@@ -1224,7 +1115,6 @@ class FedSession:
         if kind == "gmm" and self.ingest is not None:
             return self._ingest_aggregate(key, messages, info, mesh=mesh)
         if kind == "gmm":
-            mode = self._synthesis_mode()
             k_syn, k_head = jax.random.split(key)
             if self.resilience is not None and self.resilience.validate:
                 # wire-level quarantine, host and mesh paths (§13): drop
@@ -1244,70 +1134,15 @@ class FedSession:
                         return self._empty_cohort_result(k_head, info, [],
                                                          d=d0)
                     messages = kept
-            if mode == "fused" and self.program_cache is not None:
-                try:
-                    sig = FR.signature_of(messages)
-                except ValueError:
-                    sig = None   # heterogeneous (§6.3): pooled fallback below
-                if sig is not None:
-                    return self._cached_round(k_head, messages, sig, info,
-                                              mesh=mesh)
-            fused = None
-            if mode == "fused":
-                fused = self._fused_slot_stack(messages)
-                if fused is None:
-                    # mixed-K/cov cohorts keep the materializing path
-                    mode = "pooled"
-                    info["synthesis_fallback"] = "heterogeneous cohort"
-            info["synthesis"] = mode
-            # head training runs replicated on every shard (same RNG, same
-            # steps) — pin its inputs to an explicit replicated layout so
-            # the jits see ONE sharding whatever the sampling left behind
-            # (DESIGN.md §5)
-            repl = None if mesh is None else jax.sharding.NamedSharding(
-                mesh, jax.sharding.PartitionSpec())
-            if mode == "fused":
-                stack, slot_labels, slot_counts, plan = fused
-                info["synthesis_plans"] = [plan]
-                if len(plan.slot_table) == 0:
-                    return self._empty_cohort_result(k_head, info, messages)
-                if repl is not None:
-                    # the fused scan runs replicated on the post-all_gather
-                    # stack: same inputs + same RNG ⇒ identical steps on
-                    # every shard (DESIGN.md §5)
-                    stack = {k: jax.device_put(v, repl)
-                             for k, v in stack.items()}
-                    slot_labels = jax.device_put(slot_labels, repl)
-                    slot_counts = jax.device_put(slot_counts, repl)
-                head_params, losses = H.train_head_from_gmms(
-                    k_head, stack["pi"], stack["mu"], stack["cov"],
-                    slot_labels, slot_counts, self.n_classes, self.head,
-                    messages[0].header.cov_type)
-                info.update(head_losses=losses)
-                return SessionResult(model=head_params, info=info,
-                                     messages=list(messages))
-            chunks, plans = self._synthesize_all(k_syn, messages, mesh=mesh)
-            info["synthesis_plans"] = plans
-            n_syn = sum(int(f.shape[0]) for f, _ in chunks)
-            if n_syn == 0:
-                return self._empty_cohort_result(k_head, info, messages)
-            if mode == "streamed":
-                head_params, losses = H.train_head_streaming(
-                    k_head, chunks, self.n_classes, self.head,
-                    chunk_sharding=repl)
-                info.update(synthetic_chunks=chunks, head_losses=losses)
-            else:
-                feats = jnp.concatenate([f for f, _ in chunks])
-                labels = jnp.concatenate([y for _, y in chunks])
-                if repl is not None:
-                    feats = jax.device_put(feats, repl)
-                    labels = jax.device_put(labels, repl)
-                head_params, losses = H.train_head(k_head, feats, labels,
-                                                   self.n_classes, self.head)
-                info.update(synthetic_feats=feats, synthetic_labels=labels,
-                            head_losses=losses)
-            return SessionResult(model=head_params, info=info,
-                                 messages=list(messages))
+            fused = self._fused_slot_stack(messages)
+            if fused is None:
+                return self._pooled_round(k_syn, k_head, messages, info)
+            stack, plan = fused
+            info["synthesis"] = "fused"
+            info["synthesis_plans"] = [plan]
+            return self._fused_round(k_head, lambda: stack,
+                                     messages[0].header.cov_type, info,
+                                     messages, mesh=mesh)
         # head-level aggregation (one-shot baselines) — estimators match
         # the paper's: uniform AVG, FedBE with 10 posterior samples
         from repro.fl import baselines as FB
@@ -1382,10 +1217,10 @@ class FedSession:
         ``transfer_seed + i`` for client i) and ``all_gather``s the bf16
         wire pytree — that collective is the round.  Server phase: the
         replicated wire decodes through the SAME codec layout host clients
-        use (:func:`messages_from_wire`), then planner-bucketed synthesis
-        runs data-parallel over the mixture slots and the head trains
-        replicated on every shard.  Results are shard-count invariant up
-        to wire precision (tests/multidevice).
+        use (:func:`messages_from_wire`), then the fused server phase
+        (:meth:`_fused_round`) trains the head replicated on every shard.
+        Results are shard-count invariant up to wire precision
+        (tests/multidevice).
         """
         n_shards = self.shards if self.mesh is None and \
             self.shards is not None else None
@@ -1413,7 +1248,7 @@ class FedSession:
         messages, wire_rejs = decoded if validate else (decoded, [])
         if not messages:
             # every client quarantined at the mesh wire: the empty-cohort
-            # guard, with the same (k_syn, k_head) split as the fused path
+            # guard, with the same (k_syn, k_head) split as server_aggregate
             _, k_head = jax.random.split(key)
             info: Dict = {
                 "comm_bytes": 0,
@@ -1461,7 +1296,6 @@ class FedSession:
         k_head)`` split), so under capacity the returned head is
         bit-identical to the non-streaming fused session's.
         """
-        self._check_ingest_mode()
         if not isinstance(self.topology, Star):
             raise NotImplementedError(
                 f"FedSession(ingest=...): the broker receives one-shot "
@@ -1525,7 +1359,6 @@ class FedSession:
                 "FedSession.run(faults=...): chaos injection wraps the "
                 "host wire; the mesh round has no per-message delivery "
                 "to perturb")
-        self._check_ingest_mode()
         if not isinstance(self.topology, Star):
             raise NotImplementedError(
                 f"FedSession.run(faults=...): fault schedules target the "

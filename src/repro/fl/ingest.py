@@ -332,8 +332,8 @@ def fold_messages(state: IngestState,
                 f"(C={h.n_classes}, cov={h.cov_type!r}, K={h.K}, d={h.d}) "
                 f"≠ state schema (C={C}, cov={state.cov_type!r}, "
                 f"K={state.K}, d={state.d}) — heterogeneous cohorts can't "
-                "share one slot reservoir; run the host path with "
-                "synthesis='pooled' (paper §6.3)")
+                "share one slot reservoir; drop ingest= so the host path "
+                "falls back to pooled synthesis (paper §6.3)")
         counts = msg.counts
         n_eff = counts if samples_per_class is None else \
             np.where(counts > 0, samples_per_class, 0).astype(np.int64)

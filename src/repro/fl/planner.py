@@ -16,8 +16,9 @@ policy reproduces the old monolithic padded dispatch (one bucket at the
 global max) — kept for the A/B in ``benchmarks/synthesize_bench.py``.
 
 The planner is pure host-side bookkeeping over the counts matrix; execution
-(one ``_sample_stacked`` call per bucket, streamed into head training)
-lives in :mod:`repro.fl.api`.
+(one ``_sample_stacked`` call per bucket for the heterogeneous-cohort
+fallback, the flat :class:`SlotTable` for the fused server phase) lives in
+:mod:`repro.fl.api`.
 """
 from __future__ import annotations
 

@@ -1,68 +1,44 @@
 """The server phase as a pure, AOT-compilable **round program** (DESIGN.md §11).
 
-``FedSession.server_aggregate`` historically traced+compiled inside the
-request path: every new cohort signature (M, C, K, d, cov_type, dtype) paid
-full compile latency before its round could run.  This module extracts the
-fused server phase — decode wire → slot grid → ``head.fused_gmm_steps`` —
-into :func:`round_program`, a jitted function of arrays plus ONE static
+``FedSession`` trains the global head from a flat mixture-slot stack.  This
+module holds that server phase — slot stack → ``head.fused_gmm_steps`` — as
+:func:`round_program`, a jitted function of arrays plus ONE static
 :class:`CohortSignature`, so ``launch.aot_cache`` can lower+compile it ahead
 of time per canonical signature and serve every matching cohort from the
 executable cache.
 
-Two layouts, one program:
-
-* ``layout="wire"`` — inputs are the stacked wire tensors exactly as
-  encoded (``pi (M, C, K)``, ``mu (M, C, K, d)``, ``cov (M, C) + packed``
-  in the codec's wire dtype, ``counts (M, C)`` int32).  Decode (cast to
-  f32, tril-unpack full covariances) and slot-grid layout happen INSIDE the
-  compiled program.  The slot grid is the full M·C lattice in client-major
-  order with absent classes left in place at count 0 — unlike the host
-  path's compacted ``SlotTable``, its shape is a pure function of the
-  signature.
-* ``layout="slots"`` — inputs are an already-decoded flat slot stack
-  (``pi (M, K)``, ``mu (M, K, d)``, ``cov (M, K, …)`` unpacked f32,
-  ``slot_labels (M,)``, ``counts (M,)``): the streaming reservoir's
-  ``IngestState.padded_stack()`` at ``M == capacity``.
+The program has one input format, the decoded slot stack: ``pi (M, K)``,
+``mu (M, K, d)``, ``cov (M, K, …)`` unpacked f32, ``slot_labels (M,)`` and
+``counts (M,)``.  Every route builds it the same way — the message list
+through ``fl.api.fused_slot_stack`` (one row per present (client, class)
+slot, ``samples_per_class`` already applied), the streaming reservoir
+through ``IngestState.padded_stack()`` at ``M == capacity``.
 
 Zero-count rows anywhere in the stack are exact no-ops under the fused
 trainer (f32 cumulative mass adds 0.0 exactly; ``gmm.draw_slots``'
-``searchsorted(side="right")`` never selects a zero-mass row), so both the
-full-grid layout and the leading :func:`gmm.identity_gmm` pad clients of
-:func:`pad_cohort` train heads **bit-identical** to the compacted host path
-— the same argument DESIGN.md §9 makes for the reservoir's pad prefix,
-asserted bitwise in tests/test_aot_cache.py.
+``searchsorted(side="right")`` never selects a zero-mass row), so the
+leading :func:`gmm.identity_gmm` pad rows of :func:`pad_slots` train heads
+**bit-identical** to the exact-shape stack — the same argument DESIGN.md §9
+makes for the reservoir's pad prefix, asserted bitwise in
+tests/test_aot_cache.py.
 """
 from __future__ import annotations
 
 import dataclasses
 from functools import partial
-from typing import Dict, Optional, Sequence, Tuple
+from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-import ml_dtypes
 import numpy as np
 
 from repro.core import gmm as G
 from repro.core import head as H
 
 __all__ = [
-    "CohortSignature", "WIRE_DTYPES", "next_pow2", "signature_of",
-    "signature_of_state", "wire_stack", "pad_cohort", "pad_slots",
+    "CohortSignature", "next_pow2", "pad_slots",
     "round_program",
 ]
-
-# codec dtype name → numpy dtype of the wire tensors.  Mirrors
-# ``fl.api._WIRE_DTYPES`` (the codec owns the byte layout; this module only
-# needs the dtypes to build stand-ins and cast-decode inside the program).
-WIRE_DTYPES = {
-    "float16": np.dtype(np.float16),
-    "bfloat16": np.dtype(ml_dtypes.bfloat16),
-    "float32": np.dtype(np.float32),
-}
-
-LAYOUTS = ("wire", "slots")
-
 
 def next_pow2(n: int) -> int:
     """Smallest power of two ≥ n (planner's ``_bucket_ceiling`` law, n ≥ 1)."""
@@ -75,163 +51,50 @@ def next_pow2(n: int) -> int:
 class CohortSignature:
     """Everything the round program's compile key depends on.
 
-    ``M`` is the client axis (``layout="wire"``) or the flat slot-row axis
-    (``layout="slots"``); ``C``/``K``/``d``/``cov_type`` are the mixture
-    schema; ``dtype`` is the codec dtype the wire tensors arrive in.
-    Frozen + hashable so it can serve directly as a jit static and a cache
-    key — the ``CACHE-KEY`` analyzer rule double-checks hash stability.
+    ``M`` is the flat slot-row axis; ``C``/``K``/``d``/``cov_type`` are the
+    mixture schema (the parameter operands are always f32).  Frozen +
+    hashable so it can serve directly as a jit static and a cache key —
+    the ``CACHE-KEY`` analyzer rule double-checks hash stability.
     """
     M: int
     C: int
     K: int
     d: int
     cov_type: str
-    dtype: str = "bfloat16"
-    layout: str = "wire"
 
     def __post_init__(self):
         if self.cov_type not in G.COV_TYPES:
             raise ValueError(f"CohortSignature: cov_type={self.cov_type!r} "
                              f"∉ {G.COV_TYPES}")
-        if self.dtype not in WIRE_DTYPES:
-            raise ValueError(f"CohortSignature: dtype={self.dtype!r} ∉ "
-                             f"{tuple(WIRE_DTYPES)}")
-        if self.layout not in LAYOUTS:
-            raise ValueError(f"CohortSignature: layout={self.layout!r} ∉ "
-                             f"{LAYOUTS}")
         if min(self.M, self.C, self.K, self.d) < 1:
             raise ValueError(f"CohortSignature: non-positive axis in "
                              f"(M={self.M}, C={self.C}, K={self.K}, "
                              f"d={self.d})")
 
     @property
-    def n_slots(self) -> int:
-        """Rows of the flat slot grid the head trains over."""
-        return self.M * self.C if self.layout == "wire" else self.M
-
-    def cov_shape(self, packed: bool) -> Tuple[int, ...]:
-        """Trailing shape of one slot's cov leaf (packed = wire layout)."""
-        if packed:
-            return G.packed_cov_shape(self.cov_type, self.K, self.d)
+    def cov_shape(self) -> Tuple[int, ...]:
+        """Trailing shape of one slot's (unpacked) cov leaf."""
         if self.cov_type == "full":
             return (self.K, self.d, self.d)
         return (self.K, self.d) if self.cov_type == "diag" else (self.K,)
 
     def canonical(self) -> "CohortSignature":
         """The signature actually compiled for: M rounded up to a power of
-        two (planner bucketing idiom).  C/K/d/cov_type/dtype stay exact —
+        two (planner bucketing idiom).  C/K/d/cov_type stay exact —
         padding K would perturb the in-scan categorical draws and break
         bit-identity; distinct K values are separate grid points instead."""
         return dataclasses.replace(self, M=next_pow2(self.M))
 
 
-def signature_of(messages: Sequence) -> CohortSignature:
-    """Derive the cohort signature from a homogeneous GMM message stack.
-
-    Raises ``ValueError`` on heterogeneous cohorts (mixed K / d / cov
-    family / wire dtype, paper §6.3) — those keep the materializing
-    fallback path, exactly like ``FedSession._fused_slot_stack``.
-    """
-    if not messages:
-        raise ValueError("signature_of needs at least one message")
-    sigs = {(m.header.kind, m.header.cov_type, m.header.K, m.header.d,
-             m.header.n_classes, m.header.dtype) for m in messages}
-    if len(sigs) > 1:
-        raise ValueError(
-            f"signature_of: heterogeneous cohort {sorted(sigs)} — mixed "
-            "schemas can't share one compiled round program")
-    kind, cov_type, K, d, C, dtype = next(iter(sigs))
-    if kind != "gmm":
-        raise ValueError(f"signature_of: round programs train from GMM "
-                         f"summaries, got kind={kind!r}")
-    return CohortSignature(M=len(messages), C=C, K=K, d=d,
-                           cov_type=cov_type, dtype=dtype, layout="wire")
-
-
-def signature_of_state(state) -> CohortSignature:
-    """Signature of an ``ingest.IngestState`` reservoir (already decoded:
-    flat f32 slot rows at the fixed capacity)."""
-    return CohortSignature(M=int(state.capacity), C=int(state.n_classes),
-                           K=int(state.K), d=int(state.d),
-                           cov_type=state.cov_type, dtype="float32",
-                           layout="slots")
-
-
-def wire_stack(messages: Sequence
-               ) -> Tuple[Dict[str, np.ndarray], np.ndarray]:
-    """Stack homogeneous messages into the round program's wire tensors.
-
-    Returns ``({"pi": (M, C, K), "mu": (M, C, K, d), "cov": (M, C) +
-    packed} in the wire dtype, counts (M, C) int32)``.  Values are the
-    decoded f32 params cast BACK to the wire dtype — exact for present
-    classes (they already round-tripped the codec), so the in-program
-    cast-decode reproduces ``m.params`` bitwise.  Absent classes'
-    placeholders may round (e.g. pi = 1/K is not a bf16 lattice point) —
-    harmless, their count-0 rows are never sampled.
-    """
-    sig = signature_of(messages)
-    wd = WIRE_DTYPES[sig.dtype]
-    pi = np.stack([np.asarray(jax.device_get(m.params["pi"]), np.float32)
-                   for m in messages]).astype(wd)
-    mu = np.stack([np.asarray(jax.device_get(m.params["mu"]), np.float32)
-                   for m in messages]).astype(wd)
-    cov = np.stack([np.asarray(jax.device_get(m.params["cov"]), np.float32)
-                    for m in messages])
-    if sig.cov_type == "full":
-        cov = np.asarray(G.tril_pack(cov))
-    cov = cov.astype(wd)
-    counts = np.stack([np.asarray(m.counts, np.int64)
-                       for m in messages]).astype(np.int32)
-    return {"pi": pi, "mu": mu, "cov": cov}, counts
-
-
-def _pad_rows(sig: CohortSignature, n_pad: int, lead_shape: Tuple[int, ...],
-              dtype) -> Dict[str, np.ndarray]:
-    """``n_pad`` identity-GMM pad rows broadcast over ``lead_shape``."""
-    ident = G.identity_gmm(sig.K, sig.d, sig.cov_type)
-    cov = np.asarray(ident["cov"], np.float32)
-    if sig.layout == "wire" and sig.cov_type == "full":
-        cov = np.asarray(G.tril_pack(cov))
-    out = {}
-    for name, row in (("pi", np.asarray(ident["pi"], np.float32)),
-                      ("mu", np.asarray(ident["mu"], np.float32)),
-                      ("cov", cov)):
-        out[name] = np.broadcast_to(
-            row, (n_pad,) + lead_shape + row.shape).astype(dtype)
-    return out
-
-
-def pad_cohort(stack: Dict[str, np.ndarray], counts: np.ndarray,
-               sig: CohortSignature, target: CohortSignature
-               ) -> Tuple[Dict[str, np.ndarray], np.ndarray]:
-    """Pad a wire-layout cohort up to the canonical signature.
-
-    Prepends ``target.M − sig.M`` identity-GMM clients (count 0 on every
-    class) — pads FIRST, mirroring the reservoir's layout (DESIGN.md §9).
-    Leading zero-count rows are exact no-ops under the fused trainer, so
-    the padded cohort trains a bit-identical head at the canonical shape.
-    """
-    if dataclasses.replace(sig, M=target.M) != target:
-        raise ValueError(f"pad_cohort: {sig} only pads along M, target was "
-                         f"{target}")
-    if target.M < sig.M:
-        raise ValueError(f"pad_cohort: target M={target.M} < cohort "
-                         f"M={sig.M} — cohorts are padded up, never cut")
-    n_pad = target.M - sig.M
-    if n_pad == 0:
-        return stack, counts
-    pad = _pad_rows(sig, n_pad, (sig.C,), WIRE_DTYPES[sig.dtype])
-    out = {k: np.concatenate([pad[k], np.asarray(v)]) for k, v in
-           stack.items()}
-    counts = np.concatenate([np.zeros((n_pad, sig.C), np.int32),
-                             np.asarray(counts, np.int32)])
-    return out, counts
-
-
 def pad_slots(pi, mu, cov, slot_labels, counts, sig: CohortSignature,
               target: CohortSignature):
-    """Slot-layout analogue of :func:`pad_cohort` (leading identity rows,
-    label 0, count 0)."""
+    """Pad a slot stack up to the canonical signature.
+
+    Prepends ``target.M − sig.M`` identity-GMM rows (label 0, count 0) —
+    pads FIRST, mirroring the reservoir's layout (DESIGN.md §9).  Leading
+    zero-count rows are exact no-ops under the fused trainer, so the padded
+    stack trains a bit-identical head at the canonical shape.
+    """
     if dataclasses.replace(sig, M=target.M) != target:
         raise ValueError(f"pad_slots: {sig} only pads along M, target was "
                          f"{target}")
@@ -241,56 +104,31 @@ def pad_slots(pi, mu, cov, slot_labels, counts, sig: CohortSignature,
     n_pad = target.M - sig.M
     if n_pad == 0:
         return pi, mu, cov, slot_labels, counts
-    pad = _pad_rows(sig, n_pad, (), np.float32)
-    return (np.concatenate([pad["pi"], np.asarray(pi, np.float32)]),
-            np.concatenate([pad["mu"], np.asarray(mu, np.float32)]),
-            np.concatenate([pad["cov"], np.asarray(cov, np.float32)]),
+    ident = G.identity_gmm(sig.K, sig.d, sig.cov_type)
+    pad = lambda name, a: np.concatenate([
+        np.broadcast_to(np.asarray(ident[name], np.float32),
+                        (n_pad,) + np.shape(ident[name])),
+        np.asarray(a, np.float32)])
+    return (pad("pi", pi), pad("mu", mu), pad("cov", cov),
             np.concatenate([np.zeros((n_pad,), np.int32),
                             np.asarray(slot_labels, np.int32)]),
             np.concatenate([np.zeros((n_pad,), np.int32),
                             np.asarray(counts, np.int32)]))
 
 
-@partial(jax.jit, static_argnames=("sig", "head_cfg", "samples_per_class"))
-def round_program(key, pi, mu, cov, counts, slot_labels=None, *,
-                  sig: CohortSignature, head_cfg: H.HeadConfig,
-                  samples_per_class: Optional[int] = None):
+@partial(jax.jit, static_argnames=("sig", "head_cfg"))
+def round_program(key, pi, mu, cov, counts, slot_labels, *,
+                  sig: CohortSignature, head_cfg: H.HeadConfig):
     """The whole server phase as one pure function of arrays + statics.
 
-    ``layout="wire"``: decode (cast → f32, tril-unpack), lay the full M·C
-    slot grid out client-major (labels = slot index mod C, the wire
-    stack's class axis), apply the ``samples_per_class`` override
-    (``planner.plan_synthesis`` semantics: absent classes stay 0), and run
-    :func:`head.fused_gmm_steps`.  ``layout="slots"``: inputs are already
-    the flat decoded stack (``slot_labels`` required); the reservoir
-    applied ``samples_per_class`` at fold time, so pass ``None``.
-
-    Every shape this traces is a pure function of ``sig`` — the invariant
+    Inputs are the flat slot stack (module docstring); ``counts`` are the
+    per-slot draw counts with ``samples_per_class`` already applied.  Every
+    shape this traces is a pure function of ``sig`` — the invariant
     ``launch.aot_cache`` keys on and ``analysis.compile``'s ``CACHE-KEY``
     rule enforces.  Returns ``(head params, per-step loss trace)``.
     """
-    C, K, d = sig.C, sig.K, sig.d
-    if sig.layout == "wire":
-        n = sig.M * C
-        pi32 = pi.astype(jnp.float32).reshape(n, K)
-        mu32 = mu.astype(jnp.float32).reshape(n, K, d)
-        cov32 = cov.astype(jnp.float32).reshape(
-            (n,) + sig.cov_shape(packed=True))
-        if sig.cov_type == "full":
-            cov32 = G.tril_unpack(cov32, d)
-        labels = jnp.arange(n, dtype=jnp.int32) % C
-        n_eff = counts.reshape(n)
-    else:
-        if slot_labels is None:
-            raise ValueError("round_program: layout='slots' needs "
-                             "slot_labels")
-        pi32 = pi.astype(jnp.float32)
-        mu32 = mu.astype(jnp.float32)
-        cov32 = cov.astype(jnp.float32)
-        labels = slot_labels
-        n_eff = counts
-    if samples_per_class is not None:
-        n_eff = jnp.where(n_eff > 0, samples_per_class, 0)
-    return H.fused_gmm_steps(key, pi32, mu32, cov32, labels,
-                             n_eff.astype(jnp.int32), C, head_cfg,
+    return H.fused_gmm_steps(key, pi.astype(jnp.float32),
+                             mu.astype(jnp.float32),
+                             cov.astype(jnp.float32), slot_labels,
+                             counts.astype(jnp.int32), sig.C, head_cfg,
                              sig.cov_type)

@@ -1,13 +1,14 @@
 """AOT executable cache for the FedPFT round program (DESIGN.md §11).
 
 Serving many concurrent federations means every new cohort signature
-(M, C, K, d, cov_type, dtype) used to pay full trace+compile inside the
+(M, C, K, d, cov_type) used to pay full trace+compile inside the
 request path.  :class:`ProgramCache` bounds that churn:
 
-* cohorts are **canonicalized** — M rounds up to a power of two
-  (``CohortSignature.canonical``, the planner's bucketing idiom) and the
-  session pads with ``gmm.identity_gmm`` count-0 clients, so the cache
-  cardinality is the small canonical grid, not the cohort-size lattice;
+* cohorts are **canonicalized** — the slot-row count M rounds up to a
+  power of two (``CohortSignature.canonical``, the planner's bucketing
+  idiom) and the session pads with ``gmm.identity_gmm`` count-0 rows, so
+  the cache cardinality is the small canonical grid, not the cohort-size
+  lattice;
 * each canonical signature is **AOT lowered+compiled** once
   (``round_program.lower(*round_specs_for(sig)).compile()``), costed via
   ``launch.hlo_cost``, and optionally round-tripped through
@@ -64,7 +65,6 @@ class CachedProgram:
     """
     sig: FR.CohortSignature
     head_cfg: Any
-    samples_per_class: Optional[int]
     fingerprint: Optional[Tuple]
     executable: Any                     # jax.stages.Compiled, or None
     fallback: Any                       # jitted partial when AOT failed
@@ -78,7 +78,7 @@ class CachedProgram:
     def aot(self) -> bool:
         return self.executable is not None
 
-    def __call__(self, key, pi, mu, cov, counts, slot_labels=None):
+    def __call__(self, key, pi, mu, cov, counts, slot_labels):
         if self.executable is not None:
             return self.executable(key, pi, mu, cov, counts, slot_labels)
         return self.fallback(key, pi, mu, cov, counts, slot_labels)
@@ -127,18 +127,17 @@ class ProgramCache:
     def canonical(self, sig: FR.CohortSignature) -> FR.CohortSignature:
         return sig.canonical() if self.canonicalize else sig
 
-    def _key(self, canon, head_cfg, samples_per_class, mesh) -> Tuple:
-        return (canon, head_cfg, samples_per_class, mesh_fingerprint(mesh))
+    def _key(self, canon, head_cfg, mesh) -> Tuple:
+        return (canon, head_cfg, mesh_fingerprint(mesh))
 
     # -- the cache ----------------------------------------------------------
 
     def get(self, sig: FR.CohortSignature, head_cfg,
-            samples_per_class: Optional[int] = None,
             mesh=None) -> CachedProgram:
         """The compiled program for ``sig``'s canonical form — compiling,
         costing, and serializing it on first use."""
         canon = self.canonical(sig)
-        ck = self._key(canon, head_cfg, samples_per_class, mesh)
+        ck = self._key(canon, head_cfg, mesh)
         entry = self._entries.get(ck)
         if entry is not None:
             self.hits += 1
@@ -146,7 +145,7 @@ class ProgramCache:
             entry.uses += 1
             return entry
         self.misses += 1
-        entry = self._compile(canon, head_cfg, samples_per_class, mesh)
+        entry = self._compile(canon, head_cfg, mesh)
         entry.uses = 1
         self._entries[ck] = entry
         while len(self._entries) > self.max_entries:
@@ -154,10 +153,8 @@ class ProgramCache:
             self.evictions += 1
         return entry
 
-    def _compile(self, canon, head_cfg, samples_per_class,
-                 mesh) -> CachedProgram:
-        statics = dict(sig=canon, head_cfg=head_cfg,
-                       samples_per_class=samples_per_class)
+    def _compile(self, canon, head_cfg, mesh) -> CachedProgram:
+        statics = dict(sig=canon, head_cfg=head_cfg)
         t0 = time.perf_counter()
         executable = cost = serialized = None
         errors: Dict[str, Exception] = {}
@@ -195,19 +192,17 @@ class ProgramCache:
                     failed("serialize", e)
         return CachedProgram(
             sig=canon, head_cfg=head_cfg,
-            samples_per_class=samples_per_class,
             fingerprint=mesh_fingerprint(mesh), executable=executable,
             fallback=partial(FR.round_program, **statics),
             compile_us=compile_us, cost=cost, serialized=serialized,
             errors=errors)
 
     def warmup(self, sigs: Sequence[FR.CohortSignature], head_cfg,
-               samples_per_class: Optional[int] = None,
                mesh=None) -> Dict[str, Any]:
         """Pre-compile a signature list (one pass over the canonical grid
         before serving) — returns :meth:`stats`."""
         for sig in sigs:
-            self.get(sig, head_cfg, samples_per_class, mesh=mesh)
+            self.get(sig, head_cfg, mesh=mesh)
         return self.stats()
 
     # -- introspection ------------------------------------------------------
@@ -247,19 +242,17 @@ class ProgramCache:
 
 def canonical_grid(C: int, d: int, Ms: Sequence[int] = (4, 16, 64),
                    Ks: Sequence[int] = (1, 2, 4),
-                   cov_types: Sequence[str] = ("diag",),
-                   dtypes: Sequence[str] = ("bfloat16",),
-                   layout: str = "wire") -> List[FR.CohortSignature]:
+                   cov_types: Sequence[str] = ("diag",)
+                   ) -> List[FR.CohortSignature]:
     """A small canonical signature grid to warm the cache with — every
-    entry already canonical (Ms must be powers of two: this names the
-    compile targets, it does not bucket)."""
+    entry already canonical (Ms are slot-row counts and must be powers of
+    two: this names the compile targets, it does not bucket)."""
     for m in Ms:
         if FR.next_pow2(m) != m:
             raise ValueError(f"canonical_grid: M={m} is not a power of two "
                              "— the grid names canonical shapes")
-    return [FR.CohortSignature(M=m, C=C, K=k, d=d, cov_type=cov,
-                               dtype=dt, layout=layout)
-            for m in Ms for k in Ks for cov in cov_types for dt in dtypes]
+    return [FR.CohortSignature(M=m, C=C, K=k, d=d, cov_type=cov)
+            for m in Ms for k in Ks for cov in cov_types]
 
 
 def serving_grid(capacity: int, C: int, K: int, d: int,
@@ -267,15 +260,13 @@ def serving_grid(capacity: int, C: int, K: int, d: int,
                  ) -> List[FR.CohortSignature]:
     """The signatures a streaming-ingest service will actually request.
 
-    The broker's reservoir always closes at its fixed ``capacity`` in the
-    float32 ``"slots"`` layout (``signature_of_state``), so the warm set is
-    exactly one canonical signature per covariance type — warm these at
-    boot (``FedPFTService.warmup``) and ``close_round`` never compiles in
-    the request path.  Pass the same ``head_cfg``/``samples_per_class=None``
-    the cached ingest round uses, i.e. ``cache.warmup(serving_grid(...),
-    session.head)``.
+    The broker's reservoir always closes as a float32 slot stack of its
+    fixed ``capacity`` rows, so the warm set is exactly one canonical
+    signature per covariance type — warm these at boot
+    (``FedPFTService.warmup``) and ``close_round`` never compiles in the
+    request path.  Pass the same ``head_cfg`` the cached ingest round
+    uses, i.e. ``cache.warmup(serving_grid(...), session.head)``.
     """
     M = FR.next_pow2(capacity)
-    return [FR.CohortSignature(M=M, C=C, K=K, d=d, cov_type=cov,
-                               dtype="float32", layout="slots")
+    return [FR.CohortSignature(M=M, C=C, K=K, d=d, cov_type=cov)
             for cov in cov_types]

@@ -61,14 +61,12 @@ def round_specs_for(sig, mesh=None) -> Tuple[Any, ...]:
 
     Mirrors :func:`batch_specs_for` for the federation side: what
     ``fl.round.round_program.lower(...)`` consumes in ``launch.aot_cache``
-    — positional ``(key, pi, mu, cov, counts, slot_labels)`` matching the
-    signature's layout, no device allocation.  ``mesh`` pins every operand
+    — the slot stack's positional ``(key, pi, mu, cov, counts,
+    slot_labels)``, no device allocation.  ``mesh`` pins every operand
     to the replicated layout (the fused head runs identically on every
     shard, DESIGN.md §5) so the compiled executable's input shardings
     match what ``FedSession`` device_puts at call time.
     """
-    from repro.fl.round import WIRE_DTYPES  # deferred: fl imports stay out
-    #   of the model-dryrun import path
     sharding = None
     if mesh is not None:
         sharding = jax.sharding.NamedSharding(mesh,
@@ -79,20 +77,10 @@ def round_specs_for(sig, mesh=None) -> Tuple[Any, ...]:
             return SDS(shape, dtype)
         return SDS(shape, dtype, sharding=sharding)
 
-    key = sds((2,), jnp.uint32)
-    if sig.layout == "wire":
-        wd = jnp.dtype(WIRE_DTYPES[sig.dtype])
-        lead = (sig.M, sig.C)
-        return (key,
-                sds(lead + (sig.K,), wd),
-                sds(lead + (sig.K, sig.d), wd),
-                sds(lead + sig.cov_shape(packed=True), wd),
-                sds(lead, jnp.int32),
-                None)
-    return (key,
+    return (sds((2,), jnp.uint32),
             sds((sig.M, sig.K), jnp.float32),
             sds((sig.M, sig.K, sig.d), jnp.float32),
-            sds((sig.M,) + sig.cov_shape(packed=False), jnp.float32),
+            sds((sig.M,) + sig.cov_shape, jnp.float32),
             sds((sig.M,), jnp.int32),
             sds((sig.M,), jnp.int32))
 

@@ -24,7 +24,7 @@ buckets times the row counts, never by traffic.
 
 The round program sits behind the session's
 :class:`~repro.launch.aot_cache.ProgramCache`: :meth:`warmup` pre-compiles
-the one slots-layout signature the broker can close with
+the one slot signature the broker can close with
 (``aot_cache.serving_grid``), so extract, train, and infer share one warm
 cache and :meth:`close_round` never compiles in the request path.
 """

@@ -84,25 +84,25 @@ def test_wire_invariance_across_shard_counts(cohort, cov):
 
 
 def test_session_invariance_across_shard_counts(cohort):
-    """The full mesh-native FedSession — transfer, codec accounting,
-    planner-bucketed synthesis, streamed head — is shard-count invariant:
-    synthesized-feature statistics and the trained head agree to
-    tolerance, and comm_bytes is exactly Eqs. 9-11 regardless of shards."""
+    """The full mesh-native FedSession — transfer, codec accounting, the
+    fused server phase — is shard-count invariant: the decoded messages,
+    the features the plain reference synthesizes from them, and the
+    trained head agree to tolerance, and comm_bytes is exactly Eqs. 9-11
+    regardless of shards."""
     feats, labels = cohort
     results = {}
     for n in SHARD_COUNTS:
         sess = FA.FedSession(
             n_classes=N_CLASSES, summarizer=FA.GMMSummarizer(_gmm_cfg()),
-            head=H.HeadConfig(n_steps=120, lr=3e-3), shards=n,
-            synthesis="streamed")
+            head=H.HeadConfig(n_steps=120, lr=3e-3), shards=n)
         res = sess.run_sharded(jax.random.PRNGKey(0), feats, labels)
         assert res.info["n_shards"] == n
         assert res.info["comm_bytes"] == \
             G.comm_bytes("diag", DIM, K, N_CLASSES, 2) * I
-        pool = np.concatenate([np.asarray(f, np.float32)
-                               for f, _ in res.info["synthetic_chunks"]])
-        pool_y = np.concatenate([np.asarray(y)
-                                 for _, y in res.info["synthetic_chunks"]])
+        pool, pool_y = FA.synthesize_looped(
+            jax.random.PRNGKey(1), FA.stack_messages(res.messages),
+            np.stack([m.counts for m in res.messages]), "diag")
+        pool, pool_y = np.asarray(pool, np.float32), np.asarray(pool_y)
         assert_finite(res.model, f"in {n}-shard head")
         results[n] = (res, pool, pool_y)
     ref, ref_pool, ref_y = results[1]
@@ -133,8 +133,8 @@ def test_session_invariance_across_shard_counts(cohort):
 
 
 def test_fused_head_invariance_across_shard_counts(cohort):
-    """The zero-materialization server phase (FedSession's default,
-    ``synthesis="fused"``) runs the fused sampler-in-the-loop scan
+    """The zero-materialization server phase (FedSession's one route for
+    a homogeneous cohort) runs the fused sampler-in-the-loop scan
     REPLICATED on the post-all_gather slot stack: same inputs + same RNG
     on every shard ⇒ the trained head is shard-count invariant, with no
     synthetic pool or chunk list ever materialized."""

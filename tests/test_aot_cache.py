@@ -1,9 +1,9 @@
-"""AOT round-program cache (ISSUE 8, DESIGN.md §11): signature derivation
-and canonicalization, LRU accounting, serialized-executable round-trip
+"""AOT round-program cache (DESIGN.md §11): slot signatures and
+canonicalization, LRU accounting, serialized-executable round-trip
 determinism, the graceful jit fallback, bit-identity of padded canonical
-cohorts with the exact-shape fused path (host, mesh, ingest), and the
-multi-tenant acceptance law — after one pass over the canonical grid a
-mixed-signature stream triggers ZERO new compiles."""
+slot stacks with the exact-shape fused path (host, mesh, ingest, broker),
+and the multi-tenant acceptance law — after one pass over the canonical
+grid a mixed-signature stream triggers ZERO new compiles."""
 import dataclasses
 
 import jax
@@ -14,6 +14,7 @@ import pytest
 from repro.core import gmm as G
 from repro.core import head as H
 from repro.fl import api as FA
+from repro.fl import ingest as IG
 from repro.fl import round as FR
 from repro.fl.ingest import IngestConfig
 from repro.launch import input_specs as IS
@@ -59,6 +60,21 @@ def _sess(**kw):
     return FA.FedSession(n_classes=N_CLASSES, head=_HEAD, **kw)
 
 
+def _slots(msgs, target=None):
+    """The message-list round's slot stack as the round program's
+    positional ``(pi, mu, cov, counts, slot_labels)``, padded up to
+    ``target`` when given."""
+    stack, labels, counts, _ = FA.fused_slot_stack(
+        FA.stack_messages(msgs), np.stack([m.counts for m in msgs]))
+    pi, mu, cov = stack["pi"], stack["mu"], stack["cov"]
+    sig = FR.CohortSignature(int(mu.shape[0]), N_CLASSES, K, DIM,
+                             msgs[0].header.cov_type)
+    if target is not None:
+        pi, mu, cov, labels, counts = FR.pad_slots(pi, mu, cov, labels,
+                                                   counts, sig, target)
+    return sig, tuple(map(jnp.asarray, (pi, mu, cov, counts, labels)))
+
+
 def _same_head(a, b) -> bool:
     return bool(jnp.array_equal(a["w"], b["w"])
                 and jnp.array_equal(a["b"], b["b"]))
@@ -77,40 +93,58 @@ class TestSignature:
             FR.next_pow2(0)
 
     def test_signature_of_messages(self):
-        sig = FR.signature_of(_cohort(5, cov="full"))
-        assert sig == FR.CohortSignature(M=5, C=N_CLASSES, K=K, d=DIM,
-                                         cov_type="full", dtype="bfloat16",
-                                         layout="wire")
-        assert sig.n_slots == 5 * N_CLASSES
-        assert sig.canonical().M == 8
+        """A message-list round stages one slot row per present (client,
+        class) pair: 5 clients × 4 present classes → M = 20 slot rows,
+        canonical 32, f32 slots with the full (K, d, d) covariance."""
+        res = _sess(program_cache=ProgramCache()).server_aggregate(
+            jax.random.PRNGKey(0), _cohort(5, cov="full"))
+        sig = FR.CohortSignature(*res.info["compile"]["signature"])
+        assert sig == FR.CohortSignature(M=5 * N_CLASSES, C=N_CLASSES, K=K,
+                                         d=DIM, cov_type="full")
+        assert sig.cov_shape == (K, DIM, DIM)
+        assert sig.canonical().M == 32
+        assert res.info["compile"]["canonical"] == \
+            dataclasses.astuple(sig.canonical())
         # canonical is idempotent — grid points map to themselves
         assert sig.canonical().canonical() == sig.canonical()
-
-    def test_heterogeneous_cohort_raises(self):
-        msgs = _cohort(2) + [_msg(9, [3] * N_CLASSES, cov="spher")]
-        with pytest.raises(ValueError, match="heterogeneous"):
-            FR.signature_of(msgs)
 
     def test_signature_validation(self):
         with pytest.raises(ValueError, match="cov_type"):
             FR.CohortSignature(2, 4, 2, 8, "bogus")
-        with pytest.raises(ValueError, match="dtype"):
-            FR.CohortSignature(2, 4, 2, 8, "diag", dtype="int8")
-        with pytest.raises(ValueError, match="layout"):
-            FR.CohortSignature(2, 4, 2, 8, "diag", layout="bogus")
+        with pytest.raises(ValueError, match="non-positive"):
+            FR.CohortSignature(0, 4, 2, 8, "diag")
 
     def test_round_specs_shapes(self):
-        sig = FR.CohortSignature(4, N_CLASSES, K, DIM, "full")
+        sig = FR.CohortSignature(16, N_CLASSES, K, DIM, "full")
         key, pi, mu, cov, counts, labels = IS.round_specs_for(sig)
-        assert pi.shape == (4, N_CLASSES, K)
-        assert mu.shape == (4, N_CLASSES, K, DIM)
-        assert cov.shape == (4, N_CLASSES, K, DIM * (DIM + 1) // 2)
-        assert counts.shape == (4, N_CLASSES) and labels is None
-        slot = dataclasses.replace(sig, layout="slots", dtype="float32",
-                                   M=16)
-        _, pi, mu, cov, counts, labels = IS.round_specs_for(slot)
-        assert pi.shape == (16, K) and cov.shape == (16, K, DIM, DIM)
-        assert labels.shape == (16,)
+        assert key.shape == (2,)
+        assert pi.shape == (16, K) and mu.shape == (16, K, DIM)
+        assert cov.shape == (16, K, DIM, DIM)
+        assert counts.shape == labels.shape == (16,)
+        assert pi.dtype == mu.dtype == cov.dtype == jnp.float32
+        assert IS.round_specs_for(
+            dataclasses.replace(sig, cov_type="diag"))[3].shape == \
+            (16, K, DIM)
+        assert IS.round_specs_for(
+            dataclasses.replace(sig, cov_type="spher"))[3].shape == (16, K)
+
+    def test_pad_slots_only_pads_up_along_m(self):
+        """Padding prepends count-0 identity rows along M and nothing else:
+        a target that differs in another axis, or is shorter than the
+        stack, is refused instead of silently reshaping the cohort."""
+        sig, (pi, mu, cov, counts, labels) = _slots(_cohort(3))
+        canon = sig.canonical()
+        out = FR.pad_slots(pi, mu, cov, labels, counts, sig, canon)
+        n_pad = canon.M - sig.M
+        assert [a.shape[0] for a in out] == [canon.M] * 5
+        assert not out[4][:n_pad].any() and not out[3][:n_pad].any()
+        np.testing.assert_array_equal(out[4][n_pad:], counts)
+        with pytest.raises(ValueError, match="only pads along M"):
+            FR.pad_slots(pi, mu, cov, labels, counts, sig,
+                         dataclasses.replace(canon, K=K + 1))
+        with pytest.raises(ValueError, match="target M"):
+            FR.pad_slots(pi, mu, cov, labels, counts, sig,
+                         dataclasses.replace(sig, M=sig.M - 1))
 
 
 # ---------------------------------------------------------------------------
@@ -136,10 +170,8 @@ class TestProgramCache:
         base = FR.CohortSignature(4, N_CLASSES, K, DIM, "diag")
         cache.get(base, _HEAD)
         cache.get(dataclasses.replace(base, cov_type="spher"), _HEAD)
-        cache.get(dataclasses.replace(base, dtype="float16"), _HEAD)
         cache.get(base, H.HeadConfig(n_steps=21, batch_size=64))
-        cache.get(base, _HEAD, samples_per_class=16)
-        assert len(cache) == 5 and cache.misses == 5 and cache.hits == 0
+        assert len(cache) == 3 and cache.misses == 3 and cache.hits == 0
 
     def test_lru_eviction_order(self):
         cache = ProgramCache(max_entries=2)
@@ -161,15 +193,12 @@ class TestProgramCache:
         """deserialize(serialize(compiled)) must run bit-identical to the
         live executable — the deployment artifact IS the program."""
         cache = ProgramCache()
-        msgs = _cohort(4)
-        sig = FR.signature_of(msgs)
+        sig, _ = _slots(_cohort(4))
         entry = cache.get(sig, _HEAD)
         if entry.serialized is None:
             pytest.skip("backend cannot serialize executables")
-        stack, counts = FR.wire_stack(msgs)
-        args = (jax.random.PRNGKey(3), jnp.asarray(stack["pi"]),
-                jnp.asarray(stack["mu"]), jnp.asarray(stack["cov"]),
-                jnp.asarray(counts), None)
+        _, stack = _slots(_cohort(4), cache.canonical(sig))
+        args = (jax.random.PRNGKey(3),) + stack
         head_live, losses_live = entry(*args)
         head_rt, losses_rt = entry.deserialize()(*args)
         assert _same_head(head_live, head_rt)
@@ -184,7 +213,8 @@ class TestProgramCache:
             lambda *a, **k: (_ for _ in ()).throw(RuntimeError("no AOT")))
         cache = ProgramCache()
         msgs = _cohort(4)
-        entry = cache.get(FR.signature_of(msgs), _HEAD)
+        sig, _ = _slots(msgs)
+        entry = cache.get(sig, _HEAD)
         assert not entry.aot and cache.jit_fallbacks == 1 \
             and cache.compiles == 0
         # the failure stays visible: the exception rides on the entry
@@ -194,13 +224,8 @@ class TestProgramCache:
             entry.deserialize()
         # the fallback round is still the SAME program — bit-identical to
         # an AOT entry for the same canonical signature
-        stack, counts = FR.wire_stack(msgs)
-        sig = FR.signature_of(msgs)
-        stack, counts = FR.pad_cohort(stack, counts, sig,
-                                      cache.canonical(sig))
-        args = (jax.random.PRNGKey(3), jnp.asarray(stack["pi"]),
-                jnp.asarray(stack["mu"]), jnp.asarray(stack["cov"]),
-                jnp.asarray(counts), None)
+        _, stack = _slots(msgs, cache.canonical(sig))
+        args = (jax.random.PRNGKey(3),) + stack
         head_fb, _ = entry(*args)
         monkeypatch.undo()
         head_aot, _ = ProgramCache().get(sig, _HEAD)(*args)
@@ -228,55 +253,54 @@ class TestProgramCache:
 
 
 class TestBitIdentity:
-    @pytest.mark.parametrize("cov", ["diag", "full", "spher"])
-    def test_padded_equals_exact_host(self, key, cov):
-        """M=5 pads to the canonical M=8 with identity-GMM count-0 clients
-        — the trained head must be bit-identical to the uncached compacted
-        SlotTable path, for every covariance family."""
-        msgs = _cohort(5, cov=cov, seed=3)
-        r_exact = _sess().server_aggregate(key, msgs)
-        cache = ProgramCache()
-        r_canon = _sess(program_cache=cache).server_aggregate(key, msgs)
-        assert _same_head(r_exact.model, r_canon.model)
-        assert jnp.array_equal(r_exact.info["head_losses"],
-                               r_canon.info["head_losses"])
-        assert r_canon.info["compile"]["canonical"][0] == 8
-        assert r_canon.info["compile"]["aot"]
-
-    def test_padded_equals_exact_mesh(self, key):
-        """The same law through run_sharded: the cache compiles with
-        replicated input shardings and must match the uncached mesh round
-        bitwise."""
-        clients = [(np.random.RandomState(7 + i).randn(24, DIM)
+    @staticmethod
+    def _round(key, route, cov, cache):
+        """One round on ``route`` — every route ends in the session's one
+        fused runner, cached or plain."""
+        if route in ("host", "broker"):
+            msgs = _cohort(5, cov=cov, seed=3)
+            if route == "host":
+                return _sess(program_cache=cache).server_aggregate(key, msgs)
+            broker = IG.IngestBroker(IngestConfig(capacity=20), N_CLASSES)
+            for i, m in enumerate(msgs):
+                broker.submit(i, m)
+            return _sess(program_cache=cache).aggregate_from_broker(key,
+                                                                    broker)
+        clients = [(np.random.RandomState(7 + i).randn(30, DIM)
                     .astype(np.float32),
                     np.random.RandomState(70 + i).randint(
-                        0, N_CLASSES, 24).astype(np.int32))
-                   for i in range(3)]
-        summ = FA.GMMSummarizer(G.GMMConfig(K, "diag", n_iter=4))
-        r_exact = _sess(summarizer=summ, shards=1).run(key, clients)
-        r_canon = _sess(summarizer=summ, shards=1,
-                        program_cache=ProgramCache()).run(key, clients)
-        assert _same_head(r_exact.model, r_canon.model)
-        assert r_canon.info["compile"]["aot"]
-
-    def test_padded_equals_exact_ingest(self, key):
-        """Streaming reservoir route: a non-power-of-two capacity pads to
-        the canonical slot count, bit-identical to the uncached ingest
-        session."""
-        clients = [(np.random.RandomState(i).randn(30, DIM)
-                    .astype(np.float32),
-                    np.random.RandomState(50 + i).randint(
                         0, N_CLASSES, 30).astype(np.int32))
-                   for i in range(4)]
-        summ = FA.GMMSummarizer(G.GMMConfig(K, "diag", n_iter=4))
-        ig = IngestConfig(capacity=20)     # → canonical 32
-        r_exact = _sess(summarizer=summ, ingest=ig).run(key, clients)
+                   for i in range(5)]
+        summ = FA.GMMSummarizer(G.GMMConfig(K, cov, n_iter=4))
+        kw = {"shards": 1} if route == "mesh" else \
+            {"ingest": IngestConfig(capacity=20)}
+        return _sess(summarizer=summ, program_cache=cache, **kw).run(
+            key, clients)
+
+    @pytest.mark.parametrize("cov", ["diag", "full", "spher"])
+    @pytest.mark.parametrize("route", ["host", "mesh", "ingest", "broker"])
+    def test_cached_equals_plain(self, key, route, cov):
+        """The cached round pads the slot stack up to the canonical
+        power-of-two row count with identity-GMM count-0 rows and runs the
+        AOT executable; the trained head and loss trace must be
+        bit-identical to the plain exact-shape run
+        (``train_head_from_gmms``) on every route — message list on the
+        host and on a 1-shard mesh (replicated input shardings), ingest
+        session, an external broker — for every covariance family."""
+        plain = self._round(key, route, cov, None)
         cache = ProgramCache()
-        r_canon = _sess(summarizer=summ, ingest=ig,
-                        program_cache=cache).run(key, clients)
-        assert _same_head(r_exact.model, r_canon.model)
-        sig, _, spc, fp = cache.keys()[0]
-        assert (sig.layout, sig.M, spc, fp) == ("slots", 32, None, None)
+        cached = self._round(key, route, cov, cache)
+        assert "compile" not in plain.info
+        assert _same_head(plain.model, cached.model)
+        assert jnp.array_equal(plain.info["head_losses"],
+                               cached.info["head_losses"])
+        assert cached.info["compile"]["aot"]
+        # five clients with every class present: the message list stages
+        # one row per (client, class) slot, the reservoir its capacity —
+        # 20 slot rows either way, canonical 32
+        assert cached.info["compile"]["signature"][0] == 20
+        sig, _, fp = cache.keys()[0]
+        assert sig.M == 32 and (fp is None) == (route != "mesh")
 
     def test_samples_per_class_and_empty_cohort(self, key):
         msgs = _cohort(3, seed=5)
@@ -314,7 +338,8 @@ class TestMultiTenant:
         ≥20-cohort mixed-signature stream triggers zero new traces/
         compiles — asserted on the cache counters, per round."""
         cache = ProgramCache()
-        grid = canonical_grid(C=N_CLASSES, d=DIM, Ms=(4, 8), Ks=(K,),
+        # slot rows: 3..8 clients × 4 present classes → 12..32 → {16, 32}
+        grid = canonical_grid(C=N_CLASSES, d=DIM, Ms=(16, 32), Ks=(K,),
                               cov_types=("diag", "spher"))
         cache.warmup(grid, _HEAD)
         assert cache.compiles == len(grid) == 4
@@ -336,12 +361,17 @@ class TestMultiTenant:
     def test_info_compile_reporting(self, key):
         """info["compile"] carries hit/miss, the canonical signature, and
         compile-amortized latency that decays as the entry is reused."""
+        # the first round must pay a real compile: the warm-grid test above
+        # compiles the same canonical signature, and JAX's in-process
+        # executable cache would otherwise serve it in about a millisecond
+        jax.clear_caches()
         cache = ProgramCache()
         sess = _sess(program_cache=cache)
         r1 = sess.server_aggregate(key, _cohort(3, seed=11))
         c1 = r1.info["compile"]
         assert c1["hit"] is False and c1["aot"] is True
-        assert c1["signature"][0] == 3 and c1["canonical"][0] == 4
+        # 3 clients × 4 present classes → 12 slot rows, canonical 16
+        assert c1["signature"][0] == 12 and c1["canonical"][0] == 16
         assert c1["compile_us"] > 0 and c1["cache"]["entries"] == 1
         r2 = sess.server_aggregate(jax.random.PRNGKey(9),
                                    _cohort(4, seed=12))

@@ -107,15 +107,14 @@ def test_client_fit_runs_the_kernel(one_chip, tpu_dispatch):
 
 
 def test_round_program_compiles_for_serving_grid(one_chip):
-    """The one signature a service warms (slots layout, diag, d=2048)
+    """The one signature a service warms (slot stack, diag, d=2048)
     compiles, costs and serializes for one chip."""
     from jax.experimental import serialize_executable as SE
     sig = AC.serving_grid(64, 4, 2, D)[0]
     specs = jax.tree.map(
         lambda s: _sds(s.shape, s.dtype, one_chip), IS.round_specs_for(sig))
     compiled = FR.round_program.lower(
-        *specs, sig=sig, head_cfg=H.HeadConfig(),
-        samples_per_class=None).compile()
+        *specs, sig=sig, head_cfg=H.HeadConfig()).compile()
     cost = HloCost(compiled.as_text()).total()
     assert cost.elem_flops > 0
     assert SE.serialize(compiled)[0]

@@ -265,12 +265,13 @@ class TestFedSessionPaths:
         """A cohort where one client holds NO samples of some class must
         stay NaN-free end-to-end: the empty slot's EM fit (all-zero
         weights under the batched classwise fit) is finite, its message
-        encodes/decodes finite params, and pooled synthesis + head
-        training never see a NaN."""
+        encodes/decodes finite params, and neither the plain reference
+        (looped synthesis + ``train_head``) nor the fused server phase
+        ever sees a NaN."""
         x, y, xt, yt = dataset
         clients = [(x[y < 3], y[y < 3]),            # classes 3.. absent
                    (x[y >= 2], y[y >= 2])]          # classes 0-1 absent
-        sess = _gmm_session(cov=cov, K=2, synthesis="pooled")
+        sess = _gmm_session(cov=cov, K=2)
         keys = jax.random.split(key, 3)
         msgs = [sess.client_update(k, f, yy, i)
                 for i, (k, (f, yy)) in enumerate(zip(keys[1:], clients))]
@@ -278,16 +279,18 @@ class TestFedSessionPaths:
             assert 0 in {int(c) for c in m.header.counts}
             for leaf in jax.tree.leaves(m.params):
                 assert np.isfinite(np.asarray(leaf)).all(), cov
-        res = sess.server_aggregate(keys[0], msgs)
-        sf = res.info["synthetic_feats"]
+        k_syn, k_head = jax.random.split(keys[0])
+        sf, sl = FA.synthesize_looped(k_syn, FA.stack_messages(msgs),
+                                      np.stack([m.counts for m in msgs]),
+                                      cov)
         assert np.isfinite(np.asarray(sf)).all()
-        for leaf in jax.tree.leaves(res.model):
+        ref, _ = H.train_head(k_head, sf, sl, N_CLASSES, sess.head)
+        for leaf in jax.tree.leaves(ref):
             assert np.isfinite(np.asarray(leaf)).all()
         # every class is represented by at least one client's synthesis
-        assert set(np.unique(np.asarray(res.info["synthetic_labels"]))) \
-            == set(range(N_CLASSES))
+        assert set(np.unique(np.asarray(sl))) == set(range(N_CLASSES))
         # the fused default never materializes the pool yet stays finite
-        res_f = _gmm_session(cov=cov, K=2).server_aggregate(keys[0], msgs)
+        res_f = sess.server_aggregate(keys[0], msgs)
         assert res_f.info["synthesis"] == "fused"
         assert "synthetic_feats" not in res_f.info
         for leaf in jax.tree.leaves(res_f.model):
@@ -349,7 +352,7 @@ class TestMeshMode:
         == Eqs. 9-11 — the mesh path and the codec share one layout.
         Runs under the runtime sanitizer (nan/inf + key-reuse tracer)."""
         feats, labels = self._cohort(dataset)
-        sess = _gmm_session(shards=1, synthesis="streamed")
+        sess = _gmm_session(shards=1)
         res = sess.run_sharded(key, feats, labels)
         assert res.info["n_shards"] == 1
         assert res.info["comm_bytes"] == \

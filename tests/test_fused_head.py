@@ -1,9 +1,10 @@
 """Zero-materialization server phase (ISSUE 5): the fused
 sampler-in-the-loop head trainer (``head.train_head_from_gmms``), its slot
-table (``fl.planner.SlotTable``), and the ``FedSession(synthesis="fused")``
-default — distributional equivalence with planner-bucketed synthesis
+table (``fl.planner.SlotTable``), and the ``FedSession`` server phase
+built on it — distributional equivalence with planner-bucketed synthesis
 (per-slot draw frequencies, per-class moment match, head-accuracy parity),
-the empty-cohort guard, and the materializing fallbacks."""
+small-slot edge cases, the empty-cohort guard, and the heterogeneous
+pooled fallback."""
 import dataclasses
 
 import jax
@@ -244,25 +245,21 @@ class TestSessionFusedDefault:
 
     @pytest.mark.slow
     def test_fused_matches_pooled_session_accuracy(self, key):
+        """The session's fused head learns the task as well as the plain
+        reference on the same decoded messages: the looped
+        per-(client, class) synthesis, pooled, fed to ``train_head``."""
         clients, xt, yt = self._clients(key)
         res_f = self._session().run(key, clients)
-        res_p = self._session(synthesis="pooled").run(key, clients)
-        assert res_p.info["synthesis"] == "pooled"
+        msgs = res_f.messages
+        k_syn, k_head = jax.random.split(key)
+        pf, py = FA.synthesize_looped(k_syn, FA.stack_messages(msgs),
+                                      np.stack([m.counts for m in msgs]),
+                                      "diag")
+        ref, _ = H.train_head(k_head, pf, py, N_CLASSES,
+                              self._session().head)
         acc_f = float(H.accuracy(res_f.model, xt, yt))
-        acc_p = float(H.accuracy(res_p.model, xt, yt))
+        acc_p = float(H.accuracy(ref, xt, yt))
         assert acc_f > 0.6 and abs(acc_f - acc_p) < 0.1, (acc_f, acc_p)
-
-    def test_stream_synthesis_alias_is_gone(self, key):
-        """The PR-6 deprecation alias was removed: synthesis='streamed' is
-        the one spelling, and the old kwarg fails loudly at construction."""
-        assert self._session(synthesis="streamed")._synthesis_mode() \
-            == "streamed"
-        with pytest.raises(TypeError, match="stream_synthesis"):
-            self._session(stream_synthesis=True)
-
-    def test_invalid_synthesis_mode_raises(self, key):
-        with pytest.raises(ValueError, match="synthesis"):
-            self._session(synthesis="bogus")._synthesis_mode()
 
     def test_heterogeneous_cohort_falls_back_to_pooled(self, key):
         """Mixed-K cohorts (paper §6.3) can't stack into one slot tensor —
@@ -291,52 +288,58 @@ class TestSessionFusedDefault:
             assert np.isfinite(np.asarray(leaf)).all()
 
 
-class TestStreamingCompileChurn:
-    def test_one_row_chunk_trains_full_width(self, key):
-        """A 1-row chunk must not shrink the minibatch shape (it is padded
-        with weight-0 rows) and must still contribute steps."""
+def _class_slots(x, y, classes, counts):
+    """A K=1 diag slot stack with one slot per class in ``classes``: the
+    class's mean and variance, drawn ``counts`` times."""
+    x, y = np.asarray(x), np.asarray(y)
+    mu = np.stack([x[y == c].mean(0) for c in classes])[:, None]
+    var = np.stack([x[y == c].var(0) for c in classes])[:, None]
+    return (jnp.ones((len(classes), 1)), jnp.asarray(mu), jnp.asarray(var),
+            jnp.asarray(classes, jnp.int32), jnp.asarray(counts, jnp.int32))
+
+
+class TestFusedSmallSlots:
+    def test_one_row_chunk_trains_full_width(self, key, monkeypatch):
+        """A class whose one slot asks for a single draw must not shrink
+        the minibatch: with Σcounts = C ≪ batch_size every Adam step
+        still computes its loss over a full ``batch_size`` rows, and the
+        head learns every class."""
         dcfg = D.DatasetConfig(n_classes=N_CLASSES, n_per_class=80,
                                input_dim=DIM, class_sep=2.0)
         x, y = D.make_dataset(dcfg)
-        chunks = [(x[:1], y[:1]), (x[1:], y[1:])]
-        cfg = H.HeadConfig(n_steps=100, lr=3e-3)
-        params, losses = H.train_head_streaming(key, chunks, N_CLASSES, cfg)
+        xt, yt = D.make_dataset(dcfg, split=1)
+        stack = _class_slots(x, y, range(N_CLASSES), [1] * N_CLASSES)
+        widths = []
+        real = H._xent
+        monkeypatch.setattr(H, "_xent", lambda p, f, lbl, w: (
+            widths.append(f.shape), real(p, f, lbl, w))[1])
+        # an n_steps of its own forces a fresh trace through the patch
+        cfg = H.HeadConfig(n_steps=101, batch_size=64, lr=3e-3)
+        params, losses = H.train_head_from_gmms(key, *stack, N_CLASSES,
+                                                cfg, "diag")
+        assert widths and set(widths) == {(cfg.batch_size, DIM)}
         assert losses.shape == (cfg.n_steps,)
         assert np.isfinite(np.asarray(losses)).all()
-        for leaf in jax.tree.leaves(params):
-            assert np.isfinite(np.asarray(leaf)).all()
+        assert float(H.accuracy(params, xt, yt)) > 0.6
 
     def test_interleaving_retains_minority_chunk_class(self, key):
-        """A class living ONLY in one small chunk must survive training:
-        the round-robin interleave revisits that chunk every
-        ≈ n_steps/_INTERLEAVE steps instead of letting the large chunks
-        (which never contain the class) run out the clock on it."""
+        """A class living ONLY in one small slot must survive training:
+        the in-scan slot draw hits it ∝ its count on every step, so the
+        large slots (which never carry the class) cannot run out the
+        clock on it."""
         dcfg = D.DatasetConfig(n_classes=N_CLASSES, n_per_class=120,
                                input_dim=DIM, class_sep=2.0)
         x, y = D.make_dataset(dcfg)
         xt, yt = D.make_dataset(dcfg, split=1)
-        x, y = np.asarray(x), np.asarray(y)
-        m0 = y == 0
-        chunks = [(jnp.asarray(x[m0][:20]), jnp.asarray(y[m0][:20])),
-                  (jnp.asarray(x[~m0]), jnp.asarray(y[~m0]))]
+        stack = _class_slots(x, y, range(N_CLASSES),
+                             [20] + [600] * (N_CLASSES - 1))
         cfg = H.HeadConfig(n_steps=400, lr=3e-3)
-        params, _ = H.train_head_streaming(key, chunks, N_CLASSES, cfg)
+        params, _ = H.train_head_from_gmms(key, *stack, N_CLASSES, cfg,
+                                           "diag")
         lo = np.asarray(yt) == 0
         acc0 = float(H.accuracy(params, jnp.asarray(np.asarray(xt)[lo]),
                                 jnp.asarray(np.asarray(yt)[lo])))
-        assert acc0 > 0.5, f"minority-chunk class forgotten: acc0={acc0}"
-
-    def test_step_allocation_proportional_and_exact(self, key):
-        """The deterministic largest-remainder allocation spends exactly
-        n_steps steps, ∝ chunk size."""
-        dcfg = D.DatasetConfig(n_classes=N_CLASSES, n_per_class=100,
-                               input_dim=DIM, class_sep=2.0)
-        x, y = D.make_dataset(dcfg)
-        cuts = [0, 30, 330, x.shape[0]]
-        chunks = [(x[a:b], y[a:b]) for a, b in zip(cuts, cuts[1:])]
-        cfg = H.HeadConfig(n_steps=200, lr=3e-3)
-        _, losses = H.train_head_streaming(key, chunks, N_CLASSES, cfg)
-        assert losses.shape == (cfg.n_steps,)
+        assert acc0 > 0.5, f"minority-slot class forgotten: acc0={acc0}"
 
 
 class TestPaddedSlotStack:

@@ -518,11 +518,6 @@ class TestSessionIngest:
         assert res.info.get("empty_cohort") is True
         assert np.isfinite(np.asarray(res.model["w"])).all()
 
-    def test_requires_fused_synthesis(self, key):
-        with pytest.raises(ValueError, match="fused"):
-            _session(synthesis="pooled",
-                     ingest=IG.IngestConfig()).run(key, _clients(key))
-
     def test_rejects_chain_topology(self, key):
         with pytest.raises(NotImplementedError, match="Star"):
             _session(topology=FA.Chain(),

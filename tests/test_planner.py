@@ -1,7 +1,7 @@
-"""Count-stratified synthesis planner (fl/planner + fl/api.synthesize_chunks)
-and the streaming head trainer: plan invariants (≤ 2·Σcounts padded draws),
-parity with the looped reference under heavy skew, chunked-vs-pooled head
-training, and the empty-cohort guard end to end."""
+"""Count-stratified synthesis planner (fl/planner + fl/api.synthesize_chunks):
+plan invariants (≤ 2·Σcounts padded draws), parity with the looped
+reference under heavy skew, session-vs-reference head training, and the
+empty-cohort guard end to end."""
 import dataclasses
 
 import jax
@@ -142,41 +142,7 @@ class TestPlannedSynthesis:
             np.testing.assert_allclose(mp, mm, atol=0.5)
 
 
-class TestStreamingHead:
-    def _separable(self, key):
-        dcfg = D.DatasetConfig(n_classes=N_CLASSES, n_per_class=120,
-                               input_dim=DIM, class_sep=2.0)
-        return (*D.make_dataset(dcfg), *D.make_dataset(dcfg, split=1))
-
-    def test_streaming_matches_pooled_accuracy(self, key):
-        x, y, xt, yt = self._separable(key)
-        cfg = H.HeadConfig(n_steps=300, lr=3e-3)
-        pooled, _ = H.train_head(key, x, y, N_CLASSES, cfg)
-        # chunk the SAME data arbitrarily — streaming must learn the task
-        cuts = [0, 97, 311, 312, 700, x.shape[0]]
-        chunks = [(x[a:b], y[a:b]) for a, b in zip(cuts, cuts[1:])]
-        streamed, losses = H.train_head_streaming(key, chunks, N_CLASSES, cfg)
-        assert losses.shape == (cfg.n_steps,)
-        acc_p = float(H.accuracy(pooled, xt, yt))
-        acc_s = float(H.accuracy(streamed, xt, yt))
-        assert abs(acc_p - acc_s) < 0.07, (acc_p, acc_s)
-
-    def test_streaming_skips_empty_chunks(self, key):
-        x, y, xt, yt = self._separable(key)
-        chunks = [(x[:0], y[:0]), (x, y)]
-        params, _ = H.train_head_streaming(key, chunks, N_CLASSES,
-                                           H.HeadConfig(n_steps=150, lr=3e-3))
-        assert float(H.accuracy(params, xt, yt)) > 0.6
-
-    def test_streaming_all_empty_returns_init(self, key):
-        params, losses = H.train_head_streaming(
-            key, [(jnp.zeros((0, DIM)), jnp.zeros((0,), jnp.int32))],
-            N_CLASSES, H.HeadConfig())
-        assert params["w"].shape == (DIM, N_CLASSES)
-        assert losses.shape == (0,)
-        for leaf in jax.tree.leaves(params):
-            assert np.isfinite(np.asarray(leaf)).all()
-
+class TestPooledHead:
     def test_train_head_empty_pool_returns_init(self, key):
         params, losses = H.train_head(key, jnp.zeros((0, DIM)),
                                       jnp.zeros((0,), jnp.int32),
@@ -203,16 +169,23 @@ class TestSessionIntegration:
 
     @pytest.mark.slow
     def test_streamed_synthesis_matches_pooled_session(self, key):
+        """The session's server phase learns the task as well as the plain
+        reference on the same decoded messages (looped synthesis, pooled,
+        ``train_head``) — and reports its plan without pooling."""
         clients, xt, yt = self._clients(key)
-        res_pool = self._session().run(key, clients)
-        res_stream = self._session(synthesis="streamed").run(key, clients)
-        acc_p = float(H.accuracy(res_pool.model, xt, yt))
-        acc_s = float(H.accuracy(res_stream.model, xt, yt))
+        res = self._session().run(key, clients)
+        msgs = res.messages
+        k_syn, k_head = jax.random.split(key)
+        pf, py = FA.synthesize_looped(k_syn, FA.stack_messages(msgs),
+                                      np.stack([m.counts for m in msgs]),
+                                      "diag")
+        ref, _ = H.train_head(k_head, pf, py, N_CLASSES,
+                              self._session().head)
+        acc_p = float(H.accuracy(ref, xt, yt))
+        acc_s = float(H.accuracy(res.model, xt, yt))
         assert acc_s > 0.6 and abs(acc_p - acc_s) < 0.1, (acc_p, acc_s)
-        # streaming never pools: chunks in info, no synthetic_feats tensor
-        assert "synthetic_chunks" in res_stream.info
-        assert "synthetic_feats" not in res_stream.info
-        assert "synthesis_plans" in res_stream.info
+        assert "synthetic_feats" not in res.info
+        assert "synthesis_plans" in res.info
 
     def test_empty_cohort_guard_end_to_end(self, key):
         """min_class_count filtering EVERY class must yield a clean result
